@@ -6,8 +6,9 @@ threaded codec (``disq_tpu.native``) plugs in behind the same functions
 when built, and a Pallas inflate kernel is the planned device path — all
 three share this module's block framing.
 
-**Canonical deflate pin** (the byte-identity contract from BASELINE.md):
-raw DEFLATE, zlib level 6, memLevel 8, default strategy. All BGZF output
+**Canonical deflate pin** (the byte-identity contract: a sorted BAM and
+its BAI written twice, by any path that uses host deflate, are the same
+bytes): raw DEFLATE, zlib level 6, memLevel 8, default strategy. All BGZF output
 in this framework uses exactly these parameters, so repeated writes of the
 same records are byte-identical.
 
@@ -150,7 +151,7 @@ def inflate_blocks_device(
     keep_device: bool = False, to_columnar=None,
 ):
     """Device path of ``inflate_blocks``: the 128-lane SIMD Pallas
-    kernel (``ops/inflate_simd``, the PROBES.md design) with ISIZE
+    kernel (``ops/inflate_simd``) with ISIZE
     validated against the kernel's per-lane output length and CRC on
     host. ``DISQ_TPU_DEVICE_INFLATE=legacy`` selects the round-1
     one-block-per-grid-program kernel (``ops/inflate``) for A/B runs.

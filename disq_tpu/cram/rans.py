@@ -162,7 +162,7 @@ def rans_encode_order1(raw: bytes) -> bytes:
     encoder walks that schedule in reverse.
 
     Reference behavior: htsjdk/htslib rANS order-1 (SURVEY.md §2.8 CRAM
-    row; VERDICT r4 item 7)."""
+    row)."""
     try:
         from disq_tpu.native import rans_encode1_native
 

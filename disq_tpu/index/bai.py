@@ -16,8 +16,8 @@ Build is vectorized: bins come from ``reg2bin`` applied to whole columns;
 the *sorted* batch — the "segmented scan over sorted virtual offsets"
 design from BASELINE.json's north star.
 
-Canonical-encoder pins (BASELINE.md: byte-identity is defined against
-THIS encoder): bins emitted in ascending bin-id order, metadata bin last;
+Canonical-encoder pins (byte-identity of written indexes is defined
+against THIS encoder): bins emitted in ascending bin-id order, metadata bin last;
 adjacent chunks merged when the next chunk begins in the same compressed
 block the previous one ends in (``beg >> 16 <= prev_end >> 16``); linear
 index holes forward-filled with the previous window's offset.

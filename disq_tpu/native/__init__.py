@@ -1,9 +1,13 @@
 """ctypes bindings for the C++ host runtime (``native/disq_host.cpp``).
 
-Auto-builds the shared library with g++ on first import (cached next to
-this module); import fails cleanly when no toolchain is present, and
-every caller falls back to the pure-Python/numpy path — the native layer
-is an accelerator, never a requirement.
+Auto-builds the shared library with g++ on first use (cached next to
+this module, rebuilt whenever the SHA-256 of ``native/disq_host.cpp``
+differs from the one baked into the library — mtimes do not survive a
+copy of the tree); import fails cleanly when no toolchain is present,
+and every caller falls back to the pure-Python/numpy path — the native
+layer is an accelerator, never a requirement. ``build_variant()`` says
+which inflate was linked; entry points that report host rates
+(``chip_smoke.py``) call it and fail when the library cannot be built.
 
 Byte-identity note: the deflate path uses the same zlib with the same
 parameters as the Python pin (level 6, memLevel 8, raw), so outputs are
@@ -28,13 +32,33 @@ _lib = None
 _load_error: Exception | None = None
 
 
+def _src_hash() -> str:
+    import hashlib
+
+    with open(_SRC, "rb") as f:
+        return hashlib.sha256(f.read()).hexdigest()
+
+
+def _so_build_info() -> tuple[str, str]:
+    """(source sha256, inflate variant) baked into the library file, or
+    ("", "") for a library built before the build stamp existed."""
+    import re
+
+    with open(_SO, "rb") as f:
+        m = re.search(rb"disq-build:(\w+):(\w+):", f.read())
+    return (m.group(1).decode(), m.group(2).decode()) if m else ("", "")
+
+
 def _build() -> None:
     # Unique temp name: concurrent first-use builds in sibling processes
     # must not interleave output into the same file; os.replace is atomic.
     tmp = f"{_SO}.{os.getpid()}.tmp"
-    base = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", _SRC, "-o", tmp]
+    base = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", _SRC, "-o", tmp,
+            f'-DDISQ_SRC_HASH="{_src_hash()}"']
     # Prefer the libdeflate inflate/CRC fast path; retry zlib-only when
-    # libdeflate headers/libs are absent on this host.
+    # libdeflate headers/libs are absent on this host.  The retry is
+    # loud: the zlib variant inflates 2-3x slower, and a host rate taken
+    # on it must not pass for the libdeflate one (``build_variant()``).
     variants = [
         base + ["-DDISQ_HAVE_LIBDEFLATE", "-ldeflate", "-lz", "-pthread"],
         base + ["-lz", "-pthread"],
@@ -45,6 +69,14 @@ def _build() -> None:
             try:
                 subprocess.run(cmd, check=True, capture_output=True)
                 os.replace(tmp, _SO)
+                if err is not None:
+                    import warnings
+
+                    warnings.warn(
+                        "disq_tpu native library built WITHOUT libdeflate "
+                        "(zlib inflate): "
+                        + err.stderr.decode(errors="replace").strip()[-300:],
+                        RuntimeWarning, stacklevel=2)
                 return
             except subprocess.CalledProcessError as e:
                 err = e
@@ -124,11 +156,14 @@ def _load() -> ctypes.CDLL:
         if _load_error is not None:
             raise ImportError(f"native library unavailable: {_load_error}")
         try:
+            have_src = os.path.exists(_SRC)
             for attempt in (0, 1):
                 try:
+                    # staleness is keyed on the source's hash, which the
+                    # build bakes into the library — mtimes do not
+                    # survive a copy of the tree
                     if attempt or not os.path.exists(_SO) or (
-                        os.path.exists(_SRC)
-                        and os.path.getmtime(_SRC) > os.path.getmtime(_SO)
+                        have_src and _so_build_info()[0] != _src_hash()
                     ):
                         _build()
                     lib = ctypes.CDLL(_SO)
@@ -139,7 +174,7 @@ def _load() -> ctypes.CDLL:
                     # rebuild attempt when the source is present, else a
                     # clean ImportError so every caller's Python
                     # fallback engages
-                    if attempt or not os.path.exists(_SRC):
+                    if attempt or not have_src:
                         raise
         except (OSError, subprocess.CalledProcessError,
                 AttributeError) as e:
@@ -147,6 +182,14 @@ def _load() -> ctypes.CDLL:
             raise ImportError(f"cannot load native library: {e}") from e
         _lib = lib
         return lib
+
+
+def build_variant() -> str:
+    """Which inflate the loaded library was built with: ``libdeflate``
+    or ``zlib`` (the retry when libdeflate is absent — 2-3x slower at
+    inflate, same bytes).  Raises ImportError when it cannot build."""
+    _load()
+    return _so_build_info()[1]
 
 
 def _as_u8(buf) -> np.ndarray:

@@ -37,12 +37,7 @@ def _depth_psum_compiled(mesh, axis: str, n_windows: int):
     with the single-device scatter.  Padding rows carry window index
     ``n_windows`` (one past the last +1 slot) so they fall into the
     sliced-off tail on every device."""
-    from jax import lax
-
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from jax import lax, shard_map
 
     def body(w_lo, w_hi):
         diff = jnp.zeros(n_windows + 2, jnp.int32)

@@ -15,7 +15,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 FLAGSTAT_FIELDS = (
@@ -104,11 +104,6 @@ def _flagstat_sharded_compiled(mesh, axis: str, per: int):
     its axis index — global index < n), then one 12-lane ``psum`` over
     ICI merges the rows. The column never moves; only the 48-byte
     count row crosses d2h."""
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-
     def body(f, n):
         i = lax.axis_index(axis)
         base = (i * per).astype(jnp.int32)
@@ -191,11 +186,6 @@ def flagstat_counts(
     with hbm_resident(padded.nbytes + validity.nbytes):
         fd = jax.device_put(padded.reshape(n_shards, per), sharding)
         vd = jax.device_put(validity.reshape(n_shards, per), sharding)
-
-        try:
-            from jax import shard_map
-        except ImportError:
-            from jax.experimental.shard_map import shard_map
 
         def body(f, v):
             local = _counts(f.reshape(-1), v.reshape(-1))

@@ -473,13 +473,6 @@ def inflate_stacked(
     return out, meta
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # noqa: BLE001
-        return False
-
-
 def inflate_payloads(
     payloads: List[bytes], usizes=None, interpret=None
 ) -> List[bytes]:
@@ -508,7 +501,9 @@ def inflate_payloads(
     if usizes is not None:
         us[:b] = usizes
     if interpret is None:
-        interpret = not _on_tpu()
+        from disq_tpu.util import pallas_interpret
+
+        interpret = pallas_interpret()
     from disq_tpu.runtime.tracing import (
         count_transfer, device_span, hbm_resident)
 

@@ -1,13 +1,12 @@
-"""128-lane SIMD raw-DEFLATE inflate — the PROBES.md redesign.
+"""128-lane SIMD raw-DEFLATE inflate — lane-parallel streams.
 
 The north-star device codec (SURVEY.md §2.8 row 1, §7 step 2; reference
 behavior: htsjdk ``BlockCompressedInputStream`` + zlib ``Inflater``).
 The round-1 kernel (``ops/inflate.py``) decodes one block per grid
-program with a *scalar* state machine and is latency-bound at ~0.9 MB/s
-on a real chip; PROBES.md measures the scalar-core wall (~150 ns per
-data-dependent SMEM step) and concludes the only viable architecture is
-**lane-parallel SIMD**: 128 independent DEFLATE streams, one per vector
-lane, every piece of decoder state a ``(1, 128)`` vector.
+program with a *scalar* state machine, one data-dependent SMEM step
+after another, and is latency-bound. This kernel is **lane-parallel
+SIMD** instead: 128 independent DEFLATE streams, one per vector lane,
+every piece of decoder state a ``(1, 128)`` vector.
 
 Per superstep (one ``lax.while_loop`` iteration), every lane advances
 its own predicated state machine — header / stored / dynamic-table
@@ -17,15 +16,20 @@ are gated with ``pl.when``, and the refill/far-history sweeps behind
 ``lax.cond`` whole-warp gates. A lane emits 1 output byte per literal
 superstep, up to 4 per stored/short-copy superstep, and up to 8 (two
 output words) in the aligned steady state of a long match (d >= 8).
-All data-dependent indexing uses the one vector-gather primitive
-PROBES.md proved both correct and fast on the VPU: the one-hot row
-gather ``sum(where(row_iota == idx, data, 0))`` (54 ns over (512,128);
-``take_along_axis``/1-D gathers miscompile or crash Mosaic). Big-buffer
+All data-dependent indexing uses one vector-gather primitive, the
+one-hot row gather ``sum(where(row_iota == idx, data, 0))``: pure
+compares, selects and a sublane reduction, which Mosaic lowers for any
+row count (whether ``take_along_axis`` would now lower as well has not
+been tried on this compiler). Big-buffer
 sweeps (comp refill, output RMW, far-history reads) are additionally
 *windowed*: lanes advance in rough lockstep, so each slab's sweep is
-skipped when the live row window [min, max] misses it. Mosaic pitfall
-learned here: bool (1,128) vectors do not survive ``lax.cond`` return
-lowering — carry them as i32 across the branch.
+skipped when the live row window [min, max] misses it. Bool (1,128)
+vectors are carried as i32 across ``lax.cond`` branches, unsigned
+reductions and min run in i32: workarounds written against an earlier
+Mosaic. This installation (jax 0.9.0, libtpu 0.0.34) compiles the
+kernel as it stands, the largest geometry included (comp 4 MB + out
+8 MB + tables whole in VMEM, no ``vmem_limit_bytes``); whether it
+would also take the constructs those workarounds avoid is untested.
 
 Huffman decoding is bit-serial canonical (puff-style count/first/offset
 walk) rather than root-table driven: the per-length arrays are (16,128)
@@ -38,9 +42,7 @@ sorts over the code-length arrays.
 Memory (v1): compressed words, output words and all tables live whole
 in VMEM; history reads and output writes are one-hot sweeps over the
 full (OW,128) output. Correct and Mosaic-friendly, but the sweeps scale
-with buffer size — the measured-ring layout from PROBES.md (per-lane
-comp ring + tiered history + column-DMA refill) replaces them in the
-optimization pass.
+with buffer size; the windowed slab gates above are what bounds them.
 
 Error codes in meta row 1 (shared with ``ops/inflate.py``): 0 ok ·
 1 bad btype · 2 stored-LEN mismatch · 3 bad Huffman code · 4 invalid
@@ -180,9 +182,8 @@ def _gather_ref_win(ref, rows, slab: int = _SLAB):
 
 def _gather(data, rows):
     """One-hot row gather: data (R,128), rows (1,128) → (1,128).
-    The only per-lane dynamic-index read Mosaic compiles correctly
-    (PROBES.md 'Vector (VPU) facts'). Unsigned data is bitcast through
-    i32 — Mosaic has no unsigned reductions."""
+    The kernel's only per-lane dynamic-index read. Unsigned data is
+    bitcast through i32 — Mosaic has no unsigned reductions."""
     r = data.shape[0]
     unsigned = data.dtype == jnp.uint32
     if unsigned:
@@ -887,7 +888,10 @@ def _compiled(cw: int, ow: int, interpret: bool,
     return jax.jit(call, donate_argnums=nums)
 
 
-from disq_tpu.util import bucket_pow2 as _bucket  # noqa: E402 — shared policy
+from disq_tpu.util import (  # noqa: E402 — shared policy
+    bucket_pow2 as _bucket,
+    pallas_interpret as _pallas_interpret,
+)
 
 
 # ---------------------------------------------------------------------------
@@ -1069,8 +1073,8 @@ def host_inflate(p, expect: Optional[int] = None) -> bytes:
 
 def _fetch_chunk(handle, lanes: int):
     """Materialize one launched chunk under the synced kernel span
-    (PROBES.md: asarray, not block_until_ready, fences) and book the
-    D2H bytes; returns the lanes-major uint8 view + the meta rows."""
+    (the fetch itself waits for the kernel) and book the D2H bytes;
+    returns the lanes-major uint8 view + the meta rows."""
     words, meta = handle
     with _device_span("device.kernel", kernel="inflate_simd",
                       lanes=lanes) as fence:
@@ -1202,7 +1206,7 @@ def inflate_payloads_simd(
     an adaptive launch window (``dispatch_window``).
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = _pallas_interpret()
     keep_device = keep_device and as_array and usizes is not None
     n = len(payloads)
     if n == 0:

@@ -86,8 +86,8 @@ def parse_fixed_words_pallas(
 
     Called with concrete arrays (host entry) it books device telemetry
     — ``device.kernel_launches{kernel=parse}``, transfer bytes for a
-    host-side input, and a synced ``device.kernel`` span (PROBES.md:
-    only materialization fences).  Called under an enclosing trace
+    host-side input, and a ``device.kernel`` span fenced with
+    ``block_until_ready``.  Called under an enclosing trace
     (the device pipeline's jit) it is a passthrough: the outer caller
     owns the accounting and no host sync is possible mid-trace."""
     from jax.core import Tracer

@@ -159,13 +159,6 @@ def rans0_decode_stacked(
     return out.reshape(b, out_rows * 128), meta.reshape(b, 8 * 128)[:, :2]
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # noqa: BLE001
-        return False
-
-
 def rans0_decode_device(streams: List[bytes], interpret=None) -> List[bytes]:
     """Decode a batch of order-0 rANS 4x8 streams (full streams incl.
     the 9-byte header) on device. Tables parse host-side (O(alphabet));
@@ -213,7 +206,9 @@ def rans0_decode_device(streams: List[bytes], interpret=None) -> List[bytes]:
         freqs_arr[i] = freqs[:256]
         cums_arr[i] = cum
     if interpret is None:
-        interpret = not _on_tpu()
+        from disq_tpu.util import pallas_interpret
+
+        interpret = pallas_interpret()
     from disq_tpu.runtime.tracing import (
         count_transfer, device_span, hbm_resident)
 

@@ -1,12 +1,11 @@
 """128-lane SIMD rANS-4x8 order-0 decode — lane-parallel streams.
 
-Applies the PROBES.md lane-parallel architecture (proven by
-``ops/inflate_simd.py``) to CRAM's rANS order-0 external-block codec
+Applies the lane-parallel architecture of ``ops/inflate_simd.py``
+(one stream per vector lane) to CRAM's rANS order-0 external-block codec
 (htsjdk ``RANSExternalCompressor`` / htslib ``rANS_static``; CRAM 3.0
 §13 — SURVEY.md §2.8 CRAM row). The round-1 kernel (``ops/rans.py``)
 decodes one stream per grid program with a scalar state machine and is
-latency-bound at ~0.13 MB/s on a real chip; here 128 independent
-streams decode at once, one per vector lane, with every piece of
+latency-bound; here 128 independent streams decode at once, one per vector lane, with every piece of
 decoder state a ``(1, 128)`` vector.
 
 rANS maps onto lanes even better than DEFLATE because the decode
@@ -358,7 +357,9 @@ def rans0_decode_simd(
     which raises the same exceptions the host path always has.
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        from disq_tpu.util import pallas_interpret
+
+        interpret = pallas_interpret()
     n = len(streams)
     if n == 0:
         return []

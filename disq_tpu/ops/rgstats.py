@@ -133,13 +133,8 @@ def _rg_psum_kernel(mesh, axis: str, n_rg: int, per: int):
     locally, one psum over ICI merges the histograms."""
     import jax
     import jax.numpy as jnp
-    from jax import lax
+    from jax import lax, shard_map
     from jax.sharding import PartitionSpec as P
-
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
 
     def body(rg, mapq, flag, n):
         i = lax.axis_index(axis)

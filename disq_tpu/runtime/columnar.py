@@ -227,8 +227,9 @@ class ColumnarBatch:
         if n <= 0:
             return cls.from_host(ReadBatch.empty())
         if interpret is None:
-            jx = _jax_fns()["jax"]
-            interpret = jx.default_backend() != "tpu"
+            from disq_tpu.util import pallas_interpret
+
+            interpret = pallas_interpret()
         self = cls()
         self._n = n
         self._blob = blob

@@ -6,9 +6,9 @@ the per-shard dispatch in ``bgzf/codec.py`` / ``cram/rans.py`` submits
 one shard's blocks at a time — a shard with 40 BGZF blocks launches a
 40/128-full chunk, and N executor decode workers each do so
 *concurrently*, so the device sees N partial launches instead of the
-few full ones the work actually needs (TPU_KERNELS.json: 54.2 MB/s
-kernel-only vs 17.96 MB/s end-to-end — the whole gap is host packing,
-per-chunk allocation and partial lanes).
+few full ones the work actually needs (``TPU_KERNELS.json`` carries the
+kernel-only and end-to-end rows; the gap between them is host packing,
+per-chunk allocation, transfers and partial lanes).
 
 This module inverts the ownership, the way "Extending TensorFlow's
 Semantics with Pipelined Execution" overlaps producer/consumer stages:
@@ -352,9 +352,9 @@ class DeviceDecodeService:
                 os.environ.get("DISQ_TPU_SERVICE_FLUSH_MS", "2")) / 1e3
         self.flush_timeout_s = flush_timeout_s
         if interpret is None:
-            import jax
+            from disq_tpu.util import pallas_interpret
 
-            interpret = jax.default_backend() != "tpu"
+            interpret = pallas_interpret()
         # outstanding fire-and-forget host-fallback lanes (drained at
         # close so shutdown never strands a waiter); the pool itself is
         # the process-wide disq_tpu.util.shared_host_pool
@@ -375,6 +375,7 @@ class DeviceDecodeService:
         self._queues: Dict[str, List[Deque[_Lane]]] = {
             k: [deque() for _ in range(n_dev)]
             for k in ("inflate", "rans", "deflate")}
+        self._next_queue = 0  # tie-break rotation (see _enqueue)
         self._inflight: Deque[Tuple[str, Any, List[_Lane]]] = deque()
         self._closed = False
         # window sized for the standard full-BGZF geometry; the env
@@ -495,11 +496,16 @@ class DeviceDecodeService:
                 raise RuntimeError("device decode service is closed")
             # least-loaded device sub-queue takes the whole batch (one
             # submission's lanes stay together — they share pack
-            # geometry and error scope); with one device this is the
-            # old single-queue append
+            # geometry and error scope); ties rotate, or a stream of
+            # small submissions that each drain before the next arrives
+            # (serve queries, tiny splits) would all land on device 0.
+            # With one device this is the old single-queue append
             subqs = self._queues[kind]
-            subqs[min(range(len(subqs)),
-                      key=lambda i: len(subqs[i]))].extend(lanes)
+            n_q = len(subqs)
+            pick = min(range(n_q), key=lambda i: (
+                len(subqs[i]), (i - self._next_queue) % n_q))
+            self._next_queue = (pick + 1) % n_q
+            subqs[pick].extend(lanes)
             depth = sum(
                 len(q) for qs in self._queues.values() for q in qs)
             if sub._pending <= 0:

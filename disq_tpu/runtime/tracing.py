@@ -52,10 +52,10 @@ against the README table):
   ``device.host_fallback_blocks{reason=}``, the ``device.hbm_bytes``
   live-footprint gauge, and ``device.kernel`` / ``device.transfer``
   spans.  Device spans are timed by ``device_span`` /
-  ``synced_timer``, which **materialize a sentinel element** of the
-  kernel's output before closing — PROBES.md: ``block_until_ready``
-  does not sync on this platform, so an unmaterialized timing
-  under-reports arbitrarily.
+  ``synced_timer``, which ``jax.block_until_ready`` the kernel's
+  output before closing — dispatch is asynchronous, so an unfenced
+  timing measures the enqueue (``chip_smoke.py`` times one long
+  kernel both ways on every run and fails if the fence does not hold).
 - ``telemetry.*`` — self-observation (``telemetry.dropped_spans``).
 
 Back-compat: ``trace_phase`` / ``record_phase`` / ``phase_report`` /
@@ -829,28 +829,10 @@ def wrap_span(name: str, fn: Callable, **labels: Any) -> Callable:
 # Device telemetry: synced kernel spans, transfer counters, HBM gauge
 # ---------------------------------------------------------------------------
 
-def _materialize_sentinel(value: Any) -> None:
-    """Truly wait for every jax array in ``value`` (a pytree) by
-    materializing ONE element of each.  ``block_until_ready`` does not
-    block on this platform (PROBES.md measurement caveats) — only
-    ``np.asarray`` syncs — so a sentinel fetch is the cheapest honest
-    fence: a one-element slice dispatches after the producing kernel
-    and costs a few bytes of D2H, not the whole result."""
-    try:
-        import jax
-        from jax.core import Tracer
-        import numpy as _np
-    except ImportError:  # host-only deployment: nothing to sync
-        return
-    for leaf in jax.tree_util.tree_leaves(value):
-        if isinstance(leaf, jax.Array) and not isinstance(leaf, Tracer):
-            _np.asarray(leaf.ravel()[:1] if leaf.ndim else leaf)
-
-
 class _DeviceSync:
     """Handle yielded by ``device_span``: the body registers its device
-    outputs with ``sync(...)``; span close materializes one sentinel
-    element of each so the recorded duration covers real execution."""
+    outputs with ``sync(...)``; span close blocks until they are ready
+    so the recorded duration covers real execution."""
 
     __slots__ = ("_values",)
 
@@ -864,19 +846,21 @@ class _DeviceSync:
         self._values.extend(values)
         return values[0] if len(values) == 1 else values
 
-    def materialize(self) -> None:
-        for v in self._values:
-            _materialize_sentinel(v)
-        self._values.clear()
+    def block(self) -> None:
+        if self._values:
+            import jax
+
+            # tracers and non-array leaves pass through untouched
+            jax.block_until_ready(self._values)
+            self._values.clear()
 
 
 @contextlib.contextmanager
 def device_span(name: str, **labels: Any) -> Iterator[_DeviceSync]:
     """Span over device work whose close is a true sync point: the body
     hands its output arrays to ``.sync(...)`` and span exit
-    materializes a one-element sentinel of each before taking the end
-    timestamp (the PROBES.md caveat: unmaterialized device timings
-    under-report arbitrarily).  Also books one
+    ``jax.block_until_ready``s them before taking the end timestamp
+    (an unfenced device timing measures the enqueue).  Also books one
     ``device.kernel_launches`` increment when a ``kernel=`` label is
     present, so every synced kernel span is a counted launch."""
     _resolve_span_env()
@@ -888,14 +872,14 @@ def device_span(name: str, **labels: Any) -> Iterator[_DeviceSync]:
     try:
         yield handle
     finally:
-        handle.materialize()
+        handle.block()
         _emit_span(name, t0, time.perf_counter() - t0, labels)
 
 
 def synced_timer(name: str, **labels: Any) -> Callable:
     """Decorator form of ``device_span``: times the wrapped function
-    and materializes a sentinel of its return value before the span
-    closes — for ops entry points whose return IS the device output."""
+    and blocks on its return value before the span closes — for ops
+    entry points whose return IS the device output."""
     def deco(fn: Callable) -> Callable:
         def wrapped(*args: Any, **kwargs: Any):
             with device_span(name, **labels) as fence:

@@ -39,19 +39,70 @@ def shared_host_pool():
         return _HOST_POOL
 
 
+def pallas_interpret() -> bool:
+    """THE decision whether a device kernel runs in Pallas interpret
+    mode — every kernel entry point asks here, nowhere else.
+
+    False on a TPU backend: kernels compile for the chip.  True only
+    when the process explicitly selected the CPU platform
+    (``JAX_PLATFORMS=cpu`` / ``jax.config.jax_platforms`` — the test
+    route, where the interpreter is the point).  Any other backend
+    means jax did not attach the chip the armed device knob asked for
+    (it dropped to CPU on its own, or found some other accelerator);
+    interpreting there would return right answers from the wrong
+    machine, so it raises instead."""
+    import jax
+
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    requested = (jax.config.jax_platforms or "").split(",")[0].strip()
+    if backend == "cpu" and requested == "cpu":
+        return True
+    raise RuntimeError(
+        f"device kernels need a TPU backend, but jax.default_backend() "
+        f"is {backend!r} and the platform was not explicitly set to cpu "
+        f"(jax_platforms={jax.config.jax_platforms!r}). Set "
+        f"JAX_PLATFORMS=cpu to run them in Pallas interpret mode, or "
+        f"disarm the DISQ_TPU_DEVICE_* / resident-decode knobs.")
+
+
+def enable_compile_cache() -> str:
+    """Turn on jax's persistent compilation cache and return its
+    directory.  Called by the program's entry points (``chip_smoke.py``,
+    ``bench.py``, ``scripts/serve.py``, ``python -m disq_tpu.ops.tpu_ci``)
+    before their first compile — never by library import.
+
+    ``JAX_COMPILATION_CACHE_DIR`` set: jax reads it on its own, so this
+    sets nothing.  Unset: the cache lives at ``<checkout>/.jax_cache``,
+    resolved from this package's own path — a fixed place, because the
+    path is part of what a later run must find again (never a temp dir,
+    a pid or a timestamp)."""
+    import os
+
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+
+    path = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 def resolve_num_shards(storage) -> int:
     """Shard count for write paths: the storage's ``num_shards`` override,
-    else the attached device count, else 1. Single source of truth for
-    every sink (BAM/SAM/VCF/CRAM)."""
+    else the attached device count. Single source of truth for every
+    sink (BAM/SAM/VCF/CRAM). A backend that fails to initialize raises
+    here — a chip that did not attach must not turn into one shard."""
     n = getattr(storage, "_num_shards", None)
     if n:
         return n
-    try:
-        import jax
+    import jax
 
-        return len(jax.devices())
-    except Exception:
-        return 1
+    return len(jax.devices())
 
 
 def shard_bounds(storage, count: int):

@@ -31,9 +31,25 @@
 // with -DDISQ_HAVE_LIBDEFLATE -ldeflate and retries without on failure.
 #ifdef DISQ_HAVE_LIBDEFLATE
 #include <libdeflate.h>
+#define DISQ_VARIANT "libdeflate"
+#else
+#define DISQ_VARIANT "zlib"
+#endif
+
+// The Python builder passes the SHA-256 of this file; the loader finds
+// this string in the library's bytes and compares it with the source on
+// disk, so a library that was copied (mtimes lost) or built from an
+// older source is rebuilt, never reused.
+#ifndef DISQ_SRC_HASH
+#define DISQ_SRC_HASH "unknown"
 #endif
 
 extern "C" {
+
+// "disq-build:<source sha256>:<inflate variant>:" (exported, so the
+// linker keeps it).
+extern const char disq_build_info[] =
+    "disq-build:" DISQ_SRC_HASH ":" DISQ_VARIANT ":";
 
 // Walk the BAM record chain: buf holds concatenated records; writes up to
 // max_out offsets (of each record start) into out_offsets and finally the

@@ -500,7 +500,7 @@ def breaker_leg(path, baseline) -> str:
 
 _ABORT_CHILD = r"""
 import os, sys
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+os.environ["JAX_PLATFORMS"] = "cpu"  # child: never the parent's chip
 sys.path.insert(0, {repo!r})
 from disq_tpu import ReadsStorage, WatchdogStallError
 from disq_tpu.fsw import (FaultInjectingFileSystemWrapper, FaultSpec,
@@ -592,7 +592,7 @@ def postmortem_check(tmp) -> str:
 
 _STEAL_CHILD = r"""
 import hashlib, json, os, sys, time
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+os.environ["JAX_PLATFORMS"] = "cpu"  # child: never the parent's chip
 sys.path.insert(0, {repo!r})
 import numpy as np
 from disq_tpu import ReadsStorage
@@ -748,7 +748,7 @@ def _steal_leg_body(addr, path, repo, want) -> str:
 
 _KILL_CHILD = r"""
 import os, sys
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+os.environ["JAX_PLATFORMS"] = "cpu"  # child: never the parent's chip
 sys.path.insert(0, {repo!r})
 from disq_tpu import DisqOptions, ReadsStorage
 from disq_tpu.api import StageManifestWriteOption
@@ -870,7 +870,7 @@ def kill_leg(path, tmp) -> str:
 
 _COORD_KILL_CHILD = r"""
 import hashlib, json, os, sys
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+os.environ["JAX_PLATFORMS"] = "cpu"  # child: never the parent's chip
 sys.path.insert(0, {repo!r})
 import numpy as np
 from disq_tpu import ReadsStorage
@@ -1263,7 +1263,7 @@ _FLEET_REPLICA_CODE = r"""
 import json, os, sys
 cfg = json.loads(sys.argv[1])
 sys.path.insert(0, cfg["repo"])
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+os.environ["JAX_PLATFORMS"] = "cpu"  # child: never the parent's chip
 from disq_tpu.runtime import serve as serve_mod
 addr = serve_mod.start_serve(port=0, tenant_slots=8, tenant_queue=32)
 serve_mod.serve_if_running().register("soak", cfg["bam"])

@@ -34,8 +34,6 @@ import sys
 sys.path.insert(
     0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
@@ -94,7 +92,11 @@ def main(argv=None) -> int:
               flush=True)
     else:
         from disq_tpu.api import serve
+        from disq_tpu.util import enable_compile_cache
 
+        # The launcher chooses no platform: jax takes the accelerator
+        # when there is one, and JAX_PLATFORMS is the operator's to set.
+        enable_compile_cache()
         handle = serve(
             datasets, port=args.port,
             tenant_slots=args.tenant_slots,

@@ -1,5 +1,6 @@
 """Regression tests for the round-1 advisor findings (ADVICE.md) and the
-round-1 verdict's silent-fallback item (VERDICT.md next-round #8)."""
+round-1 review's silent-fallback item (a poisoned mesh sort must
+raise)."""
 
 import numpy as np
 import pytest
@@ -75,7 +76,7 @@ class TestShardedSortReadBatch:
 
 
 class TestRaggedBytesOnMesh:
-    """VERDICT r4 item 5: name/cigar/seq/qual/tag bytes travel through
+    """Name/cigar/seq/qual/tag bytes travel through
     the sort exchange itself — the success path never touches the
     host-side segment gather."""
 
@@ -123,7 +124,7 @@ class TestRaggedBytesOnMesh:
 
 
 class TestNoSilentFallback:
-    """VERDICT #8: a poisoned mesh sort must raise, not silently degrade
+    """A poisoned mesh sort must raise, not silently degrade
     to the host argsort."""
 
     def test_poisoned_mesh_sort_raises(self, monkeypatch):

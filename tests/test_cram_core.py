@@ -1,4 +1,4 @@
-"""CRAM CORE-block bit codecs + rANS order-1 encode (VERDICT r4 item 7).
+"""CRAM CORE-block bit codecs + rANS order-1 encode.
 
 Foreign htsjdk/samtools CRAMs route data series through CORE-block bit
 codecs — canonical Huffman, BETA, GAMMA, SUBEXP — which the reader now

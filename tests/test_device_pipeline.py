@@ -1,4 +1,4 @@
-"""Device-resident read pipeline (VERDICT r4 item 4).
+"""Device-resident read pipeline.
 
 The decisive assertion is the transfer guard: the parse → keys → sort
 → flagstat step runs under ``jax.transfer_guard("disallow")``, so ANY

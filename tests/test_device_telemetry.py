@@ -1,6 +1,6 @@
 """Device observability (``runtime/tracing.py`` device helpers +
 ``runtime/device_pipeline.py`` + the ``ops/`` entry points): synced
-kernel spans (the PROBES.md materialize-to-sync caveat), transfer-byte
+kernel spans (fenced with ``block_until_ready``), transfer-byte
 counters that match what is actually uploaded (alignment pad
 included), the live-HBM gauge, the host-fallback counter, and the
 device track in the Chrome export."""
@@ -79,7 +79,7 @@ class TestDeviceSpanHelpers:
             pass
         assert REGISTRY.counter("device.kernel_launches").total() == 0
 
-    def test_sentinel_handles_pytrees_and_scalars(self):
+    def test_fence_handles_pytrees_and_scalars(self):
         with device_span("device.kernel", kernel="tree") as fence:
             fence.sync({"a": jnp.ones((2, 3)), "b": [jnp.float32(1.5)]})
             fence.sync(np.arange(4))  # non-jax values pass through
@@ -169,8 +169,7 @@ class TestDevicePipelineTelemetry:
         # upload accounting is exact: word-padded blob + i32 starts
         pad = (-len(blob)) % 4
         assert h2d == (len(blob) + pad) + 4 * (len(offs) - 1)
-        # fetched results: hi/lo keys u32 + order i32 + flagstat, plus
-        # the span's one-element sync sentinel
+        # fetched results: hi/lo keys u32 + order i32 + flagstat
         n = len(offs) - 1
         assert d2h >= 3 * 4 * n
         assert REGISTRY.counter("device.kernel_launches").value(

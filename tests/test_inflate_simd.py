@@ -1,6 +1,6 @@
 """128-lane SIMD inflate kernel vs zlib (byte equality).
 
-Milestone ladder from PROBES.md "Design conclusion": (a) fixed-Huffman +
+Milestone ladder: (a) fixed-Huffman +
 stored blocks, (b) dynamic-Huffman table build. The oracle is zlib
 itself: every payload here is produced by ``zlib.compressobj`` with a
 controlled strategy/level and must round-trip byte-identically.

@@ -180,10 +180,7 @@ class TestGlobalMesh:
         from functools import partial
         from jax.sharding import PartitionSpec as P
 
-        try:
-            from jax import shard_map
-        except ImportError:
-            from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         mesh = global_mesh()
         n = mesh.shape["dcn"] * mesh.shape["shards"]

@@ -150,3 +150,12 @@ class TestSegmentGatherNative:
         got_f, _ = segment_gather_native(
             flat, np.array([0, 2, 5, 9], np.int64), np.array([1]))
         assert got_f.tolist() == [2, 3, 4]
+
+
+def test_build_stamp_matches_source_and_names_variant():
+    """Staleness is keyed on the source hash baked into the library (a
+    copied tree keeps no mtimes), and the build says which inflate it
+    linked — the zlib-only retry must never pass for libdeflate."""
+    assert native.build_variant() in ("libdeflate", "zlib")
+    assert native._so_build_info() == (
+        native._src_hash(), native.build_variant())
