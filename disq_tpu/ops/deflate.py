@@ -57,7 +57,6 @@ from disq_tpu.bgzf.block import BGZF_MAX_PAYLOAD as BLOCK_PAYLOAD
 from disq_tpu.runtime.tracing import (
     count_transfer as _count_transfer,
     counter as _counter,
-    device_span as _device_span,
     span as _span,
 )
 
@@ -373,14 +372,11 @@ class DeflateTable:
             return self._luts
 
 
-def launch_chunk(payloads: Sequence, table: DeflateTable,
-                 cw: Optional[int] = None):
+def pack_chunk(payloads: Sequence, cw: Optional[int] = None):
     """Pack one <=128-lane payload chunk into a pooled staging arena
-    and launch the batched encoder; returns an opaque handle for
-    ``fetch_chunk``.  Payloads may be ``memoryview`` slices — nothing
+    (host work only); returns ``(comp, clen, arena, cw)`` for
+    ``submit_chunk``.  Payloads may be ``memoryview`` slices — nothing
     here copies the uncompressed bytes besides the arena pack."""
-    import jax.numpy as jnp
-
     from disq_tpu.ops import inflate_simd as IS
 
     if cw is None:
@@ -388,6 +384,21 @@ def launch_chunk(payloads: Sequence, table: DeflateTable,
     arena = IS.ARENAS.acquire(("deflate", cw), lambda: IS._PackArena(cw))
     try:
         comp, clen = IS._pack_chunk(payloads, cw, arena)
+    except BaseException:
+        IS.ARENAS.release(("deflate", cw), arena)
+        raise
+    return comp, clen, arena, cw
+
+
+def submit_chunk(packed, table: DeflateTable):
+    """Upload a packed chunk and launch the batched encoder; returns an
+    opaque handle for ``fetch_chunk``."""
+    import jax.numpy as jnp
+
+    from disq_tpu.ops import inflate_simd as IS
+
+    comp, clen, arena, cw = packed
+    try:
         _count_transfer("h2d", comp.nbytes + clen.nbytes)
         code_lut, len_lut = table.luts()
         fn = _compiled(cw, table.out_bytes)
@@ -398,6 +409,13 @@ def launch_chunk(payloads: Sequence, table: DeflateTable,
         IS.ARENAS.release(("deflate", cw), arena)
         raise
     return out, arena, cw
+
+
+def launch_chunk(payloads: Sequence, table: DeflateTable,
+                 cw: Optional[int] = None):
+    """``pack_chunk`` then ``submit_chunk`` (the decode service calls
+    the two itself, a span round each)."""
+    return submit_chunk(pack_chunk(payloads, cw), table)
 
 
 def release_chunk_arena(handle) -> None:
@@ -424,22 +442,34 @@ def launch_resident(comp_cols, clen: np.ndarray,
     return out, None, cw
 
 
-def fetch_chunk(handle, table: DeflateTable, lanes: int):
-    """Materialize one launched chunk under the synced kernel span:
-    the end-bit row first, then ONLY the occupied body prefix — d2h
-    carries compressed bytes, not the worst-case buffer (the inverse
-    of the readback-bound economics in the module header)."""
-    out = handle[0]
-    bodies_dev, end_dev = out
-    with _device_span("device.kernel", kernel="deflate_simd",
-                      lanes=lanes) as fence:
-        end = np.asarray(fence.sync(end_dev)).reshape(-1)
+def fetch_chunk(handle, table: DeflateTable, lanes: int,
+                labels: Optional[dict] = None):
+    """Materialize one launched chunk: wait for the device
+    (``device.launch.wait``), then fetch (``device.launch.d2h``) ONLY
+    the occupied body prefix — d2h carries compressed bytes, not the
+    worst-case buffer (the inverse of the readback-bound economics in
+    the module header).  The wait covers the encoder, the end-bit row
+    (4 B a lane, which says how wide the prefix is) and the device
+    slice that cuts the bodies to it, so that the d2h span is the copy
+    alone and its ``bytes`` are known as it opens; the copy blocked on
+    the slice before, so no fence is added.  ``labels`` are the
+    spans', as in ``inflate_simd._fetch_chunk``."""
+    import jax
+
+    bodies_dev, end_dev = handle[0]
+    if labels is None:
+        labels = {"kind": "deflate", "lanes": lanes}
+    _counter("device.kernel_launches").inc(kernel="deflate_simd")
+    with _span("device.launch.wait", **labels):
+        end = np.asarray(end_dev).reshape(-1)
         top = int(end[:lanes].max()) if lanes else 0
         need = (top + table.eob_len + 7) // 8 + 2
         # quantize the fetch width so slice shapes hit a small compile
         # cache instead of one executable per chunk
         need = min(table.out_bytes, (need + 1023) // 1024 * 1024)
-        bodies = np.asarray(bodies_dev[:, :need])
+        prefix = jax.block_until_ready(bodies_dev[:, :need])
+    with _span("device.launch.d2h", bytes=prefix.nbytes, **labels):
+        bodies = np.asarray(prefix)
     _count_transfer("d2h", bodies.nbytes + end.nbytes)
     return bodies, end
 

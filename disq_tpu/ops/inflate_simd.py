@@ -77,8 +77,8 @@ from disq_tpu.ops.inflate import (
 from disq_tpu.runtime.tracing import (
     count_transfer as _count_transfer,
     counter as _counter,
-    device_span as _device_span,
     gauge as _gauge,
+    span as _span,
     track_hbm as _track_hbm,
 )
 
@@ -1071,16 +1071,27 @@ def host_inflate(p, expect: Optional[int] = None) -> bytes:
     return host
 
 
-def _fetch_chunk(handle, lanes: int):
-    """Materialize one launched chunk under the synced kernel span
-    (the fetch itself waits for the kernel) and book the D2H bytes;
-    returns the lanes-major uint8 view + the meta rows."""
+def _fetch_chunk(handle, lanes: int,
+                 labels: Optional[Dict[str, Any]] = None,
+                 kernel: str = "inflate_simd"):
+    """Materialize one launched chunk and book the D2H bytes; returns
+    the lanes-major uint8 view + the meta rows.  Two spans, so a
+    launch's time splits into kernel and transfer: ``device.launch.wait``
+    (blocked on the kernel) and then ``device.launch.d2h`` (the copy
+    alone).  ``np.asarray`` blocked here before the split, so no fence
+    is added.  ``labels`` are the spans' (the decode service passes the
+    ones that join a launch's spans: ``kind``, ``lanes``, ``launch``)."""
     words, meta = handle
-    with _device_span("device.kernel", kernel="inflate_simd",
-                      lanes=lanes) as fence:
-        words = np.asarray(fence.sync(words))
+    if labels is None:
+        labels = {"kind": "inflate", "lanes": lanes}
+    _counter("device.kernel_launches").inc(kernel=kernel)
+    with _span("device.launch.wait", **labels):
+        jax.block_until_ready((words, meta))
+    nbytes = words.nbytes + meta.nbytes
+    with _span("device.launch.d2h", bytes=nbytes, **labels):
+        words = np.asarray(words)
         meta = np.asarray(meta)
-    _count_transfer("d2h", words.nbytes + meta.nbytes)
+    _count_transfer("d2h", nbytes)
     return words.view(np.uint8), meta
 
 

@@ -60,6 +60,7 @@ from disq_tpu.ops.inflate_simd import (
     ARENAS,
     LANES,
     _bucket,
+    _fetch_chunk as _inflate_fetch_chunk,
     _gather,
     _gather_ref_win,
     _pack_chunk,
@@ -70,7 +71,6 @@ from disq_tpu.ops.inflate_simd import (
 from disq_tpu.runtime.tracing import (
     count_transfer as _count_transfer,
     counter as _counter,
-    device_span as _device_span,
 )
 
 RANS_LOW = 1 << 23
@@ -334,16 +334,13 @@ def pack_lane_tables(metas, cw: int, arena: Optional[_PackArena] = None):
     return comp, clen, raws, states, freq, cum
 
 
-def _fetch_chunk(handle, lanes: int):
-    """Materialize one launched rANS chunk under the synced kernel span
-    and book the D2H bytes; returns (lanes-major u8 view, meta)."""
-    words, meta = handle
-    with _device_span("device.kernel", kernel="rans_simd",
-                      lanes=lanes) as fence:
-        words = np.asarray(fence.sync(words))
-        meta = np.asarray(meta)
-    _count_transfer("d2h", words.nbytes + meta.nbytes)
-    return words.view(np.uint8), meta
+def _fetch_chunk(handle, lanes: int, labels=None):
+    """Materialize one launched rANS chunk (the inflate codec's wait
+    and d2h spans, ``kind="rans"``) and book the D2H bytes; returns
+    (lanes-major u8 view, meta)."""
+    return _inflate_fetch_chunk(
+        handle, lanes, labels or {"kind": "rans", "lanes": lanes},
+        kernel="rans_simd")
 
 
 def rans0_decode_simd(

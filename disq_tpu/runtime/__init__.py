@@ -174,11 +174,9 @@ from disq_tpu.runtime.manifest import (  # noqa: F401
 from disq_tpu.runtime.tracing import (  # noqa: F401
     REGISTRY,
     MetricsRegistry,
-    chrome_trace_events,
     count_transfer,
     counter,
     device_span,
-    export_chrome_trace,
     gauge,
     hbm_resident,
     synced_timer,

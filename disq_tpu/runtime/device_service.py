@@ -46,7 +46,14 @@ per-shard dispatch did.
 Telemetry: ``device.lane_fill`` (lanes per launch / 128),
 ``device.queue_depth``, ``device.batch.flush{reason=full|timeout|drain}``,
 ``device.service.wait`` (oldest-lane queue wait per flushed chunk) and
-the arena pool's ``device.arena_bytes``.
+the arena pool's ``device.arena_bytes``.  The dispatcher thread's own
+time is tiled by six spans a launch, joined by ``kind``, ``lanes`` and
+the per-service sequence number ``launch``: ``device.service.idle``
+(asleep since the previous launch; booked as the launch begins, also
+when 0, and once more at close for the sleep that close ended),
+``device.launch.pack`` (host lane packing), ``.submit`` (upload +
+enqueue), ``.wait`` (blocked on the kernel), ``.d2h`` (the copy back)
+and ``.deliver`` (lanes handed to their submissions).
 
 Enablement: ``DISQ_TPU_DEVICE_SERVICE=1`` — checked by the codec entry
 points alongside ``DISQ_TPU_DEVICE_INFLATE`` / ``DISQ_TPU_DEVICE_RANS``.
@@ -70,6 +77,7 @@ from disq_tpu.runtime.tracing import (
     current_trace as _current_trace,
     observe_gauge as _observe_gauge,
     record_span as _record_span,
+    span as _span,
     trace_scope as _trace_scope,
 )
 
@@ -185,50 +193,59 @@ class _InflateEngine:
         self._interpret = bool(interpret)
         self._host_map = host_map
 
-    def launch(self, lanes: Sequence[_Lane]):
+    def launch(self, lanes: Sequence[_Lane], labels: Dict[str, Any]):
         import jax.numpy as jnp
 
         from disq_tpu.ops import inflate_simd as IS
 
         payloads = [l.payload for l in lanes]
-        cw, ow = IS.buckets_for(
-            payloads, max(l.expect for l in lanes))
-        arena = IS.ARENAS.acquire(
-            ("inflate", cw), lambda: IS._PackArena(cw))
+        arena = None
         try:
-            comp, clen = IS._pack_chunk(payloads, cw, arena)
-            IS._count_transfer("h2d", comp.nbytes + clen.nbytes)
-            fn = IS._compiled(cw, ow, self._interpret, True, True)
-            out = fn(jnp.asarray(comp), jnp.asarray(clen),
-                     *IS._device_const_tables())
+            with _span("device.launch.pack", **labels):
+                cw, ow = IS.buckets_for(
+                    payloads, max(l.expect for l in lanes))
+                arena = IS.ARENAS.acquire(
+                    ("inflate", cw), lambda: IS._PackArena(cw))
+                comp, clen = IS._pack_chunk(payloads, cw, arena)
+            nbytes = comp.nbytes + clen.nbytes
+            with _span("device.launch.submit", bytes=nbytes, **labels):
+                IS._count_transfer("h2d", nbytes)
+                fn = IS._compiled(cw, ow, self._interpret, True, True)
+                out = fn(jnp.asarray(comp), jnp.asarray(clen),
+                         *IS._device_const_tables())
         except BaseException:
-            IS.ARENAS.release(("inflate", cw), arena)
+            if arena is not None:
+                IS.ARENAS.release(("inflate", cw), arena)
             raise
         return out, arena, cw
 
-    def finalize(self, handle, lanes: Sequence[_Lane]) -> None:
+    def finalize(self, handle, lanes: Sequence[_Lane],
+                 labels: Dict[str, Any]) -> None:
         from disq_tpu.ops import inflate_simd as IS
 
         out, arena, cw = handle
         try:
-            lanes_u8, meta = IS._fetch_chunk(out, len(lanes))
-        finally:
+            lanes_u8, meta = IS._fetch_chunk(out, len(lanes), labels)
+        except BaseException:
             IS.ARENAS.release(("inflate", cw), arena)
-        flagged: List[_Lane] = []
-        for j, lane in enumerate(lanes):
-            n, status = int(meta[0, j]), int(meta[1, j])
-            if status != 0 or n != lane.expect:
-                IS.last_stats["host_fallback"] += 1
-                _counter("device.host_fallback_blocks").inc(
-                    reason="flagged")
-                flagged.append(lane)
-            else:
-                IS.last_stats["device_lanes"] += 1
-                lane.sub.deliver(lane.index, lanes_u8[j, :n])
-        if flagged:
-            self._host_map(
-                flagged,
-                lambda lane: IS.host_inflate(lane.payload, lane.expect))
+            raise
+        with _span("device.launch.deliver", **labels):
+            IS.ARENAS.release(("inflate", cw), arena)
+            flagged: List[_Lane] = []
+            for j, lane in enumerate(lanes):
+                n, status = int(meta[0, j]), int(meta[1, j])
+                if status != 0 or n != lane.expect:
+                    IS.last_stats["host_fallback"] += 1
+                    _counter("device.host_fallback_blocks").inc(
+                        reason="flagged")
+                    flagged.append(lane)
+                else:
+                    IS.last_stats["device_lanes"] += 1
+                    lane.sub.deliver(lane.index, lanes_u8[j, :n])
+            if flagged:
+                self._host_map(
+                    flagged,
+                    lambda lane: IS.host_inflate(lane.payload, lane.expect))
 
 
 class _RansEngine:
@@ -243,48 +260,59 @@ class _RansEngine:
         self._interpret = bool(interpret)
         self._host_map = host_map
 
-    def launch(self, lanes: Sequence[_Lane]):
+    def launch(self, lanes: Sequence[_Lane], labels: Dict[str, Any]):
         import jax.numpy as jnp
 
         from disq_tpu.ops import inflate_simd as IS
         from disq_tpu.ops import rans_simd as RS
 
         metas = [l.payload[1] for l in lanes]
-        cw, ow = RS.kernel_geometry(metas)
-        arena = IS.ARENAS.acquire(("rans", cw),
-                                  lambda: RS._rans_arena(cw))
+        arena = None
         try:
-            args = RS.pack_lane_tables(metas, cw, arena)
-            IS._count_transfer("h2d", sum(a.nbytes for a in args))
-            fn = RS._compiled(cw, ow, self._interpret, True, True)
-            out = fn(*(jnp.asarray(a) for a in args))
+            with _span("device.launch.pack", **labels):
+                cw, ow = RS.kernel_geometry(metas)
+                arena = IS.ARENAS.acquire(("rans", cw),
+                                          lambda: RS._rans_arena(cw))
+                args = RS.pack_lane_tables(metas, cw, arena)
+            nbytes = sum(a.nbytes for a in args)
+            with _span("device.launch.submit", bytes=nbytes, **labels):
+                IS._count_transfer("h2d", nbytes)
+                fn = RS._compiled(cw, ow, self._interpret, True, True)
+                out = fn(*(jnp.asarray(a) for a in args))
         except BaseException:
-            IS.ARENAS.release(("rans", cw), arena)
+            if arena is not None:
+                IS.ARENAS.release(("rans", cw), arena)
             raise
         return out, arena, cw
 
-    def finalize(self, handle, lanes: Sequence[_Lane]) -> None:
+    def finalize(self, handle, lanes: Sequence[_Lane],
+                 labels: Dict[str, Any]) -> None:
         from disq_tpu.ops import inflate_simd as IS
         from disq_tpu.ops import rans_simd as RS
 
         out, arena, cw = handle
         try:
-            lanes_u8, meta = RS._fetch_chunk(out, len(lanes))
-        finally:
+            lanes_u8, meta = RS._fetch_chunk(out, len(lanes), labels)
+        except BaseException:
             IS.ARENAS.release(("rans", cw), arena)
-        flagged: List[_Lane] = []
-        for j, lane in enumerate(lanes):
-            if int(meta[1, j]) != 0:
-                RS.last_stats["host_fallback"] += 1
-                _counter("device.host_fallback_blocks").inc(
-                    reason="flagged")
-                flagged.append(lane)
-            else:
-                RS.last_stats["device_lanes"] += 1
-                lane.sub.deliver(lane.index, lanes_u8[j, : lane.expect])
-        if flagged:
-            self._host_map(
-                flagged, lambda lane: RS._host_decode0(lane.payload[0]))
+            raise
+        with _span("device.launch.deliver", **labels):
+            IS.ARENAS.release(("rans", cw), arena)
+            flagged: List[_Lane] = []
+            for j, lane in enumerate(lanes):
+                if int(meta[1, j]) != 0:
+                    RS.last_stats["host_fallback"] += 1
+                    _counter("device.host_fallback_blocks").inc(
+                        reason="flagged")
+                    flagged.append(lane)
+                else:
+                    RS.last_stats["device_lanes"] += 1
+                    lane.sub.deliver(lane.index,
+                                     lanes_u8[j, : lane.expect])
+            if flagged:
+                self._host_map(
+                    flagged,
+                    lambda lane: RS._host_decode0(lane.payload[0]))
 
 
 class _DeflateEngine:
@@ -310,34 +338,43 @@ class _DeflateEngine:
         # accepted for engine-construction symmetry but unused
         self._host_map = host_map
 
-    def launch(self, lanes: Sequence[_Lane]):
+    def launch(self, lanes: Sequence[_Lane], labels: Dict[str, Any]):
         from disq_tpu.ops import deflate as DF
 
         payloads = [l.payload[0] for l in lanes]
-        freq = np.zeros(256, np.int64)
-        for l in lanes:
-            freq += l.payload[1]
-        table = DF.DeflateTable(freq, len(lanes))
-        handle = DF.launch_chunk(payloads, table)
+        with _span("device.launch.pack", **labels):
+            freq = np.zeros(256, np.int64)
+            for l in lanes:
+                freq += l.payload[1]
+            table = DF.DeflateTable(freq, len(lanes))
+            packed = DF.pack_chunk(payloads)
+        nbytes = packed[0].nbytes + packed[1].nbytes
+        with _span("device.launch.submit", bytes=nbytes, **labels):
+            handle = DF.submit_chunk(packed, table)
         return handle, table
 
-    def finalize(self, handle, lanes: Sequence[_Lane]) -> None:
+    def finalize(self, handle, lanes: Sequence[_Lane],
+                 labels: Dict[str, Any]) -> None:
         from disq_tpu.ops import deflate as DF
 
         chunk_handle, table = handle
         try:
-            bodies, end = DF.fetch_chunk(chunk_handle, table, len(lanes))
-        finally:
+            bodies, end = DF.fetch_chunk(
+                chunk_handle, table, len(lanes), labels)
+        except BaseException:
             DF.release_chunk_arena(chunk_handle)
+            raise
         # shared per-lane finalize: identical framing + accounting on
         # every route; expanded lanes fan out over the service's host
         # pool, off this dispatcher thread
-        DF.finalize_chunk(
-            bodies, end, table, [l.payload[0] for l in lanes],
-            lambda j, blk: lanes[j].sub.deliver(lanes[j].index, blk),
-            lambda flagged: self._host_map(
-                [lanes[j] for j in flagged],
-                lambda lane: DF.host_block(lane.payload[0])))
+        with _span("device.launch.deliver", **labels):
+            DF.release_chunk_arena(chunk_handle)
+            DF.finalize_chunk(
+                bodies, end, table, [l.payload[0] for l in lanes],
+                lambda j, blk: lanes[j].sub.deliver(lanes[j].index, blk),
+                lambda flagged: self._host_map(
+                    [lanes[j] for j in flagged],
+                    lambda lane: DF.host_block(lane.payload[0])))
 
 
 class DeviceDecodeService:
@@ -376,8 +413,14 @@ class DeviceDecodeService:
             k: [deque() for _ in range(n_dev)]
             for k in ("inflate", "rans", "deflate")}
         self._next_queue = 0  # tie-break rotation (see _enqueue)
-        self._inflight: Deque[Tuple[str, Any, List[_Lane]]] = deque()
+        self._inflight: Deque[
+            Tuple[str, Any, List[_Lane], Dict[str, Any]]] = deque()
         self._closed = False
+        # dispatcher-thread only: launches so far (the ``launch`` label
+        # that joins one launch's spans) and the seconds slept in
+        # ``_cond.wait`` since the last one (``device.service.idle``)
+        self._launch_seq = 0
+        self._idle_s = 0.0
         # window sized for the standard full-BGZF geometry; the env
         # knobs in dispatch_window apply here too.  Scaled by the
         # device count: the window bounds launches IN FLIGHT, and with
@@ -580,8 +623,18 @@ class DeviceDecodeService:
                     if self._inflight:
                         break  # overlap the wait with a materialize
                     if self._closed:
-                        return
+                        break
+                    # timed inline: a context-manager span here would
+                    # take the span lock inside the condition wait
+                    t_sleep = time.perf_counter()
                     self._cond.wait(self._wait_s_locked())
+                    self._idle_s += time.perf_counter() - t_sleep
+            if chunk is None and not self._inflight:
+                # closed and drained: the sleep that close ended is
+                # followed by no launch, so it is booked here
+                if self._idle_s > 0.0:
+                    _record_span("device.service.idle", self._idle_s)
+                return
             if chunk is not None:
                 kind, dev_i, lanes, reason = chunk
                 try:
@@ -634,6 +687,15 @@ class DeviceDecodeService:
 
     def _launch(self, kind: str, dev_i: int, lanes: List[_Lane],
                 reason: str):
+        # first of all, so that the sleep this launch ended is booked
+        # as it ends and its span's ts and dur are true.  Booked for
+        # every launch, 0.0 included: each launch has all six spans
+        # under its ``launch`` number, and a join on it lacks none
+        self._launch_seq += 1
+        labels = {"kind": kind, "lanes": len(lanes),
+                  "launch": self._launch_seq}
+        _record_span("device.service.idle", self._idle_s, **labels)
+        self._idle_s = 0.0
         dev = self._devices[dev_i]
         _counter("device.batch.flush").inc(reason=reason)
         _flightrec.record_event("device_flush", codec=kind,
@@ -670,12 +732,12 @@ class DeviceDecodeService:
         t_launch = time.perf_counter()
         try:
             if dev is None:
-                handle = self._engines[kind].launch(lanes)
+                handle = self._engines[kind].launch(lanes, labels)
             else:
                 import jax
 
                 with jax.default_device(dev):
-                    handle = self._engines[kind].launch(lanes)
+                    handle = self._engines[kind].launch(lanes, labels)
         except BaseException as e:  # noqa: BLE001 — owners, not the loop
             for lane in lanes:
                 lane.sub.fail(e)
@@ -690,12 +752,12 @@ class DeviceDecodeService:
                                  max(0.0, wait) + share, kind=kind,
                                  lanes=len(own_lanes),
                                  batch_lanes=len(lanes))
-        return kind, handle, lanes
+        return kind, handle, lanes, labels
 
     def _materialize(self, entry) -> None:
-        kind, handle, lanes = entry
+        kind, handle, lanes, labels = entry
         try:
-            self._engines[kind].finalize(handle, lanes)
+            self._engines[kind].finalize(handle, lanes, labels)
         except BaseException as e:  # noqa: BLE001 — owners, not the loop
             for lane in lanes:
                 lane.sub.fail(e)
@@ -710,7 +772,7 @@ class DeviceDecodeService:
                     q.clear()
             inflight = list(self._inflight)
             self._inflight.clear()
-        for _kind, _handle, lanes in inflight:
+        for _kind, _handle, lanes, _labels in inflight:
             pending.extend(lanes)
         for lane in pending:
             lane.sub.fail(exc)

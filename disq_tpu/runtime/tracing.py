@@ -17,14 +17,16 @@ layer shared by every subsystem:
   (``DISQ_TPU_TRACE_JSONL`` or ``start_span_log(path)`` /
   ``DisqOptions.span_log``).  A whole BAM read becomes a replayable
   per-shard timeline (``scripts/trace_report.py``) instead of a sum.
-- **Exporters**: Chrome/Perfetto ``trace_event`` JSON
-  (``chrome_trace_events`` / ``export_chrome_trace``) and Prometheus
-  text (``metrics_text``).
-- **jax.profiler bridge**: ``trace_phase(name)`` additionally opens a
-  ``jax.profiler.TraceAnnotation`` so phases appear on the XLA
-  timeline, and ``DISQ_TPU_TRACE_DIR`` (or ``start_trace(dir)``)
-  captures a perfetto/tensorboard trace of everything between the
-  first phase entered and process exit (or ``stop_trace()``).
+- **Exporter**: Prometheus text (``metrics_text``).
+- **jax.profiler bridge**: every context-manager span (``span`` /
+  ``device_span`` / ``trace_phase``) also opens a
+  ``jax.profiler.TraceAnnotation("disq_tpu.<name>")``, so under a
+  capture the program's spans lie in the same ``.xplane.pb``, on the
+  same clock, as the ``XLA Ops`` line (Perfetto shows host and device
+  on one timeline).  ``record_span`` books a wait after the fact and
+  cannot be bridged.  ``DISQ_TPU_TRACE_DIR`` (or ``start_trace(dir)``)
+  captures everything between the first ``trace_phase`` entered and
+  process exit (or ``stop_trace()``).
 
 Metric taxonomy (dotted names, linted by ``scripts/check_metrics.py``
 against the README table):
@@ -58,7 +60,7 @@ against the README table):
   kernel both ways on every run and fails if the fence does not hold).
 - ``telemetry.*`` — self-observation (``telemetry.dropped_spans``).
 
-Back-compat: ``trace_phase`` / ``record_phase`` / ``phase_report`` /
+Back-compat: ``trace_phase`` / ``phase_report`` /
 ``observe_gauge`` / ``gauge_report`` are thin views over the registry —
 phases are unlabeled duration histograms, so ``phase_report()`` keeps
 returning ``{name: {calls, total_s}}``.
@@ -72,6 +74,7 @@ import contextvars
 import json
 import logging
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -725,7 +728,7 @@ def stop_span_log() -> None:
             if dropped > 0:
                 # Trailer meta line: the in-memory ring overflowed
                 # during this sink's lifetime, so any ring-derived view
-                # (/spans, chrome export) is truncated even though the
+                # (/spans) is truncated even though the
                 # JSONL itself is complete — trace_report surfaces it
                 # as a banner instead of silently rendering a partial
                 # waterfall.
@@ -793,15 +796,36 @@ def _emit_span(name: str, ts: float, dur: float,
     logger.debug("span %s: %.4fs %s", name, dur, labels)
 
 
+_annotation_cls = None  # jax.profiler.TraceAnnotation once jax is loaded
+_NO_ANNOTATION = contextlib.nullcontext()
+
+
+def _annotation(name: str):
+    """The profiler annotation of a span (no labels in its name, so a
+    reduction can key on it).  jax is never imported for it: a process
+    that has not loaded jax has no capture running."""
+    global _annotation_cls
+    cls = _annotation_cls
+    if cls is None:
+        try:
+            cls = sys.modules["jax"].profiler.TraceAnnotation
+        except (KeyError, AttributeError):  # absent, or mid-import
+            return _NO_ANNOTATION
+        _annotation_cls = cls
+    return cls("disq_tpu." + name)
+
+
 @contextlib.contextmanager
 def span(name: str, **labels: Any) -> Iterator[None]:
     """Timeline span: emits a ``{ts, dur, name, labels}`` event into the
-    ring/JSONL and books the duration in the ``name`` histogram (so
-    ``phase_report()`` and percentiles see it)."""
+    ring/JSONL, books the duration in the ``name`` histogram (so
+    ``phase_report()`` and percentiles see it) and lies, as
+    ``disq_tpu.<name>``, on the profiler's clock under a capture."""
     _resolve_span_env()
     t0 = time.perf_counter()
     try:
-        yield
+        with _annotation(name):
+            yield
     finally:
         _emit_span(name, t0, time.perf_counter() - t0, labels)
 
@@ -863,17 +887,15 @@ def device_span(name: str, **labels: Any) -> Iterator[_DeviceSync]:
     (an unfenced device timing measures the enqueue).  Also books one
     ``device.kernel_launches`` increment when a ``kernel=`` label is
     present, so every synced kernel span is a counted launch."""
-    _resolve_span_env()
     if "kernel" in labels:
         REGISTRY.counter("device.kernel_launches").inc(
             kernel=labels["kernel"])
     handle = _DeviceSync()
-    t0 = time.perf_counter()
-    try:
-        yield handle
-    finally:
-        handle.block()
-        _emit_span(name, t0, time.perf_counter() - t0, labels)
+    with span(name, **labels):
+        try:
+            yield handle
+        finally:
+            handle.block()
 
 
 def synced_timer(name: str, **labels: Any) -> Callable:
@@ -933,91 +955,26 @@ def hbm_resident(nbytes: int) -> Iterator[None]:
 
 
 # ---------------------------------------------------------------------------
-# Chrome/Perfetto trace_event export
-# ---------------------------------------------------------------------------
-
-
-_DEVICE_TRACK_PID = 2  # device.* spans render as their own process row
-
-
-def chrome_trace_events(
-    span_list: Optional[List[Dict[str, Any]]] = None
-) -> List[Dict[str, Any]]:
-    """Spans as Chrome ``trace_event`` complete events (``ph: "X"``,
-    microsecond units).  Rows (``tid``) are shard ids when the span
-    carries one, so chrome://tracing / Perfetto renders the per-shard
-    waterfall directly.  ``device.*`` spans land on their own track
-    (process row 2, named via metadata events), so kernel/transfer
-    time reads against the host stages instead of hiding inside one
-    shard's row."""
-    events = []
-    has_device = False
-    for s in (spans() if span_list is None else span_list):
-        labels = s.get("labels") or {}
-        tid = labels.get("shard")
-        try:
-            tid = int(tid)
-        except (TypeError, ValueError):
-            tid = 0
-        device = s["name"].startswith("device.")
-        has_device = has_device or device
-        events.append({
-            "name": s["name"],
-            "ph": "X",
-            "ts": round(s["ts"] * 1e6, 3),
-            "dur": round(s["dur"] * 1e6, 3),
-            "pid": _DEVICE_TRACK_PID if device else 1,
-            "tid": tid,
-            "args": labels,
-        })
-    if has_device:
-        events = [
-            {"name": "process_name", "ph": "M", "pid": 1,
-             "args": {"name": "host"}},
-            {"name": "process_name", "ph": "M", "pid": _DEVICE_TRACK_PID,
-             "args": {"name": "device"}},
-        ] + events
-    return events
-
-
-def export_chrome_trace(path: str,
-                        span_list: Optional[List[Dict[str, Any]]] = None
-                        ) -> None:
-    with open(path, "w") as f:
-        # default=str: label values may be numpy scalars (voffsets)
-        json.dump({"traceEvents": chrome_trace_events(span_list),
-                   "displayTimeUnit": "ms"}, f, default=str)
-
-
-# ---------------------------------------------------------------------------
 # jax.profiler bridge + phase back-compat views
 # ---------------------------------------------------------------------------
 
 _lock = threading.Lock()
 _trace_active = False
 
-# DISQ_TPU_TRACE_DIR and the jax import are resolved ONCE (first
-# trace_phase) — the old implementation re-read os.environ and re-ran
-# the import machinery on every call.
+# DISQ_TPU_TRACE_DIR is resolved ONCE (first trace_phase), not read
+# from os.environ on every call.
 _phase_env_resolved = False
 _trace_dir: Optional[str] = None
-_annotation_cls = None  # jax.profiler.TraceAnnotation, or None
 
 
 def _resolve_phase_env() -> None:
-    global _phase_env_resolved, _trace_dir, _annotation_cls
+    global _phase_env_resolved, _trace_dir
     if _phase_env_resolved:
         return
     with _lock:
         if _phase_env_resolved:
             return
         _trace_dir = os.environ.get("DISQ_TPU_TRACE_DIR")
-        try:
-            import jax
-
-            _annotation_cls = jax.profiler.TraceAnnotation
-        except ImportError:  # host-only deployments: timing still works
-            _annotation_cls = None
         _phase_env_resolved = True
 
 
@@ -1051,23 +1008,13 @@ def stop_trace() -> None:
 
 @contextlib.contextmanager
 def trace_phase(name: str, **labels: Any) -> Iterator[None]:
-    """``span`` + the jax.profiler bridge: the phase also appears on
-    the XLA timeline under a capture, and the first phase entered
-    auto-starts a ``DISQ_TPU_TRACE_DIR`` capture."""
+    """``span`` whose first entry auto-starts a ``DISQ_TPU_TRACE_DIR``
+    capture (the top-level phases of a read or a write)."""
     _resolve_phase_env()
     if _trace_dir and not _trace_active:
         start_trace(_trace_dir)
-    annotation = (_annotation_cls(f"disq_tpu.{name}")
-                  if _annotation_cls is not None
-                  else contextlib.nullcontext())
     with span(name, **labels):
-        with annotation:
-            yield
-
-
-def record_phase(name: str, seconds: float, **labels: Any) -> None:
-    """Back-compat alias for ``record_span``."""
-    record_span(name, seconds, **labels)
+        yield
 
 
 def phase_report() -> Dict[str, Dict[str, float]]:

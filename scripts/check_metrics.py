@@ -4,7 +4,7 @@
 
 Walks ``disq_tpu/`` for metric and span name *literals* (first string
 argument of ``span`` / ``wrap_span`` / ``trace_phase`` /
-``record_phase`` / ``record_span`` / ``counter`` / ``gauge`` /
+``record_span`` / ``counter`` / ``gauge`` /
 ``histogram`` / ``observe_gauge`` calls) and enforces:
 
 1. **Dotted taxonomy** — every name is lower_snake dotted with at
@@ -88,7 +88,7 @@ NAME_RE = re.compile(r"^[a-z0-9_]+(\.[a-z0-9_]+)+$")
 # Literal first-arg of a telemetry call (optionally alias-imported with
 # a leading underscore, e.g. http.py's ``_span`` / ``_counter``).
 CALL_RE = re.compile(
-    r"""\b_?(span|wrap_span|trace_phase|record_phase|record_span|
+    r"""\b_?(span|wrap_span|trace_phase|record_span|
              device_span|synced_timer|
              counter|gauge|histogram|observe_gauge)\s*\(\s*
         (["'])([^"'\n]+)\2""",
@@ -103,7 +103,6 @@ KIND_OF = {
     "span": "timing",
     "wrap_span": "timing",
     "trace_phase": "timing",
-    "record_phase": "timing",
     "record_span": "timing",
     "device_span": "timing",
     "synced_timer": "timing",

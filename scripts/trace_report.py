@@ -30,7 +30,7 @@ wrappers) categorize as ``K``/``T``.
 Usage::
 
     python scripts/trace_report.py spans.jsonl [--top 5] [--width 80]
-        [--run RUN_ID] [--chrome out.json]
+        [--run RUN_ID]
     python scripts/trace_report.py spans.jsonl --analyze
     python scripts/trace_report.py progress.jsonl --progress
     python scripts/trace_report.py profile.collapsed --flame
@@ -51,9 +51,6 @@ every uncovered gap attributed as ``hop`` (the bounding spans live in
 different processes — network/queue handoff) or ``intra``
 (uninstrumented time inside one process).
 
-``--chrome`` additionally converts the spans to Chrome/Perfetto
-``trace_event`` JSON (open in chrome://tracing or ui.perfetto.dev;
-device spans render on their own process track).
 ``--analyze`` is the "why is this run slow" mode: a time-sweep
 attributes every instant of wall-clock to one bucket (stage / device /
 transfer / stall / idle), a backward walk extracts the critical path
@@ -99,12 +96,22 @@ CATEGORIES = (
                     "sam.write.stage")),
     # Device-pipeline spans (runtime/device_pipeline.py + ops/): synced
     # kernel execution and explicit h2d/d2h transfer phases.
-    ("device", "K", ("device.kernel",)),
-    ("transfer", "T", ("device.transfer",)),
+    # The SIMD codecs' launches split it: blocked on the kernel
+    # (device.launch.wait), upload and copy back (.submit / .d2h).
+    ("device", "K", ("device.kernel", "device.launch.wait")),
+    ("transfer", "T", ("device.transfer", "device.launch.submit",
+                       "device.launch.d2h")),
     # Decode-service queue wait (runtime/device_service.py): the
     # oldest-lane wait of each flushed chunk — lanes sitting batched
     # before their kernel launched.
     ("service_wait", "w", ("device.service.wait",)),
+    # The service's one dispatcher thread between kernels: host lane
+    # packing before a launch and handing its lanes out after it.
+    ("dispatch", "P", ("device.launch.pack", "device.launch.deliver")),
+    # The dispatcher asleep with nothing to launch: the device's queue
+    # ran dry, so the host side upstream (fetch, parse, emit) is the
+    # one to look at.
+    ("service_idle", "i", ("device.service.idle",)),
     # Symmetric device write path (ops/deflate.py +
     # runtime/device_write.py): Huffman table builds and resident
     # encode→deflate chunks — the write-side device work, separable
@@ -153,8 +160,8 @@ def load_spans(path: str, run: Optional[str] = None):
 
     Also returns the total ``dropped_spans`` recorded by any meta
     trailer line: nonzero means the in-memory span ring overflowed
-    while this log was being written, so ring-derived views (``/spans``,
-    chrome export of the ring) were truncated — the report surfaces it
+    while this log was being written, so ring-derived views (``/spans``)
+    were truncated — the report surfaces it
     as a banner instead of silently rendering a partial waterfall."""
     spans: List[Dict[str, Any]] = []
     runs: List[str] = []
@@ -293,7 +300,7 @@ def report(spans, run, runs, top: int, width: int,
         out.append(
             f"WARNING: span ring overflowed ({dropped} spans dropped "
             "from the in-memory ring) — ring-derived timelines "
-            "(/spans, chrome export of the ring) are truncated")
+            "(/spans) are truncated")
     out.append("")
 
     # -- waterfall ---------------------------------------------------------
@@ -370,13 +377,17 @@ STALL_CATEGORIES = {"emit_stall", "retry", "quarantine", "watchdog"}
 # it only wins instants where nothing else is making progress — and
 # hedge-wasted time ranks last among work: it is burned concurrency,
 # attributed to its own bucket so the --analyze verdict can name it.
-WORK_PRIORITY = ("device", "transfer", "device_write", "columnar",
+WORK_PRIORITY = ("device", "transfer", "dispatch", "device_write",
+                 "columnar",
                  "decode", "encode", "deflate",
                  "stage", "fetch", "hedge", "hedge_wasted",
                  # service queue wait ranks last: it only wins instants
                  # where nothing is making progress — lanes parked in
                  # the batcher while the device sits idle
                  "service_wait",
+                 # and the dispatcher's sleep after it: nothing queued
+                 # at all
+                 "service_idle",
                  # scheduler coordination ranks below all real work:
                  # RPC rounds only win instants where no stage runs,
                  # and steal/idle-wait time is by definition a worker
@@ -413,6 +424,15 @@ ADVICE = {
                     "batched while the device idles — lower "
                     "DISQ_TPU_SERVICE_FLUSH_MS, or raise "
                     "executor_workers so more shards feed the batcher",
+    "dispatch": "the decode service's dispatcher dominates: its one "
+                "thread packs and delivers lanes while no kernel runs "
+                "— device.launch.pack against .deliver says which; "
+                "widen DISQ_TPU_DISPATCH_WINDOW so kernels in flight "
+                "cover it",
+    "service_idle": "the decode service sleeps: no lanes queued — the "
+                    "host upstream of it (fetch, parse hand-over, "
+                    "ordered emit) starves the device; raise "
+                    "executor_workers / prefetch_shards",
     "device_write": "device encode/deflate dominates the write: raise "
                     "writer_workers so shards overlap launches, route "
                     "through the service (DISQ_TPU_DEVICE_SERVICE=1) "
@@ -1137,8 +1157,6 @@ def main(argv=None) -> int:
                     help="waterfall width in columns (default 72)")
     ap.add_argument("--run", default=None,
                     help="run id to report (default: last run in file)")
-    ap.add_argument("--chrome", default=None, metavar="OUT.json",
-                    help="also write Chrome/Perfetto trace_event JSON")
     ap.add_argument("--progress", action="store_true",
                     help="treat the input as a progress JSONL "
                     "(DisqOptions.progress_log) and replay it as a "
@@ -1197,13 +1215,6 @@ def main(argv=None) -> int:
     spans, run, runs, dropped = load_spans(path, args.run)
     sys.stdout.write(report(spans, run, runs, args.top, args.width,
                             dropped))
-    if args.chrome:
-        sys.path.insert(
-            0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-        from disq_tpu.runtime.tracing import export_chrome_trace
-
-        export_chrome_trace(args.chrome, spans)
-        sys.stdout.write(f"chrome trace written to {args.chrome}\n")
     return 0
 
 

@@ -274,6 +274,162 @@ class TestServiceBatching:
         assert blob.tobytes() == b"".join(raws)
 
 
+# ---------------------------------------------------------------------------
+# The dispatcher's time, tiled by spans
+# ---------------------------------------------------------------------------
+
+LAUNCH_SPANS = ("device.service.idle", "device.launch.pack",
+                "device.launch.submit", "device.launch.wait",
+                "device.launch.d2h", "device.launch.deliver")
+
+
+def _launch_spans(since: int):
+    """The dispatcher's spans emitted after ``since`` ring entries,
+    grouped by their ``launch`` label (``None``: the sleep that close
+    ended, which no launch followed)."""
+    from disq_tpu.runtime.tracing import spans
+
+    by_launch = {}
+    for s in spans()[since:]:
+        if s["name"] in LAUNCH_SPANS:
+            by_launch.setdefault(s["labels"].get("launch"), []).append(s)
+    return by_launch
+
+
+def _submit(service, kind: str, lanes: int):
+    """One submission of ``lanes`` small lanes of a codec, with the
+    check of what came back."""
+    raws = [text_like(70 + 3 * i, seed=i) for i in range(lanes)]
+    if kind == "inflate":
+        sub = service.submit_inflate(
+            [deflate(r) for r in raws], [len(r) for r in raws])
+        return sub, lambda got: got[0].tobytes() == b"".join(raws)
+    if kind == "rans":
+        from disq_tpu.cram.rans import rans_encode_order0
+
+        sub = service.submit_rans([rans_encode_order0(r) for r in raws])
+        return sub, lambda got: got == raws
+    from disq_tpu.bgzf.codec import decompress_bgzf
+
+    sub = service.submit_deflate(raws)
+    return sub, lambda got: decompress_bgzf(b"".join(got)) == b"".join(raws)
+
+
+class TestLaunchSpans:
+    @pytest.mark.parametrize("kind", ["inflate", "rans", "deflate"])
+    def test_one_launch_emits_the_six_spans_joined_by_launch(self, kind):
+        """One launch of each codec: exactly the six spans, one shared
+        ``launch`` number, ``kind`` and ``lanes`` on each, the idle
+        span there although the dispatcher may not have slept."""
+        from disq_tpu.runtime.device_service import DeviceDecodeService
+        from disq_tpu.runtime.tracing import spans
+
+        since = len(spans())
+        svc = DeviceDecodeService(flush_timeout_s=0.0, interpret=True)
+        try:
+            sub, sound = _submit(svc, kind, 9)
+            assert sound(sub.result(timeout=300))
+        finally:
+            svc.close()       # joins the dispatcher: deliver has ended
+        by_launch = _launch_spans(since)
+        by_launch.pop(None, None)
+        assert list(by_launch) == [1], by_launch
+        got = by_launch[1]
+        assert sorted(s["name"] for s in got) == sorted(LAUNCH_SPANS)
+        for s in got:
+            assert s["labels"]["kind"] == kind, s
+            assert s["labels"]["lanes"] == 9, s
+            assert s["dur"] >= 0.0
+        by_name = {s["name"]: s for s in got}
+        assert by_name["device.launch.submit"]["labels"]["bytes"] > 0
+        assert by_name["device.launch.d2h"]["labels"]["bytes"] > 0
+        # in the order the dispatcher does them
+        order = sorted(got, key=lambda s: s["ts"] + s["dur"])
+        assert [s["name"] for s in order] == list(LAUNCH_SPANS)
+
+    def test_idle_is_booked_once_a_launch_also_at_zero(self):
+        """A full chunk and its remainder flush back to back (no flush
+        timeout): the second launch follows the first with no sleep
+        between, and still has its idle span, of 0.0 s."""
+        from disq_tpu.runtime.device_service import DeviceDecodeService
+        from disq_tpu.runtime.tracing import spans
+
+        since = len(spans())
+        svc = DeviceDecodeService(flush_timeout_s=0.0, interpret=True)
+        try:
+            sub, sound = _submit(svc, "inflate", 130)
+            assert sound(sub.result(timeout=300))
+        finally:
+            svc.close()
+        by_launch = _launch_spans(since)
+        by_launch.pop(None, None)
+        assert sorted(by_launch) == [1, 2]
+        idle = {n: [s for s in got if s["name"] == "device.service.idle"]
+                for n, got in by_launch.items()}
+        assert all(len(v) == 1 for v in idle.values()), idle
+        assert idle[2][0]["dur"] == 0.0
+        assert {n: idle[n][0]["labels"]["lanes"] for n in idle} == {
+            1: 128, 2: 2}
+
+    def test_the_sleep_that_close_ends_is_booked_at_close(self):
+        """After its last launch the dispatcher sleeps until close:
+        that sleep has no launch to be booked with, and is booked as
+        the thread ends, with no ``launch`` label."""
+        import time
+
+        from disq_tpu.runtime.device_service import DeviceDecodeService
+        from disq_tpu.runtime.tracing import spans
+
+        since = len(spans())
+        svc = DeviceDecodeService(flush_timeout_s=0.0, interpret=True)
+        try:
+            sub, sound = _submit(svc, "inflate", 3)
+            assert sound(sub.result(timeout=300))
+            time.sleep(0.2)
+        finally:
+            svc.close()
+        closed = time.perf_counter()
+        by_launch = _launch_spans(since)
+        (last,) = by_launch[None]
+        assert last["name"] == "device.service.idle"
+        assert last["labels"] == {}
+        assert last["dur"] >= 0.15
+        deliver = next(s for s in by_launch[1]
+                       if s["name"] == "device.launch.deliver")
+        assert deliver["ts"] + deliver["dur"] <= last["ts"] + 1e-3
+        assert last["ts"] + last["dur"] <= closed
+
+    def test_the_six_spans_tile_the_dispatcher_thread(self, service):
+        """Several launches with a sleep between them and one before
+        close: the six spans' durations add up to the stretch of the
+        dispatcher's own clock that they cover, from the first span's
+        start to the last one's end.  Sum against sum on one clock, so
+        the machine's speed cancels; what is left out is the
+        bookkeeping between spans."""
+        import time
+
+        from disq_tpu.runtime.tracing import spans
+
+        since = len(spans())
+        for lanes in (130, 40):
+            sub, sound = _submit(service, "inflate", lanes)
+            assert sound(sub.result(timeout=300))
+            time.sleep(0.2)
+        service.close()
+        by_launch = _launch_spans(since)
+        assert sorted(by_launch, key=str) == [1, 2, 3, None]
+        got = [s for launch in by_launch.values() for s in launch]
+        assert len(got) == 6 * 3 + 1
+        covered = sum(s["dur"] for s in got)
+        lifetime = (max(s["ts"] + s["dur"] for s in got)
+                    - min(s["ts"] for s in got))
+        assert 0.9 * lifetime <= covered <= 1.02 * lifetime, (
+            covered, lifetime)
+        slept = sum(s["dur"] for s in got
+                    if s["name"] == "device.service.idle")
+        assert slept >= 0.3      # before the third launch, before close
+
+
 class TestServiceDisabled:
     def test_disabled_path_runs_no_service(self, monkeypatch):
         """No flag -> enabled() is False, a device inflate call routes
@@ -361,6 +517,53 @@ class TestEndToEnd:
         assert dev.count() == host.count()
         np.testing.assert_array_equal(dev.reads.pos, host.reads.pos)
         np.testing.assert_array_equal(dev.reads.seqs, host.reads.seqs)
+
+    def test_a_service_read_lies_in_a_profiler_capture(
+            self, tmp_path, monkeypatch):
+        """A capture round a small read through the service holds the
+        dispatcher's context-manager spans and the executor's, as many
+        events of each name as the ring has spans, on one clock: a
+        reduction can put the device's idle time down to them.  The
+        back-dated idle span cannot be bridged and is in the ring
+        only."""
+        from profiler_capture import captured_events
+
+        from disq_tpu.api import ReadsStorage
+        from disq_tpu.runtime import device_service
+        from disq_tpu.runtime.tracing import spans
+
+        path = _bam_file(tmp_path, n=60)
+        monkeypatch.setenv("DISQ_TPU_DEVICE_INFLATE", "1")
+        monkeypatch.setenv("DISQ_TPU_DEVICE_SERVICE", "1")
+        since = len(spans())
+        out = {}
+
+        def body():
+            try:
+                out["n"] = (ReadsStorage.make_default().split_size(16000)
+                            .executor_workers(1).read(path).count())
+            finally:
+                device_service.shutdown_service()
+
+        bridged = [n for n in LAUNCH_SPANS if n != "device.service.idle"]
+        bridged += ["executor.fetch", "executor.decode", "bam.read.splits"]
+        events = captured_events(
+            tmp_path / "trace", body,
+            ["disq_tpu." + n for n in LAUNCH_SPANS + tuple(bridged)])
+        assert out["n"] == 60
+        ring = [s["name"] for s in spans()[since:]]
+        assert ring.count("device.service.idle") >= 1
+        for name in bridged:
+            assert ring.count(name) >= 1, name
+            assert ([ev[0] for ev in events].count("disq_tpu." + name)
+                    == ring.count(name)), name
+        assert not [ev for ev in events
+                    if ev[0] == "disq_tpu.device.service.idle"]
+        # one clock: within a launch, pack ends before submit starts
+        pack, submit = (next(ev for ev in events
+                             if ev[0] == "disq_tpu.device.launch." + n)
+                        for n in ("pack", "submit"))
+        assert pack[1] + pack[2] <= submit[1]
 
     # Slow tier (~65s e2e at workers=4): owner-only quarantine
     # semantics stay tier-1 via TestServiceBatching's unit-level

@@ -3,10 +3,9 @@
 kernel spans (fenced with ``block_until_ready``), transfer-byte
 counters that match what is actually uploaded (alignment pad
 included), the live-HBM gauge, the host-fallback counter, and the
-device track in the Chrome export."""
+device spans on the clock of a profiler capture."""
 
 import gzip
-import json
 import struct
 import zlib
 
@@ -19,7 +18,6 @@ from bam_oracle import DEFAULT_REFS, make_bam_bytes, synth_records
 from disq_tpu.runtime import tracing
 from disq_tpu.runtime.tracing import (
     REGISTRY,
-    chrome_trace_events,
     count_transfer,
     device_span,
     hbm_live_bytes,
@@ -119,33 +117,46 @@ class TestDeviceSpanHelpers:
         assert hbm_live_bytes() == 0
 
 
-# -- chrome export: device spans ride their own track -----------------------
+# -- the profiler bridge: device spans on the capture's clock ---------------
 
 
-class TestChromeDeviceTrack:
-    def test_device_spans_get_their_own_process_row(self):
-        span_list = [
-            {"ts": 1.0, "dur": 0.5, "name": "executor.fetch",
-             "run": "r", "labels": {"shard": 3}},
-            {"ts": 1.2, "dur": 0.1, "name": "device.kernel",
-             "run": "r", "labels": {"kernel": "inflate"}},
-        ]
-        evs = chrome_trace_events(span_list)
-        meta = [e for e in evs if e.get("ph") == "M"]
-        assert {(e["pid"], e["args"]["name"]) for e in meta} == {
-            (1, "host"), (2, "device")}
-        by_name = {e["name"]: e for e in evs if e.get("ph") == "X"}
-        assert by_name["executor.fetch"]["pid"] == 1
-        assert by_name["device.kernel"]["pid"] == 2
+class TestDeviceSpansInAProfilerCapture:
+    def test_a_device_span_reads_against_the_host_stage_round_it(
+            self, tmp_path):
+        """Host stage and fenced device span are events of one capture,
+        on one clock: the kernel's lies inside the stage's."""
+        from profiler_capture import captured_events
 
-    def test_no_metadata_without_device_spans(self):
-        span_list = [
-            {"ts": 1.0, "dur": 0.5, "name": "executor.fetch",
-             "run": "r", "labels": {}},
-        ]
-        evs = chrome_trace_events(span_list)
-        assert all(e.get("ph") != "M" for e in evs)
-        assert evs[0]["pid"] == 1
+        def body():
+            with tracing.span("executor.decode", shard=3):
+                with device_span("device.kernel",
+                                 kernel="unittest") as fence:
+                    fence.sync(jnp.arange(1024) * 2)
+
+        stage, kernel = captured_events(
+            tmp_path, body,
+            ["disq_tpu.executor.decode", "disq_tpu.device.kernel"])
+        assert stage[0] == "disq_tpu.executor.decode"
+        assert kernel[0] == "disq_tpu.device.kernel"    # no label in it
+        assert stage[1] <= kernel[1]
+        assert kernel[1] + kernel[2] <= stage[1] + stage[2]
+        assert [s["name"] for s in spans()] == [
+            "device.kernel", "executor.decode"]
+
+    def test_a_synced_timer_is_one_event_a_call(self, tmp_path):
+        """The decorator form rides the same bridge: one event of the
+        kernel span's name for each call, none for its labels."""
+        from profiler_capture import captured_events
+
+        @synced_timer("device.kernel", kernel="deco")
+        def work(n):
+            return jnp.ones((n,)) * n
+
+        events = captured_events(
+            tmp_path, lambda: [work(8), work(16)],
+            ["disq_tpu.device.kernel", "disq_tpu.device.kernel.deco"])
+        assert [ev[0] for ev in events] == ["disq_tpu.device.kernel"] * 2
+        assert events[0][1] + events[0][2] <= events[1][1]
 
 
 # -- run_device_pipeline ----------------------------------------------------
@@ -154,14 +165,26 @@ class TestChromeDeviceTrack:
 class TestDevicePipelineTelemetry:
     def test_books_transfers_launch_and_kernel_span(self, tmp_path):
         """Acceptance: a CPU run books nonzero bytes_to_device /
-        bytes_to_host and emits device.kernel spans visible in the
-        chrome export."""
+        bytes_to_host and emits device.kernel and device.transfer
+        spans, in the ring and in a profiler capture round the run."""
+        from profiler_capture import captured_events
+
         from disq_tpu.runtime.device_pipeline import run_device_pipeline
 
         blob, offs = _shard()
-        keys, order, stats = run_device_pipeline(blob, offs,
-                                                 interpret=True)
-        assert stats["total"] == len(offs) - 1
+        out = {}
+
+        def body():
+            out["keys"], out["order"], out["stats"] = run_device_pipeline(
+                blob, offs, interpret=True)
+
+        events = captured_events(
+            tmp_path, body,
+            ["disq_tpu.device.kernel", "disq_tpu.device.transfer"])
+        assert out["stats"]["total"] == len(offs) - 1
+        assert sorted(ev[0] for ev in events) == [
+            "disq_tpu.device.kernel", "disq_tpu.device.transfer",
+            "disq_tpu.device.transfer"]
 
         h2d = REGISTRY.counter("device.bytes_to_device").total()
         d2h = REGISTRY.counter("device.bytes_to_host").total()
@@ -178,13 +201,6 @@ class TestDevicePipelineTelemetry:
         names = [s["name"] for s in spans()]
         assert "device.kernel" in names
         assert names.count("device.transfer") == 2
-
-        out = tmp_path / "trace.json"
-        tracing.export_chrome_trace(str(out))
-        doc = json.loads(out.read_text())
-        dev = [e for e in doc["traceEvents"]
-               if e.get("pid") == 2 and e.get("ph") == "X"]
-        assert any(e["name"] == "device.kernel" for e in dev)
 
     def test_pad_accounting_counts_uploaded_bytes(self):
         """The word-alignment pad is part of what is uploaded, so it

@@ -116,6 +116,15 @@ class TestSampling:
         thread) — not to anonymous ``other`` threads."""
         st = (ReadsStorage.make_default().split_size(16 * 1024)
               .executor_workers(4))
+        # Threads that were there before the profile began are the
+        # test runner's, not the read's (under xdist the worker's
+        # receiver thread, which ``threading`` does not even list;
+        # pools that earlier tests left).  Each is sampled once a tick,
+        # as the main thread is, so together they account for
+        # len(bystanders) * ticks of the "other" samples.
+        names = {t.ident: t.name for t in threading.enumerate()}
+        bystanders = [tid for tid in sys._current_frames()
+                      if role_of(names.get(tid, "?")) == "other"]
         prof = SamplingProfiler(hz=200).start()
         t0 = time.perf_counter()
         n = None
@@ -124,10 +133,11 @@ class TestSampling:
         prof.stop()
         assert n == 3000
         by_role = prof.by_role()
-        total = sum(by_role.values())
-        assert total > 100, by_role
+        ticks = by_role["main"]
+        other = max(0, by_role.get("other", 0) - len(bystanders) * ticks)
         named = sum(v for k, v in by_role.items() if k != "other")
-        assert named / total >= 0.9, by_role
+        assert named > 100, by_role
+        assert named / (named + other) >= 0.9, (by_role, bystanders)
         # and the pipeline stages themselves were seen working
         assert by_role.get("fetch", 0) + by_role.get("decode", 0) > 0
 

@@ -18,7 +18,6 @@ from disq_tpu.runtime.executor import ShardPipelineExecutor, ShardTask
 from disq_tpu.runtime.tracing import (
     DEFAULT_BUCKETS,
     MetricsRegistry,
-    chrome_trace_events,
     counter,
     gauge,
     histogram,
@@ -170,11 +169,15 @@ def test_gauge_report_legacy_keys():
     assert g["min"] == 3 and g["mean"] == 4.0
 
 
-def test_record_phase_alias():
-    tracing.record_phase("executor.emit.stall", 0.25)
+def test_record_span_books_a_wait_that_ends_now():
+    record_span("executor.emit.stall", 0.25, shard=2)
+    now = time.perf_counter()
     rep = phase_report()
     assert rep["executor.emit.stall"]["calls"] == 1
     assert rep["executor.emit.stall"]["total_s"] == pytest.approx(0.25)
+    (s,) = spans()
+    assert s["dur"] == 0.25 and s["labels"] == {"shard": 2}
+    assert s["ts"] + s["dur"] == pytest.approx(now, abs=0.05)
 
 
 # -- span ring + sink -------------------------------------------------------
@@ -295,29 +298,69 @@ def test_prometheus_label_escaping():
     assert 'what="a\\"b\\\\c"' in metrics_text()
 
 
-def test_chrome_trace_golden():
-    span_list = [
-        {"ts": 1.0, "dur": 0.5, "name": "executor.fetch",
-         "run": "r", "labels": {"shard": 3, "path": "x.bam"}},
-        {"ts": 1.5, "dur": 0.25, "name": "bam.read.header",
-         "run": "r", "labels": {}},
-    ]
-    assert chrome_trace_events(span_list) == [
-        {"name": "executor.fetch", "ph": "X", "ts": 1000000.0,
-         "dur": 500000.0, "pid": 1, "tid": 3,
-         "args": {"shard": 3, "path": "x.bam"}},
-        {"name": "bam.read.header", "ph": "X", "ts": 1500000.0,
-         "dur": 250000.0, "pid": 1, "tid": 0, "args": {}},
-    ]
+# -- the profiler bridge: spans on the capture's clock ----------------------
 
 
-def test_export_chrome_trace_file(tmp_path):
-    with span("executor.fetch", shard=0):
+@pytest.mark.parametrize("opener", [span, trace_phase],
+                         ids=["span", "trace_phase"])
+def test_a_span_is_one_event_of_a_profiler_capture(tmp_path, opener):
+    """``span("x", ...)`` under a capture is one ``disq_tpu.x`` event
+    of the ``.xplane.pb`` (no labels in its name), inside the ring's
+    span of the same name; ``trace_phase`` delegates and yields one
+    event, not two."""
+    from profiler_capture import captured_events
+
+    def body():
+        with opener("executor.fetch", shard=3, path="x.bam"):
+            time.sleep(0.01)
+
+    events = captured_events(
+        tmp_path, body, ["disq_tpu.executor.fetch", "executor.fetch"])
+    assert [ev[0] for ev in events] == ["disq_tpu.executor.fetch"]
+    (ring,) = [s for s in spans() if s["name"] == "executor.fetch"]
+    assert ring["labels"] == {"shard": 3, "path": "x.bam"}
+    assert 0.01 <= events[0][2] / 1e9 <= ring["dur"] + 1e-6
+
+
+def test_spans_of_two_threads_share_the_captures_clock(tmp_path):
+    """Spans opened one after another on different threads come out of
+    the capture in that order, on one clock; a back-dated
+    ``record_span`` is in the ring only."""
+    from profiler_capture import captured_events
+
+    def decode():
+        with span("executor.decode", shard=0):
+            time.sleep(0.005)
+
+    def body():
+        with span("executor.fetch", shard=0):
+            time.sleep(0.005)
+        t = threading.Thread(target=decode)
+        t.start()
+        t.join(timeout=30)
+        assert not t.is_alive()
+        record_span("executor.emit.stall", 0.004, shard=0)
+
+    names = ["disq_tpu.executor." + n
+             for n in ("fetch", "decode", "emit.stall")]
+    events = captured_events(tmp_path, body, names)
+    assert [ev[0] for ev in events] == names[:2]
+    fetch, decode_ev = events
+    assert fetch[1] + fetch[2] <= decode_ev[1]
+    assert {s["name"] for s in spans()} >= {
+        "executor.fetch", "executor.decode", "executor.emit.stall"}
+
+
+def test_without_jax_a_span_opens_no_annotation(monkeypatch):
+    """No jax in the process, no annotation (and no import of it for a
+    span's sake): the span books in the ring as before."""
+    monkeypatch.setattr(tracing, "_annotation_cls", None)
+    monkeypatch.delitem(sys.modules, "jax")
+    assert tracing._annotation("executor.fetch") is tracing._NO_ANNOTATION
+    with span("executor.fetch", shard=1):
         pass
-    out = tmp_path / "trace.json"
-    tracing.export_chrome_trace(str(out))
-    doc = json.loads(out.read_text())
-    assert doc["traceEvents"] and doc["traceEvents"][-1]["ph"] == "X"
+    assert "jax" not in sys.modules
+    assert [s["name"] for s in spans()] == ["executor.fetch"]
 
 
 # -- end-to-end: BAM read -> span log -> trace_report -----------------------

@@ -71,6 +71,28 @@ class TestAttribution:
             "device": pytest.approx(3.0),
         }
 
+    def test_a_launch_of_the_decode_service_splits_into_four_buckets(self):
+        """The dispatcher's six spans of one launch, end to end on its
+        one thread: asleep, packing, uploading, blocked on the kernel,
+        copying back, delivering — each second in the bucket that says
+        whose it is."""
+        names = ["device.service.idle", "device.launch.pack",
+                 "device.launch.submit", "device.launch.wait",
+                 "device.launch.d2h", "device.launch.deliver"]
+        spans = [_span(n, float(i), 1.0, kind="inflate", launch=1)
+                 for i, n in enumerate(names)]
+        buckets, *_rest, wall = trace_report.attribute_wall(spans)
+        assert wall == pytest.approx(6.0)
+        assert buckets == {
+            "service_idle": pytest.approx(1.0),
+            "dispatch": pytest.approx(2.0),
+            "transfer": pytest.approx(2.0),
+            "device": pytest.approx(1.0),
+        }
+        for bucket in buckets:
+            assert bucket in trace_report.ADVICE
+            assert bucket in trace_report.WORK_PRIORITY
+
     def test_empty(self):
         assert trace_report.attribute_wall([]) == ({}, 0.0, 0.0, 0.0)
 

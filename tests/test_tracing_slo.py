@@ -149,10 +149,10 @@ class _StubInflateEngine:
 
     kind = "inflate"
 
-    def launch(self, lanes):
+    def launch(self, lanes, labels):
         return [zlib.decompress(l.payload, -15) for l in lanes]
 
-    def finalize(self, handle, lanes):
+    def finalize(self, handle, lanes, labels):
         for lane, out in zip(lanes, handle):
             lane.sub.deliver(lane.index, out)
 
