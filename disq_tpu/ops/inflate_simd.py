@@ -9,13 +9,22 @@ SIMD** instead: 128 independent DEFLATE streams, one per vector lane,
 every piece of decoder state a ``(1, 128)`` vector.
 
 Per superstep (one ``lax.while_loop`` iteration), every lane advances
-its own predicated state machine — header / stored / dynamic-table
-build / symbol decode / distance / LZ77 copy — by pure vector selects;
-rare events (table finalization, dyn-block entry, table-phase stores)
-are gated with ``pl.when``, and the refill/far-history sweeps behind
-``lax.cond`` whole-warp gates. A lane emits 1 output byte per literal
-superstep, up to 4 per stored/short-copy superstep, and up to 8 (two
-output words) in the aligned steady state of a long match (d >= 8).
+its own predicated state machine by pure vector selects; rare events
+(table finalization, dyn-block entry, table-phase stores) are gated
+with ``pl.when``, and the refill/far-history sweeps behind ``lax.cond``
+whole-warp gates. The phases of a superstep run in a fixed order, each
+on the state the one before left: phase A (header / stored / one
+table-build step / one literal-or-length symbol, or a literal pair),
+phase B (the distance of the length phase A has just read), then the
+copy phase (the lanes inside a match, those phase B has just put there
+included). So a token costs one superstep: a literal (two when both fit
+the output word), or a match's length + distance + first copy chunk
+together; a long match then takes one superstep more per chunk. ``meta``
+row 2 carries the launch's superstep count (counter
+``device.inflate.supersteps``). A lane emits 1-2 bytes per literal
+superstep, up to 4 per stored/short-copy superstep, and 8 or 16 (two or
+four output words) in the aligned steady state of a long match (d >= 8
+/ d >= 16).
 All data-dependent indexing uses one vector-gather primitive, the
 one-hot row gather ``sum(where(row_iota == idx, data, 0))``: pure
 compares, selects and a sublane reduction, which Mosaic lowers for any
@@ -102,7 +111,7 @@ _I32 = jnp.int32
 # Lane states.
 _HEADER, _SLEN, _SNLEN, _SCOPY = 0, 1, 2, 3
 _TBHDR, _TBCLLEN, _TBCODELEN = 4, 5, 6
-_DECODE, _DIST, _COPY, _DONE, _ERR = 7, 8, 9, 10, 11
+_DECODE, _COPY, _DONE, _ERR = 7, 8, 9, 10
 
 
 def _canonical_np(lens: np.ndarray, maxbits: int):
@@ -354,12 +363,16 @@ def _inflate_simd_kernel(
 
     # 64-bit bit buffer as a (lo, hi) u32 pair + total valid-bit count.
     # One *word-aligned* single gather per refill site (the one-hot fast
-    # path); two refill sites per superstep keep every phase's peek
-    # within the low word: pre-phase-A cnt >= 33, phase A consumes <= 32
-    # (a word-aligned 4-byte stored copy; Huffman paths <= 30 — the
-    # pair-literal decode reads two codes of <= 15 bits each),
-    # pre-phase-B refill restores >= 33, dist code <= 15 leaves >= 18
-    # >= 13 extra bits. No unaligned double-gather assembly.
+    # path). A refill turns any cnt in [0, 32] into cnt + 32, so after
+    # it cnt >= 32 and the low word is whole, whatever was consumed
+    # before; two refill sites per superstep keep every phase's peek
+    # within the low word. Pre-phase-A: 32 valid bits, phase A consumes
+    # <= 32 (a word-aligned 4-byte stored copy; Huffman paths <= 30 —
+    # two literal codes of <= 15 bits each, or a 15-bit length code + 5
+    # extra bits). Pre-phase-B: 32 valid bits again, so a match's
+    # distance can follow its length in the same superstep: the dist
+    # code (<= 15) is consumed first, which leaves >= 17 >= its 13
+    # extra bits. No unaligned double-gather assembly.
     def refill64(lo, hi, cnt, in_w):
         def do_refill(lo, hi, cnt, in_w):
             w = _gather_ref_win(
@@ -617,7 +630,7 @@ def _inflate_simd_kernel(
         lex_v = ((bitbuf >> dbits.astype(_U32)) &
                  _mask_bits(lext)).astype(_I32)
         copy_len = jnp.where(mlen, lbase + lex_v, copy_len)
-        new_state = jnp.where(mlen & ~bad_len, _DIST, new_state)
+        mdist = mlen & ~bad_len
         used = jnp.where(
             m,
             dbits + jnp.where(mlen, lext, 0)
@@ -629,11 +642,12 @@ def _inflate_simd_kernel(
         lo, hi, cnt, in_w = refill64(lo, hi, cnt, in_w)
         bitbuf = lo
 
-        # ---- DIST (phase B): distance code, refill, then extra bits.
-        # A 15-bit code + 13 extra bits needs 28 valid bits but refill
-        # only guarantees 25, so the code is consumed and the buffer
-        # refilled BEFORE the extra bits are read.
-        m = (state == _DIST) & live
+        # ---- DIST (phase B): the distance of the length symbol phase
+        # A has just read, in the same superstep. The refill above left
+        # >= 32 valid bits whatever phase A consumed; the code (<= 15)
+        # is consumed before the extra bits (<= 13) are read, so both
+        # peeks stay inside the low word.
+        m = mdist
 
         xidx, xbits, xfound = _decode_canonical(
             bitbuf, 15, cntd_ref[...], firstd_ref[...], offd_ref[...],
@@ -659,16 +673,23 @@ def _inflate_simd_kernel(
         lo, hi, cnt = consume64(lo, hi, cnt, jnp.where(mok, dext, zrow))
 
         # ---- COPY: up to 16 history bytes per superstep --------------
+        # Acts on the lanes in _COPY *after* phase B: a match's first
+        # chunk goes out in the superstep that read its length and its
+        # distance. Such a lane has emitted nothing earlier in this
+        # superstep (a length symbol emits no byte), so outpos, off and
+        # kmax are still the superstep's own, and the ring and the big
+        # out buffer are read before this superstep's emit is merged:
+        # only bytes written by earlier supersteps are ever read.
         # Source bytes come from the 4 KiB circular history ring (last
         # 4096 bytes, word rows = w & (RING_W-1)); distances past the
         # ring window read the big out buffer under a gated cond. For
         # d < 4 the 4 fetched bytes start at outpos-d and are replicated
-        # modularly (byte j := B[j mod d]), so only written bytes are
-        # ever read. When the output is word-aligned (the steady state
-        # inside a long match — the first partial step aligns it), TWO
-        # words emit straight from the source for d >= 8 and FOUR for
-        # d >= 16, cutting the superstep count of long copies 4x.
-        m = (state == _COPY) & live
+        # modularly (byte j := B[j mod d]). When the output is
+        # word-aligned (the steady state inside a long match — the first
+        # partial step aligns it), TWO words emit straight from the
+        # source for d >= 8 and FOUR for d >= 16, cutting the superstep
+        # count of long copies 4x.
+        m = new_state == _COPY
         d = copy_dist
         elig8 = m & (off == 0) & (d >= 8)
         elig16 = elig8 & (d >= 16)
@@ -1080,7 +1101,11 @@ def _fetch_chunk(handle, lanes: int,
     (blocked on the kernel) and then ``device.launch.d2h`` (the copy
     alone).  ``np.asarray`` blocked here before the split, so no fence
     is added.  ``labels`` are the spans' (the decode service passes the
-    ones that join a launch's spans: ``kind``, ``lanes``, ``launch``)."""
+    ones that join a launch's spans: ``kind``, ``lanes``, ``launch``).
+    An inflate launch's superstep count (``meta`` row 2) is booked as
+    ``device.inflate.supersteps`` and as the d2h span's ``supersteps``
+    label: over ``device.kernel_launches`` it is supersteps a launch,
+    under the kernel's seconds it is seconds a superstep."""
     words, meta = handle
     if labels is None:
         labels = {"kind": "inflate", "lanes": lanes}
@@ -1088,9 +1113,12 @@ def _fetch_chunk(handle, lanes: int,
     with _span("device.launch.wait", **labels):
         jax.block_until_ready((words, meta))
     nbytes = words.nbytes + meta.nbytes
-    with _span("device.launch.d2h", bytes=nbytes, **labels):
+    with _span("device.launch.d2h", bytes=nbytes, **labels) as at_end:
         words = np.asarray(words)
         meta = np.asarray(meta)
+        if kernel == "inflate_simd":
+            at_end["supersteps"] = supersteps = int(meta[2, 0])
+            _counter("device.inflate.supersteps").inc(supersteps)
     _count_transfer("d2h", nbytes)
     return words.view(np.uint8), meta
 

@@ -10,11 +10,12 @@ an on-chip run catches that.
 Invoked by ``tests/test_tpu_kernels.py`` (in a clean subprocess so the
 suite's forced-CPU conftest doesn't apply) or directly:
 
-    python -m disq_tpu.ops.tpu_ci [out.json]
+    python -m disq_tpu.ops.tpu_ci [out.json [wgs30x-record-bytes]]
 """
 
 from __future__ import annotations
 
+import functools
 import json
 import sys
 import time
@@ -37,6 +38,34 @@ def _bam_like(n: int, rng) -> bytes:
     qual = np.repeat(
         rng.integers(30, 42, max(1, n // 40), dtype=np.uint8), 20)[: n // 2]
     return (seq.tobytes() + qual.tobytes())[:n]
+
+
+def _inflate_kernel_only(raws: list, payloads: list):
+    """One hand-packed launch of the SIMD inflate kernel, timed alone:
+    inputs pre-uploaded, sync on the 2 KiB meta pull (isolates the
+    kernel from packing and transfers). Returns (every lane's status 0
+    and its output equal to ``raws``, best seconds of 3, meta rows)."""
+    import jax.numpy as jnp
+    from disq_tpu.ops import inflate_simd as S
+
+    assert all(len(p) <= S.MAX_DEVICE_CSIZE for p in payloads)
+    cw, ow = S.buckets_for(payloads, max(len(r) for r in raws))
+    fn = S._compiled(cw, ow, False)
+    comp, clen = S._pack_chunk(payloads, cw)
+    carg, cl = jnp.asarray(comp), jnp.asarray(clen)
+    consts = tuple(jnp.asarray(t) for t in S._CONST_TABLES)
+    w, m = fn(carg, cl, *consts)
+    meta, words = np.asarray(m), np.asarray(w)
+    ok = (int(meta[1].max()) == 0) and all(
+        np.ascontiguousarray(words[:, i]).tobytes()[:len(r)] == r
+        for i, r in enumerate(raws))
+    best = 1e9
+    for _ in range(3):
+        t0 = time.perf_counter()
+        _, m = fn(carg, cl, *consts)
+        np.asarray(m)
+        best = min(best, time.perf_counter() - t0)
+    return ok, best, meta
 
 
 def run_inflate_simd(results: list) -> None:
@@ -70,30 +99,14 @@ def run_inflate_simd(results: list) -> None:
     })
     assert ok, "SIMD inflate output != zlib"
 
-    # kernel-only row: inputs pre-uploaded, sync on the 2 KiB meta pull
-    # (isolates the kernel from packing and transfers)
-    import jax.numpy as jnp
-    from disq_tpu.ops import inflate_simd as S
-
-    cw, ow = S.buckets_for(payloads, max(usizes))
-    fn = S._compiled(cw, ow, False)
-    comp, clen = S._pack_chunk(payloads, cw)
-    carg, cl = jnp.asarray(comp), jnp.asarray(clen)
-    consts = tuple(jnp.asarray(t) for t in S._CONST_TABLES)
-    _, m = fn(carg, cl, *consts)
-    np.asarray(m)
-    best_k = 1e9
-    for _ in range(3):
-        t0 = time.perf_counter()
-        _, m = fn(carg, cl, *consts)
-        np.asarray(m)
-        best_k = min(best_k, time.perf_counter() - t0)
+    ok_k, best_k, _meta = _inflate_kernel_only(raws, payloads)
     results.append({
         "kernel": "inflate_simd_kernel_only",
         "shape": "128 lanes x 60000 B",
         "mb_per_sec": round(total / best_k / 1e6, 2),
-        "correct": ok,
+        "correct": ok_k,
     })
+    assert ok_k, "SIMD inflate kernel-only launch output != zlib"
 
 
 def run_inflate_legacy(results: list) -> None:
@@ -148,30 +161,10 @@ def run_inflate_simd_literal_heavy(results: list) -> None:
     """Pair-literal regime: pure-literal streams (no LZ77 matches) are
     the kernel's worst case — the speculative second-symbol decode
     roughly doubles it. Kernel-only row at 128 x 25 KB."""
-    import jax.numpy as jnp
-    from disq_tpu.ops import inflate_simd as S
-
     rng = np.random.default_rng(7)
     raws = [rng.integers(0, 250, 25000, dtype=np.uint8).tobytes()
             for _ in range(128)]
-    payloads = [_deflate(r) for r in raws]
-    assert all(len(p) <= S.MAX_DEVICE_CSIZE for p in payloads)
-    cw, ow = S.buckets_for(payloads, 25000)
-    fn = S._compiled(cw, ow, False)
-    comp, clen = S._pack_chunk(payloads, cw)
-    carg, cl = jnp.asarray(comp), jnp.asarray(clen)
-    consts = tuple(jnp.asarray(t) for t in S._CONST_TABLES)
-    w, m = fn(carg, cl, *consts)
-    meta = np.asarray(m)
-    ok = (int(meta[1].max()) == 0) and all(
-        np.ascontiguousarray(np.asarray(w)[:, i]).tobytes()[:25000]
-        == raws[i] for i in range(128))
-    best = 1e9
-    for _ in range(3):
-        t0 = time.perf_counter()
-        _, m = fn(carg, cl, *consts)
-        np.asarray(m)
-        best = min(best, time.perf_counter() - t0)
+    ok, best, _meta = _inflate_kernel_only(raws, [_deflate(r) for r in raws])
     total = sum(len(r) for r in raws)
     results.append({
         "kernel": "inflate_simd_literal_heavy_kernel_only",
@@ -180,6 +173,32 @@ def run_inflate_simd_literal_heavy(results: list) -> None:
         "correct": ok,
     })
     assert ok, "literal-heavy SIMD inflate output != zlib"
+
+
+def run_inflate_simd_wgs30x(results: list, record_bytes: bytes) -> None:
+    """The kernel on the benchmark's own bytes: 128 lanes of 65,280
+    ``wgs30x`` record bytes each (a full BGZF block), zlib 6. The
+    caller hands in the record bytes (``benchmark/gen.py`` +
+    ``reference.encode_records``; this package does not import the
+    benchmark). Kernel-only, with the launch's two factors: supersteps
+    (meta row 2) and seconds a superstep."""
+    block = 65280
+    assert len(record_bytes) >= 128 * block, (
+        f"{len(record_bytes)} record bytes do not fill 128 lanes")
+    raws = [record_bytes[i * block: (i + 1) * block] for i in range(128)]
+    payloads = [_deflate(r) for r in raws]
+    ok, best, meta = _inflate_kernel_only(raws, payloads)
+    steps = int(meta[2, 0])
+    results.append({
+        "kernel": "inflate_simd_wgs30x_kernel_only",
+        "shape": "128 lanes x 65280 B of wgs30x records, zlib 6",
+        "mb_per_sec": round(128 * block / best / 1e6, 2),
+        "ratio_zlib6": round(128 * block / sum(map(len, payloads)), 3),
+        "supersteps_per_launch": steps,
+        "us_per_superstep": round(best / steps * 1e6, 3),
+        "correct": ok,
+    })
+    assert ok, "wgs30x SIMD inflate output != its input"
 
 
 def run_rans_simd(results: list) -> None:
@@ -690,7 +709,10 @@ def run_mesh_parse(results: list) -> None:
     assert ok
 
 
-def main(out_path: str = "TPU_KERNELS.json") -> int:
+def main(out_path: str = "TPU_KERNELS.json",
+         wgs30x_records: str = "") -> int:
+    """``wgs30x_records``: a file of the benchmark's record bytes for
+    the ``inflate_simd_wgs30x_kernel_only`` row (left out without)."""
     import jax
 
     from disq_tpu.util import enable_compile_cache
@@ -702,7 +724,12 @@ def main(out_path: str = "TPU_KERNELS.json") -> int:
         print(f"ERROR: backend is {backend}, not tpu", file=sys.stderr)
         return 2
     results: list = []
-    for fn in (run_inflate_simd, run_inflate_simd_literal_heavy,
+    rows = [run_inflate_simd, run_inflate_simd_literal_heavy]
+    if wgs30x_records:
+        with open(wgs30x_records, "rb") as f:
+            rows.append(functools.partial(
+                run_inflate_simd_wgs30x, record_bytes=f.read()))
+    for fn in (*rows,
                run_inflate_legacy, run_rans,
                run_rans_simd, run_kernel_fuzz, run_deflate,
                run_device_pipeline_row, run_resident_decode,
@@ -712,7 +739,8 @@ def main(out_path: str = "TPU_KERNELS.json") -> int:
             fn(results)
         except Exception as e:  # record the failure, keep going
             results.append({
-                "kernel": fn.__name__, "error": f"{type(e).__name__}: {e}",
+                "kernel": getattr(fn, "func", fn).__name__,
+                "error": f"{type(e).__name__}: {e}",
                 "correct": False,
             })
     import jaxlib

@@ -816,16 +816,18 @@ def _annotation(name: str):
 
 
 @contextlib.contextmanager
-def span(name: str, **labels: Any) -> Iterator[None]:
+def span(name: str, **labels: Any) -> Iterator[Dict[str, Any]]:
     """Timeline span: emits a ``{ts, dur, name, labels}`` event into the
     ring/JSONL, books the duration in the ``name`` histogram (so
     ``phase_report()`` and percentiles see it) and lies, as
-    ``disq_tpu.<name>``, on the profiler's clock under a capture."""
+    ``disq_tpu.<name>``, on the profiler's clock under a capture.
+    Yields its labels, so the body can add one it only knows at its
+    end."""
     _resolve_span_env()
     t0 = time.perf_counter()
     try:
         with _annotation(name):
-            yield
+            yield labels
     finally:
         _emit_span(name, t0, time.perf_counter() - t0, labels)
 

@@ -659,6 +659,27 @@ def analyze(spans, run, runs, dropped: int = 0) -> str:
         out.append(f"  ({ADVICE['d2h_avoided']})")
         out.append("")
 
+    # the inflate kernel's two factors: supersteps a launch (the d2h
+    # spans' supersteps labels, meta row 2 of each launch) and seconds
+    # a superstep (the dispatcher's device.launch.wait over them)
+    launches = steps = 0
+    wait_s = 0.0
+    for s in spans:
+        labels = s.get("labels") or {}
+        if labels.get("kind") != "inflate":
+            continue
+        if s["name"] == "device.launch.wait":
+            wait_s += s["dur"]
+        elif s["name"] == "device.launch.d2h" and "supersteps" in labels:
+            launches += 1
+            steps += int(labels["supersteps"])
+    if steps:
+        out.append(
+            f"inflate_supersteps: {steps / launches:,.0f} a launch over "
+            f"{launches} launches, {wait_s / steps * 1e6:.2f} us a "
+            "superstep (device.launch.wait)")
+        out.append("")
+
     top = order[0]
     out.append(
         f"verdict: {top} is the bottleneck — "

@@ -15,6 +15,7 @@ import zlib
 import numpy as np
 import pytest
 
+from disq_tpu.ops import inflate as tables
 from disq_tpu.ops.inflate_simd import inflate_payloads_simd
 
 
@@ -244,3 +245,364 @@ class TestCopyWidthBoundaries:
                 raws.append(raw)
                 payloads.append(deflate(raw, 9))
         check(payloads, raws)
+
+
+# ---------------------------------------------------------------------------
+# The fused token schedule: a match's length, its distance and its first
+# copy chunk in ONE superstep. Hand-built streams (zlib would never emit
+# them) held to zlib's own inflate; raw launches, so each lane's status
+# and the launch's superstep count (meta row 2) can be read.
+# ---------------------------------------------------------------------------
+
+# RFC 1951's tables, as plain ints (the bit writer shifts Python ints)
+_LBASE, _LEXT, _DBASE, _DEXT, _CLORDER, _FIXED_LENS = (
+    t.tolist() for t in (tables._LBASE, tables._LEXT, tables._DBASE,
+                         tables._DEXT, tables._CLORDER, tables._FIXED_LENS))
+
+
+class Bits:
+    """DEFLATE bit writer: header fields and extra bits LSB first,
+    Huffman codes MSB first."""
+
+    def __init__(self):
+        self.acc = self.n = 0
+
+    def put(self, value, nbits):
+        self.acc |= (value & ((1 << nbits) - 1)) << self.n
+        self.n += nbits
+
+    def code(self, code, nbits):
+        for i in range(nbits - 1, -1, -1):
+            self.put((code >> i) & 1, 1)
+
+    def full_flush(self):
+        # what Z_FULL_FLUSH writes: an empty stored block, byte-aligned
+        self.put(0, 3)
+        self.n = (self.n + 7) & ~7
+        self.put(0, 16)
+        self.put(0xFFFF, 16)
+
+    def bytes(self):
+        return self.acc.to_bytes((self.n + 7) // 8, "little")
+
+
+def canonical(lens):
+    """{symbol: (code, nbits)} of the canonical Huffman code."""
+    code, out = 0, {}
+    for nbits in range(1, 16):
+        for sym, l in enumerate(lens):
+            if l == nbits:
+                out[sym] = (code, nbits)
+                code += 1
+        code <<= 1
+    return out
+
+
+_FIXED_L = canonical(_FIXED_LENS[:288])
+_FIXED_D = canonical(_FIXED_LENS[288:])
+
+
+def match_symbols(length, dist):
+    """(length symbol, its extra bits' value, distance symbol, its)."""
+    ls = max(i for i in range(29) if _LBASE[i] <= length)
+    ds = max(i for i in range(30) if _DBASE[i] <= dist)
+    return 257 + ls, length - _LBASE[ls], ds, dist - _DBASE[ds]
+
+
+def put_tokens(b, tokens, lcodes, dcodes):
+    """A block's tokens and its end-of-block. A token is a literal
+    (int), a match ``(length, dist)``, or ``("sym", length symbol,
+    extra, distance symbol, extra)`` for symbols no encoder would
+    write; a distance symbol of None cuts the stream right there."""
+    for t in tokens:
+        if isinstance(t, int):
+            b.code(*lcodes[t])
+            continue
+        ls, lx, ds, dx = t[1:] if t[0] == "sym" else match_symbols(*t)
+        b.code(*lcodes[ls])
+        b.put(lx, _LEXT[ls - 257] if ls - 257 < 29 else 0)
+        if ds is None:
+            return
+        b.code(*dcodes[ds])
+        b.put(dx, _DEXT[ds])
+    b.code(*lcodes[256])
+
+
+def fixed_block(b, tokens, final=True):
+    b.put(int(final), 1)
+    b.put(1, 2)
+    put_tokens(b, tokens, _FIXED_L, _FIXED_D)
+
+
+def dynamic_block(b, tokens, lit_lens, dist_lens, final=True):
+    """A dynamic-Huffman block with the given code lengths ({symbol:
+    bits}; both codes complete, as zlib demands); each length goes out
+    as its own 4-bit code-length symbol, no repeats."""
+    hlit = max(max(lit_lens) + 1, 257)
+    hdist = max(dist_lens) + 1
+    ll = [lit_lens.get(s, 0) for s in range(hlit)]
+    dl = [dist_lens.get(s, 0) for s in range(hdist)]
+    b.put(int(final), 1)
+    b.put(2, 2)
+    b.put(hlit - 257, 5)
+    b.put(hdist - 1, 5)
+    b.put(19 - 4, 4)
+    cl_lens = [4] * 16 + [0] * 3
+    for s in _CLORDER:
+        b.put(cl_lens[s], 3)
+    cl = canonical(cl_lens)
+    for l in ll + dl:
+        b.code(*cl[l])
+    put_tokens(b, tokens, canonical(ll), canonical(dl))
+
+
+def flat_lens(symbols, alphabet):
+    """Equal code lengths over ``symbols``, padded with unused symbols
+    of the alphabet to a power of two so that the code is complete."""
+    syms = sorted(set(symbols))
+    n = 2
+    while n < len(syms):
+        n *= 2
+    syms += [s for s in range(alphabet) if s not in syms][: n - len(syms)]
+    return {s: n.bit_length() - 1 for s in syms}
+
+
+def raw_launch(payloads, cw=128, ow=64):
+    """One launch of the kernel itself: (each lane's output bytes, the
+    (4, 128) meta rows: outpos, status, supersteps). One geometry (512
+    compressed bytes in, 256 out) for all the small streams, so the
+    interpreter traces the kernel for them once."""
+    import jax.numpy as jnp
+
+    from disq_tpu.ops import inflate_simd as S
+
+    fn = S._compiled(cw, ow, True)
+    comp, clen = S._pack_chunk(payloads, cw)
+    words, meta = fn(jnp.asarray(comp), jnp.asarray(clen),
+                     *(jnp.asarray(t) for t in S._CONST_TABLES))
+    words, meta = np.asarray(words), np.asarray(meta)
+    outs = [np.ascontiguousarray(words[:, i]).tobytes()[: meta[0, i]]
+            for i in range(len(payloads))]
+    return outs, meta
+
+
+# staircase code lengths 1, 2, ... 14, 15, 15: complete, and its last
+# two symbols get the longest codes the format allows
+_LONG_LIT = dict(zip(b"abcdefghijklm", range(1, 14)))
+_LONG_LIT.update({256: 14, 284: 15, ord("z"): 15})
+_LONG_DIST = dict(zip(range(14), range(1, 15)))
+_LONG_DIST.update({14: 15, 28: 15})
+_FILLER_LEN = 16640
+
+
+def _filler():
+    """16,640 bytes that zlib turns into long matches (16 output bytes
+    a superstep), yet no two 512-byte stretches alike, so a copy from
+    the wrong place shows."""
+    rng = np.random.default_rng(11)
+    return b"".join(
+        rng.integers(0, 256, 32, dtype=np.uint8).tobytes() * 16
+        for _ in range(_FILLER_LEN // 512 + 1))[:_FILLER_LEN]
+
+
+def longest_codes_stream(align):
+    """Filler, a full flush, then a dynamic block whose one match is a
+    15-bit length code + 5 extra bits and a 15-bit distance code + 13
+    extra bits (distance 16,585: past the ring, so the far-history
+    fetch runs inside the fused step), at output alignment ``align``."""
+    c = zlib.compressobj(9, zlib.DEFLATED, -15)
+    head = c.compress(_filler()) + c.flush(zlib.Z_FULL_FLUSH)
+    b = Bits()
+    dynamic_block(
+        b, list(b"abc"[:align]) + [("sym", 284, 21, 28, 200), ord("z")],
+        _LONG_LIT, _LONG_DIST)
+    return head + b.bytes()
+
+
+def boundary_stream(kind, lead):
+    """A match as the last token before end-of-block, a full flush,
+    then a match as the very first token after the next block's header
+    (it reaches back across the flush) and another as its last."""
+    first = list(b"abcdefgh"[:lead]) + [(5, 3)]
+    second = [(6, 8), ord("q"), (4, 2)]
+    b = Bits()
+    for tokens, final in ((first, False), (second, True)):
+        if kind == "fixed":
+            fixed_block(b, tokens, final)
+        else:
+            lits = [t for t in tokens if isinstance(t, int)] + [256]
+            syms = [match_symbols(*t) for t in tokens
+                    if not isinstance(t, int)]
+            dynamic_block(
+                b, tokens, flat_lens(lits + [m[0] for m in syms], 286),
+                flat_lens([m[2] for m in syms], 30), final)
+        if not final:
+            b.full_flush()
+    return b.bytes()
+
+
+_HEALTHY = (
+    [("longest", a) for a in range(4)]
+    + [(kind, lead) for kind in ("fixed", "dynamic") for lead in (5, 6, 7, 8)]
+)
+
+
+@pytest.fixture(scope="module")
+def healthy_launch():
+    payloads = [longest_codes_stream(a) if kind == "longest"
+                else boundary_stream(kind, a) for kind, a in _HEALTHY]
+    from disq_tpu.ops.inflate_simd import buckets_for
+
+    outs, meta = raw_launch(payloads,
+                            *buckets_for(payloads, _FILLER_LEN + 256))
+    return payloads, outs, meta
+
+
+class TestFusedMatch:
+    @pytest.mark.parametrize("lane", range(len(_HEALTHY)),
+                             ids=[f"{k}-{a}" for k, a in _HEALTHY])
+    def test_hand_built_lane_equals_zlib(self, healthy_launch, lane):
+        payloads, outs, meta = healthy_launch
+        want = zlib.decompress(payloads[lane], -15)
+        if _HEALTHY[lane][0] == "longest":
+            # the match really is the far one: 248 bytes from 16,585 back
+            at = _FILLER_LEN + _HEALTHY[lane][1]
+            assert want[at: at + 248] == want[at - 16585: at - 16585 + 248]
+        assert meta[1, lane] == 0
+        assert outs[lane] == want
+
+    def test_longest_codes_are_the_formats_longest(self):
+        lcodes = canonical([_LONG_LIT.get(s, 0) for s in range(285)])
+        dcodes = canonical([_LONG_DIST.get(s, 0) for s in range(29)])
+        assert lcodes[284][1] == 15 and _LEXT[284 - 257] == 5
+        assert dcodes[28][1] == 15 and _DEXT[28] == 13
+        assert _DBASE[28] + 200 > 4088  # RING_SAFE: the far path
+
+
+def _fixed(tokens, final=True):
+    b = Bits()
+    fixed_block(b, tokens, final)
+    return b.bytes()
+
+
+_ABCD = list(b"abcd")
+# (name, payload, status, outpos): every fault lies inside the one
+# superstep that reads the match; the lane emits nothing in it. The
+# launch's output is 256 bytes, so a 257th overflows.
+_FAULTS = [
+    ("ok-first", _fixed(_ABCD + [(4, 4), (10, 4)]), 0, 18),
+    ("dist-symbol-30", _fixed(_ABCD + [("sym", 257, 0, 30, 0)]), 3, 4),
+    ("dist-symbol-31", _fixed(_ABCD + [("sym", 260, 0, 31, 0)]), 3, 4),
+    ("length-symbol-286", _fixed(_ABCD + [("sym", 286, 0, 0, 0)]), 3, 4),
+    ("dist-beyond-output", _fixed(list(b"ab") + [(3, 5)]), 4, 2),
+    ("ok-middle", _fixed(list(b"xyzw") * 2 + [(20, 8), ord("!")]), 0, 29),
+    ("copy-overflows-ow", _fixed(_ABCD + [(252, 4), (4, 4)]), 5, 256),
+    ("ok-last", _fixed(_ABCD + [(248, 4), (4, 28)]), 0, 256),
+]
+
+
+@pytest.fixture(scope="module")
+def fault_launch():
+    return raw_launch([p for _n, p, _s, _o in _FAULTS])
+
+
+class TestFusedMatchFaults:
+    @pytest.mark.parametrize("lane", range(len(_FAULTS)),
+                             ids=[f[0] for f in _FAULTS])
+    def test_fault_lands_on_its_own_lane(self, fault_launch, lane):
+        outs, meta = fault_launch
+        _name, payload, status, outpos = _FAULTS[lane]
+        assert (meta[1, lane], meta[0, lane]) == (status, outpos)
+        if status == 0:
+            assert outs[lane] == zlib.decompress(payload, -15)
+
+    def test_stream_cut_between_length_and_distance(self):
+        # the kernel reads zero bits for the distance: it flags the lane
+        # (overrun, or a length that is not the expected one) and host
+        # zlib then refuses the stream; the neighbour is untouched
+        good = _ABCD + [(4, 4), (10, 4)]
+        cut = _fixed(_ABCD * 3 + [("sym", 265, 1, None, 0)], final=False)
+        outs, meta = raw_launch([_fixed(good), cut])
+        assert meta[1, 0] == 0 and outs[0] == b"abcd" * 4 + b"ab"
+        assert meta[1, 1] != 0 or meta[0, 1] != 12 + 12
+        with pytest.raises(ValueError, match="corrupt DEFLATE"):
+            inflate_payloads_simd([_fixed(good), cut], usizes=[18, 24],
+                                  interpret=True)
+
+
+def fused_supersteps(tokens):
+    """Supersteps the fused schedule takes for one fixed-Huffman block:
+    the header, a step a literal (one for two where both fit the output
+    word), a step a copy chunk (the match's length and distance ride on
+    its first), the end-of-block."""
+    steps, outpos, i = 1, 0, 0
+    while i < len(tokens):
+        t = tokens[i]
+        if isinstance(t, int):
+            pair = (i + 1 < len(tokens) and isinstance(tokens[i + 1], int)
+                    and outpos & 3 <= 2)
+            i += 2 if pair else 1
+            outpos += 2 if pair else 1
+            steps += 1
+            continue
+        length, dist = t
+        while length:
+            off = outpos & 3
+            k = 16 if off == 0 and dist >= 16 else \
+                8 if off == 0 and dist >= 8 else 4 - off
+            k = min(k, length)
+            length -= k
+            outpos += k
+            steps += 1
+        i += 1
+    return steps + 1
+
+
+class TestSchedulePin:
+    @pytest.mark.parametrize("length,dist,n", [(4, 4, 40), (10, 4, 25),
+                                               (3, 8, 40), (37, 16, 6)])
+    def test_a_match_costs_no_step_of_its_own(self, length, dist, n):
+        # n short matches: with the length and the distance each on a
+        # superstep of their own (the schedule before the fusion) the
+        # launch would take 2 n more
+        tokens = list(bytes(range(65, 65 + dist))) + [(length, dist)] * n
+        payload = _fixed(tokens)
+        outs, meta = raw_launch([payload])
+        assert meta[1, 0] == 0
+        assert outs[0] == zlib.decompress(payload, -15)
+        assert meta[2, 0] <= fused_supersteps(tokens)
+
+
+class TestSuperstepCounter:
+    @pytest.mark.parametrize("route", ["direct", "service"])
+    def test_supersteps_booked_once_a_launch(self, route):
+        from disq_tpu.runtime.tracing import (
+            REGISTRY, spans, telemetry_snapshot)
+
+        raws = [text_like(150 + 17 * i) for i in range(5)]
+        payloads = [deflate(r) for r in raws]
+        usizes = [len(r) for r in raws]
+        _outs, meta = raw_launch(payloads)
+        steps = REGISTRY.counter("device.inflate.supersteps")
+        launches = REGISTRY.counter("device.kernel_launches")
+        base = steps.total(), launches.value(kernel="inflate_simd")
+        if route == "direct":
+            got = inflate_payloads_simd(payloads, usizes=usizes,
+                                        interpret=True)
+        else:
+            from disq_tpu.runtime.device_service import DeviceDecodeService
+
+            svc = DeviceDecodeService(flush_timeout_s=0.05, interpret=True)
+            try:
+                blob, offs = svc.submit_inflate(payloads, usizes).result(300)
+            finally:
+                svc.close()
+            got = [blob[offs[i]: offs[i + 1]].tobytes()
+                   for i in range(len(raws))]
+        assert got == raws
+        assert launches.value(kernel="inflate_simd") - base[1] == 1
+        assert steps.total() - base[0] == meta[2, 0] > 0
+        booked = [s for s in spans() if s["name"] == "device.launch.d2h"
+                  and s["labels"].get("kind") == "inflate"]
+        assert booked[-1]["labels"]["supersteps"] == meta[2, 0]
+        assert "device.inflate.supersteps" in telemetry_snapshot()["counters"]

@@ -29,8 +29,25 @@ pytestmark = pytest.mark.skipif(
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
+def wgs30x_record_bytes() -> bytes:
+    """Record bytes of the benchmark's own shape for the
+    ``inflate_simd_wgs30x_kernel_only`` row: 24,000 ``wgs30x`` records
+    (8.4 MB: 128 lanes of 65,280 bytes). The package does not import
+    the benchmark, so the caller makes them."""
+    sys.path.insert(0, REPO)
+    try:
+        from benchmark import gen, reference
+    finally:
+        sys.path.pop(0)
+    with open(os.path.join(REPO, "benchmark", "configs", "wgs30x.json")) as f:
+        cfg = json.load(f)
+    return reference.encode_records(gen.generate(24000, 27, cfg))
+
+
 def test_device_kernels_on_chip(tmp_path):
     out = tmp_path / "TPU_KERNELS.json"
+    records = tmp_path / "wgs30x_records.bin"
+    records.write_bytes(wgs30x_record_bytes())
     # CPU parent, chip child: this process is pinned to the CPU by the
     # conftest and never touches the chip, so the child may take it.
     # Drop the conftest's overrides; JAX_PLATFORMS is unset
@@ -39,7 +56,8 @@ def test_device_kernels_on_chip(tmp_path):
     env = {k: v for k, v in os.environ.items()
            if k not in ("JAX_PLATFORMS", "XLA_FLAGS")}
     proc = subprocess.run(
-        [sys.executable, "-m", "disq_tpu.ops.tpu_ci", str(out)],
+        [sys.executable, "-m", "disq_tpu.ops.tpu_ci", str(out),
+         str(records)],
         env=env, cwd=REPO, capture_output=True, text=True, timeout=900,
     )
     # non-zero on any failed kernel AND when the child found no TPU
@@ -50,6 +68,7 @@ def test_device_kernels_on_chip(tmp_path):
     assert rows["inflate_simd"]["correct"]
     assert rows["inflate_simd"]["mb_per_sec"] > 1.0
     assert rows["rans_order0_decode"]["correct"]
+    assert rows["inflate_simd_wgs30x_kernel_only"]["supersteps_per_launch"] > 0
     # refresh the repo-root artifact for the judge
     with open(os.path.join(REPO, "TPU_KERNELS.json"), "w") as f:
         json.dump(artifact, f, indent=1)

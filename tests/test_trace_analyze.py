@@ -142,6 +142,24 @@ class TestVerdict:
                 "wall-clock") in out
         assert "CPU-bound record decode" in out
 
+    def test_inflate_launches_split_into_supersteps_and_their_cost(self):
+        """The inflate kernel's two factors: supersteps a launch from
+        the d2h spans' label, seconds a superstep from the waits."""
+        spans = []
+        for i, steps in enumerate((18000, 17000)):
+            spans += [
+                _span("device.launch.wait", 2.0 * i, 0.2,
+                      kind="inflate", launch=i),
+                _span("device.launch.d2h", 2.0 * i + 0.2, 0.01,
+                      kind="inflate", launch=i, supersteps=steps),
+            ]
+        spans.append(_span("device.launch.wait", 5.0, 3.0, kind="rans"))
+        out = trace_report.analyze(spans, "r1", ["r1"])
+        assert ("inflate_supersteps: 17,500 a launch over 2 launches, "
+                "11.43 us a superstep") in out
+        assert "inflate_supersteps" not in trace_report.analyze(
+            SPANS, "r1", ["r1"])
+
     def test_no_spans(self):
         assert "no spans" in trace_report.analyze([], None, [])
 
