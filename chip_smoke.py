@@ -13,7 +13,8 @@ The quickest proof that disq-tpu still starts on a TPU. One process:
    ``.device_deflate()`` (byte-valid: ``gzip -t`` + identical records), runs
    the operator chain, a CRAM round trip with ``DISQ_TPU_DEVICE_RANS=1`` and
    a few serve requests — every device result held to the host result;
-4. with four or more chips, repeats read → sort → write on a 4-device mesh.
+4. with four or more chips, repeats read → sort → write on a 4-device mesh,
+   at size/8 splits and at the configured split size.
 
 Every leg runs twice (cold, then warm in the same process). Any failed
 check raises: no leg's failure is turned into a note. Without a TPU the
@@ -650,6 +651,7 @@ class Smoke:
         from disq_tpu.runtime.tracing import telemetry_snapshot
 
         out = self.path("mesh_sorted.bam")
+        out_whole = self.path("mesh_sorted_whole_splits.bam")
         before = device_counters()
         # the service snapshots its dispatch devices when it starts, so
         # the mesh knob is armed before the first mesh-leg submission
@@ -677,10 +679,23 @@ class Smoke:
                                    "mesh leg")
                 storage.write(ds, out, *sorted_bam_options(), sort=True)
                 ds.reads.release()
+                # and the shape the benchmark's wgs_mesh4 runs: the
+                # same chain at the configured (default 128 MiB) split
+                # size, where a chip is fed one whole split at a time
+                whole = self.device_storage().mesh(4)
+                ds = whole.read(self.input)
+                check(ds.reads.mesh is not None
+                      and ds.flagstat() == self.want_flagstat,
+                      "mesh leg at the default split size: no mesh, or "
+                      "flagstat != reference")
+                whole.write(ds, out_whole, *sorted_bam_options(), sort=True)
+                ds.reads.release()
             finally:
                 os.environ.pop("DISQ_TPU_MESH", None)
                 device_service.shutdown_service()
         self.assert_sorted_identical(out, "mesh leg")
+        self.assert_sorted_identical(
+            out_whole, "mesh leg at the default split size")
         after = device_counters()
         fills = telemetry_snapshot()["gauges"].get("device.lane_fill", {})
         rows = sorted(k for k in fills if k.startswith("device="))

@@ -640,7 +640,8 @@ class ColumnarBatch:
             return out
         fns = _jax_fns()
         jax, jnp = fns["jax"], fns["jnp"]
-        from disq_tpu.runtime.tracing import count_transfer, device_span
+        from disq_tpu.runtime.tracing import (
+            count_transfer, device_span, span)
 
         n_dev = jnp.asarray(np.int32(self._n))  # staged pre-guard
         with device_span("device.kernel", kernel="coordinate_keys",
@@ -650,7 +651,8 @@ class ColumnarBatch:
                     dev["refid"], dev["pos"], n_dev)
                 jax.block_until_ready(order)
             fence.sync(order)
-        out = np.asarray(order[: self._n])
+        with span("sort.gather", stage="fetch", records=self._n):
+            out = np.asarray(order[: self._n])
         count_transfer("d2h", out.nbytes)
         # the 8-byte-per-record key vector stayed on device
         self._consume_on_device("sort_keys", 8 * self._n)

@@ -131,7 +131,9 @@ def _mesh_parse_compiled(mesh, interpret: bool):
     from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
-    def body(blob_words, starts):
+    # its name is the program's in a device trace
+    # (``jit_mesh_parse_columns``): a reader keys on it
+    def mesh_parse_columns(blob_words, starts):
         words = gather_record_words(blob_words, starts)
         return parse_fixed_words_pallas(words, interpret=interpret)
 
@@ -139,7 +141,7 @@ def _mesh_parse_compiled(mesh, interpret: bool):
     # type through pallas_call; the body is per-device-local by
     # construction
     return jax.jit(shard_map(
-        body, mesh=mesh, in_specs=(P(None), P(MESH_AXIS)),
+        mesh_parse_columns, mesh=mesh, in_specs=(P(None), P(MESH_AXIS)),
         out_specs=P(MESH_AXIS), check_vma=False))
 
 

@@ -28,11 +28,13 @@ intermediate ``bytes`` objects on the device path.
 Multi-chip (the mesh-native pipeline, ``runtime/mesh.py``): when the
 mesh knob is armed at service creation, each codec keeps one sub-queue
 PER DEVICE and the single dispatcher feeds them all — a submission's
-lanes land on the least-loaded device, each launch runs under
+lanes land on the least-loaded device (lanes queued or in flight;
+ties rotate), each launch runs under
 ``jax.default_device(dev)`` (const tables and staging land on that
-chip, ``inflate_simd._device_const_tables`` is device-keyed), and the
-in-flight window scales by the device count so every chip keeps a full
-pipeline instead of device 0 taking all launches.  Mesh off, the
+chip, ``inflate_simd._device_const_tables`` is device-keyed), the next
+chunk is taken for the chip with the fewest launches in flight, and
+the in-flight window scales by the device count, so every chip keeps a
+full pipeline instead of one chip taking all launches.  Mesh off, the
 device list is ``[None]`` and every code path below degenerates to the
 exact single-queue behavior it had before.
 
@@ -66,7 +68,7 @@ from __future__ import annotations
 
 import threading
 import time
-from collections import deque
+from collections import Counter, deque
 from typing import Any, Deque, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -413,8 +415,11 @@ class DeviceDecodeService:
             k: [deque() for _ in range(n_dev)]
             for k in ("inflate", "rans", "deflate")}
         self._next_queue = 0  # tie-break rotation (see _enqueue)
+        # by device, under ``_cond``: lanes queued or in flight (what
+        # ``_enqueue`` balances)
+        self._outstanding = [0] * n_dev
         self._inflight: Deque[
-            Tuple[str, Any, List[_Lane], Dict[str, Any]]] = deque()
+            Tuple[str, Any, List[_Lane], Dict[str, Any], int]] = deque()
         self._closed = False
         # dispatcher-thread only: launches so far (the ``launch`` label
         # that joins one launch's spans) and the seconds slept in
@@ -537,17 +542,22 @@ class DeviceDecodeService:
         with self._cond:
             if self._closed:
                 raise RuntimeError("device decode service is closed")
-            # least-loaded device sub-queue takes the whole batch (one
+            # least-loaded device takes the whole batch (one
             # submission's lanes stay together — they share pack
-            # geometry and error scope); ties rotate, or a stream of
-            # small submissions that each drain before the next arrives
-            # (serve queries, tiny splits) would all land on device 0.
-            # With one device this is the old single-queue append
+            # geometry and error scope).  Load is the device's lanes
+            # queued or in flight, of every codec: a queue that has
+            # drained into launches still running is not a free chip
+            # (four splits in flight, four chips).  Ties rotate, or a
+            # stream of small submissions that each drain before the
+            # next arrives (serve queries, tiny splits) would all land
+            # on device 0.  With one device this is the old
+            # single-queue append
             subqs = self._queues[kind]
             n_q = len(subqs)
             pick = min(range(n_q), key=lambda i: (
-                len(subqs[i]), (i - self._next_queue) % n_q))
+                self._outstanding[i], (i - self._next_queue) % n_q))
             self._next_queue = (pick + 1) % n_q
+            self._outstanding[pick] += len(lanes)
             subqs[pick].extend(lanes)
             depth = sum(
                 len(q) for qs in self._queues.values() for q in qs)
@@ -648,19 +658,28 @@ class DeviceDecodeService:
                     raise
                 if entry is not None:
                     self._inflight.append(entry)
+                else:
+                    self._settle(dev_i, len(lanes))
             if self._inflight and (chunk is None
                                    or len(self._inflight) >= self._window):
                 self._materialize(self._inflight.popleft())
 
     def _take_chunk_locked(self):
         now = time.perf_counter()
-        # oldest-lane-first across (kind, device) sub-queues: a
-        # sustained full-chunk burst on one codec or chip must not
+        # the chip with the fewest launches in flight first, so that
+        # every chip keeps its own pipeline of them: a submission's
+        # lanes carry one timestamp, and oldest-first alone issues all
+        # of one chip's launches before the next chip's first (four
+        # splits, four chips: the chips inflated one after the other).
+        # Among a chip's queues, and with one device, oldest lane
+        # first: a sustained full-chunk burst on one codec must not
         # starve another queue's lanes past their flush deadline
+        flying = Counter(e[4] for e in self._inflight)
         ready = sorted(
             ((k, i) for k, qs in self._queues.items()
              for i, q in enumerate(qs) if q),
-            key=lambda ki: self._queues[ki[0]][ki[1]][0].ts)
+            key=lambda ki: (flying[ki[1]],
+                            self._queues[ki[0]][ki[1]][0].ts))
         for kind, i in ready:
             q = self._queues[kind][i]
             if len(q) >= LANES:
@@ -702,7 +721,12 @@ class DeviceDecodeService:
                                 reason=reason, lanes=len(lanes))
         # mesh-off ([None]) keeps the historic unlabeled gauge; a real
         # device list labels fill per chip so partial lanes on one
-        # device are visible, not averaged away
+        # device are visible, not averaged away.  Likewise the queue
+        # wait and the five per-launch spans carry ``device`` (the
+        # index in ``service_devices()``) only then: which chip a
+        # launch went to says whether a mesh read uses its chips
+        on_chip = {} if dev is None else {"device": dev_i}
+        labels.update(on_chip)
         if dev is None:
             _observe_gauge("device.lane_fill", len(lanes) / LANES)
         else:
@@ -713,7 +737,7 @@ class DeviceDecodeService:
             sum(len(q) for qs in self._queues.values() for q in qs))
         _record_span("device.service.wait",
                      time.perf_counter() - min(l.ts for l in lanes),
-                     kind=kind, lanes=len(lanes))
+                     kind=kind, lanes=len(lanes), **on_chip)
         # group lanes by owning request context (None = untraced): a
         # coalesced launch serves n distinct requests, and each owner
         # inherits its share of queue wait + launch time below
@@ -752,15 +776,23 @@ class DeviceDecodeService:
                                  max(0.0, wait) + share, kind=kind,
                                  lanes=len(own_lanes),
                                  batch_lanes=len(lanes))
-        return kind, handle, lanes, labels
+        return kind, handle, lanes, labels, dev_i
+
+    def _settle(self, dev_i: int, n_lanes: int) -> None:
+        """``n_lanes`` of device ``dev_i`` are delivered (or failed):
+        no longer part of its load."""
+        with self._cond:
+            self._outstanding[dev_i] -= n_lanes
 
     def _materialize(self, entry) -> None:
-        kind, handle, lanes, labels = entry
+        kind, handle, lanes, labels, dev_i = entry
         try:
             self._engines[kind].finalize(handle, lanes, labels)
         except BaseException as e:  # noqa: BLE001 — owners, not the loop
             for lane in lanes:
                 lane.sub.fail(e)
+        finally:
+            self._settle(dev_i, len(lanes))
 
     def _abort_all(self, exc: BaseException) -> None:
         with self._cond:
@@ -772,7 +804,7 @@ class DeviceDecodeService:
                     q.clear()
             inflight = list(self._inflight)
             self._inflight.clear()
-        for _kind, _handle, lanes, _labels in inflight:
+        for _kind, _handle, lanes, _labels, _dev_i in inflight:
             pending.extend(lanes)
         for lane in pending:
             lane.sub.fail(exc)

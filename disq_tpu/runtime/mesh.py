@@ -42,6 +42,9 @@ import threading
 from typing import Any, List, Optional
 
 MESH_AXIS = "batch"
+MESH_COUNTERS = ("device.mesh.batches", "device.mesh.exchange_bytes",
+                 "device.mesh.reshard_bytes",
+                 "device.mesh.sort_host_fallback")
 
 _MESH_CACHE: dict = {}
 _MESH_LOCK = threading.Lock()
@@ -104,6 +107,14 @@ def get_mesh(requested: int = 0):
             from disq_tpu.runtime.tracing import observe_gauge
 
             observe_gauge("device.mesh.devices", float(n))
+    # registered at 0 wherever a mesh is handed out (the cached one
+    # too: telemetry may have been reset since it was built), so that
+    # a reader tells "did not move" (the host fallback of a sound
+    # sort) from "no such counter"
+    from disq_tpu.runtime.tracing import counter
+
+    for name in MESH_COUNTERS:
+        counter(name).inc(0)
     return mesh
 
 

@@ -53,6 +53,7 @@ def coordinate_sort_batch(batch: ReadBatch, use_mesh: bool = True,
     multi-chip psum/all_to_all exchange.
     """
     from disq_tpu.runtime.columnar import ColumnarBatch
+    from disq_tpu.runtime.tracing import span
 
     if isinstance(batch, ColumnarBatch):
         if batch.device_backed and batch.count > 0:
@@ -64,9 +65,13 @@ def coordinate_sort_batch(batch: ReadBatch, use_mesh: bool = True,
             # least-significant lexsort component, so duplicate keys
             # keep original-index order at any device count.
             order = batch.sort_permutation()
-            if keep_resident and batch.encode_source() is not None:
-                return batch.permuted(order)
-            return batch.take(order)
+            # with the permutation's fetch (``stage=fetch``, booked
+            # where it crosses d2h) this is what lies between the sort
+            # kernel and the write: the records gathered on the host
+            with span("sort.gather", stage="gather", records=batch.count):
+                if keep_resident and batch.encode_source() is not None:
+                    return batch.permuted(order)
+                return batch.take(order)
         resident_src = batch if keep_resident else None
         batch = batch.to_read_batch()
     else:

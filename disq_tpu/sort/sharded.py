@@ -39,7 +39,11 @@ import jax.numpy as jnp
 from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-SENT32 = jnp.uint32(0xFFFFFFFF)
+# a numpy scalar, not ``jnp.uint32(...)``: a device array made at import
+# and closed over by the jitted stages is read back (d2h) the first
+# time one of them is traced, and under ``resident_coordinate_sort``'s
+# ``transfer_guard("disallow")`` that read raises on a real chip
+SENT32 = np.uint32(0xFFFFFFFF)
 
 
 def make_mesh(n_devices: Optional[int] = None, axis: str = "shards") -> Mesh:
@@ -734,7 +738,7 @@ def _resident_keys_compiled(mesh: Mesh, axis: str, n_shards: int):
     the single-device ``coord_perm`` (unmapped → 0x7FFFFFFF, bucket
     padding → full-sentinel pairs) plus global row ids, reshaped to the
     (n_shards, per) exchange layout with zero resharding."""
-    def build(refid, pos, n):
+    def mesh_sort_keys(refid, pos, n):
         m = refid.shape[0]
         valid = jnp.arange(m, dtype=jnp.int32) < n
         rid = jnp.where(refid < 0, jnp.uint32(0x7FFFFFFF),
@@ -746,7 +750,7 @@ def _resident_keys_compiled(mesh: Mesh, axis: str, n_shards: int):
         return hi.reshape(shp), lo.reshape(shp), rows.reshape(shp)
 
     out_sh = NamedSharding(mesh, P(axis, None))
-    return jax.jit(build, out_shardings=(out_sh, out_sh, out_sh))
+    return jax.jit(mesh_sort_keys, out_shardings=(out_sh, out_sh, out_sh))
 
 
 def _key_byte(hi, lo, level: int):
@@ -764,7 +768,7 @@ def _hist_level_compiled(mesh: Mesh, axis: str, n_cuts: int, level: int):
     one ``lax.psum`` over the mesh axis makes the (n_cuts, 256)
     histogram global. Only that small table crosses d2h per level —
     the keys themselves never move."""
-    def body(hi, lo, pref):
+    def mesh_sort_hist_level(hi, lo, pref):
         hi, lo = hi.reshape(-1), lo.reshape(-1)
         valid = ~((hi == SENT32) & (lo == SENT32))
         tgt = _key_byte(hi, lo, level).astype(jnp.int32)
@@ -780,7 +784,7 @@ def _hist_level_compiled(mesh: Mesh, axis: str, n_cuts: int, level: int):
         return lax.psum(hist, axis)
 
     return jax.jit(shard_map(
-        body, mesh=mesh,
+        mesh_sort_hist_level, mesh=mesh,
         in_specs=(P(axis, None), P(axis, None), P(None, None)),
         out_specs=P(None, None)))
 
@@ -848,7 +852,7 @@ def resident_coordinate_sort(
     any device count."""
     from disq_tpu.runtime.mesh import MESH_AXIS
     from disq_tpu.runtime.tracing import (
-        count_transfer, counter, device_span)
+        count_transfer, counter, device_span, span)
 
     if axis is None:
         axis = MESH_AXIS if MESH_AXIS in mesh.axis_names \
@@ -867,7 +871,10 @@ def resident_coordinate_sort(
                 mesh, axis, n_shards)(refid_dev, pos_dev, n_arr)
             jax.block_until_ready(rows2)
         fence.sync(rows2)
-    s_hi_np, s_lo_np = _psum_splitters(hi2, lo2, n, mesh, axis, n_shards)
+    # eight histogram levels, each a psum and a d2h of its table
+    with span("sort.mesh.splitters", records=n, devices=n_shards):
+        s_hi_np, s_lo_np = _psum_splitters(
+            hi2, lo2, n, mesh, axis, n_shards)
     repl = NamedSharding(mesh, P(None))
     s_hi = jax.device_put(jnp.asarray(s_hi_np), repl)
     s_lo = jax.device_put(jnp.asarray(s_lo_np), repl)
@@ -885,12 +892,13 @@ def resident_coordinate_sort(
         counter("device.mesh.exchange_bytes").inc(
             3 * 4 * cap * n_shards * n_shards)
         if bool(jnp.all(ok)):
-            cnt = np.asarray(counts).reshape(-1)
-            or_h = np.asarray(orows).reshape(n_shards, -1)
-            count_transfer("d2h", cnt.nbytes + or_h.nbytes)
-            return np.concatenate(
-                [or_h[i, : cnt[i]] for i in range(n_shards)]
-            ).astype(np.int64)
+            with span("sort.gather", stage="fetch", records=n):
+                cnt = np.asarray(counts).reshape(-1)
+                or_h = np.asarray(orows).reshape(n_shards, -1)
+                count_transfer("d2h", cnt.nbytes + or_h.nbytes)
+                return np.concatenate(
+                    [or_h[i, : cnt[i]] for i in range(n_shards)]
+                ).astype(np.int64)
         cf *= 2.0
     # pathological skew defeated the capacity retries: fetch the key
     # columns once and finish on host (counted — this is the documented
