@@ -21,15 +21,18 @@ included). So a token costs one superstep: a literal (two when both fit
 the output word), or a match's length + distance + first copy chunk
 together; a long match then takes one superstep more per chunk. ``meta``
 row 2 carries the launch's superstep count (counter
-``device.inflate.supersteps``). A lane emits 1-2 bytes per literal
-superstep, up to 4 per stored/short-copy superstep, and 8 or 16 (two or
-four output words) in the aligned steady state of a long match (d >= 8
-/ d >= 16).
-All data-dependent indexing uses one vector-gather primitive, the
-one-hot row gather ``sum(where(row_iota == idx, data, 0))``: pure
-compares, selects and a sublane reduction, which Mosaic lowers for any
-row count (whether ``take_along_axis`` would now lower as well has not
-been tried on this compiler). Big-buffer
+``device.inflate.supersteps``), row 3 how many of them read history
+past the ring (``device.inflate.far_supersteps``). A lane emits 1-2
+bytes per literal superstep, up to 4 per stored/short-copy superstep,
+and 8 or 16 (two or four output words) in the aligned steady state of a
+long match (d >= 8 / d >= 16).
+All data-dependent indexing is a one-hot sweep: the row gather
+``sum(where(row_iota == idx, data, 0))``, pure compares, selects and a
+sublane reduction, which Mosaic lowers for any row count (whether
+``take_along_axis`` would now lower as well has not been tried on this
+compiler), and its tile form ``_gather_tile``, which selects a lane's
+aligned 8-row tile (one stored register) instead of a lane's row: the
+same sweep, eight consecutive words a lane instead of one. Big-buffer
 sweeps (comp refill, output RMW, far-history reads) are additionally
 *windowed*: lanes advance in rough lockstep, so each slab's sweep is
 skipped when the live row window [min, max] misses it. Bool (1,128)
@@ -48,10 +51,17 @@ sorted-symbol table). This removes the 512-entry per-lane root-table
 construction sweep entirely — dynamic table build reduces to counting
 sorts over the code-length arrays.
 
-Memory (v1): compressed words, output words and all tables live whole
-in VMEM; history reads and output writes are one-hot sweeps over the
-full (OW,128) output. Correct and Mosaic-friendly, but the sweeps scale
-with buffer size; the windowed slab gates above are what bounds them.
+Memory (v1): compressed words, output words, all tables and a ring of
+each lane's last 4 KiB of output live whole in VMEM. A copy step needs
+up to five consecutive history words a lane; they lie inside two
+aligned 8-word tiles, so it reads them with two tile sweeps of the
+1,024-row ring and, in the supersteps where some lane's distance
+reaches past the ring (``meta`` row 3 counts them), two windowed tile
+sweeps of the (OW,128) output that share their slab gates. Output
+writes are one-hot sweeps over the output's live slabs and the whole
+ring. Correct and Mosaic-friendly, but the sweeps scale with buffer
+size; the windowed slab gates above are what bounds them, and a gate
+is not free (PERF.md §5).
 
 Error codes in meta row 1 (shared with ``ops/inflate.py``): 0 ok ·
 1 bad btype · 2 stored-LEN mismatch · 3 bad Huffman code · 4 invalid
@@ -203,6 +213,57 @@ def _gather(data, rows):
         keepdims=True,
     )
     return lax.bitcast_convert_type(g, _U32) if unsigned else g
+
+
+def _gather_tile(data, tiles):
+    """One-hot tile gather: data (R,128) with R a multiple of 8, tiles
+    (1,128) per-lane tile index → (8,128), lane l holding
+    ``data[8 * tiles[l] : 8 * tiles[l] + 8, l]``. Tile -1 matches
+    nothing and gives zeros, as row -1 does in ``_gather``. An (R,128)
+    buffer is stored as R/8 registers of 8 sublanes × 128 lanes, so this
+    sweep costs what ``_gather``'s does (a compare, a select and an
+    accumulate a register, with no sublane reduction at the end) and
+    returns eight consecutive words a lane where that returns one."""
+    nt = data.shape[0] // 8
+    unsigned = data.dtype == jnp.uint32
+    if unsigned:
+        data = lax.bitcast_convert_type(data, _I32)
+    data = data.reshape(nt, 8, LANES)
+    ti = lax.broadcasted_iota(_I32, (nt, 8, LANES), 0)
+    t = jnp.sum(
+        jnp.where(ti == tiles[None], data, jnp.zeros_like(data)), axis=0)
+    return lax.bitcast_convert_type(t, _U32) if unsigned else t
+
+
+def _gather_tiles_ref_win(ref, tiles, slab: int = _SLAB):
+    """Windowed tile gather over a (possibly large) REF: one
+    ``_gather_tile`` sweep for each (1,128) index vector of ``tiles``,
+    behind ``_gather_ref_win``'s slab gates. The vectors share the
+    gates: a slab's sweeps are skipped (``lax.cond``) when the hull
+    [min, max] of all their live tiles misses it, and tile -1 never
+    anchors the hull. A gate and the hull's two reductions cost more
+    than the sweep they guard (PERF.md §5), so vectors that are read
+    together are gated together."""
+    r = ref.shape[0]
+    if r <= slab:
+        return tuple(_gather_tile(ref[...], t) for t in tiles)
+    lo = functools.reduce(
+        jnp.minimum, [jnp.where(t < 0, jnp.int32(r // 8), t) for t in tiles])
+    tmin = jnp.min(lo)
+    tmax = jnp.max(functools.reduce(jnp.maximum, tiles))
+    zeros = (jnp.zeros((8, LANES), ref.dtype),) * len(tiles)
+    acc = zeros
+    for s in range(0, r, slab):
+        sl = min(slab, r - s)
+
+        def hit(s=s, sl=sl):
+            return tuple(
+                _gather_tile(ref[s:s + sl, :], t - s // 8) for t in tiles)
+
+        got = lax.cond(
+            (tmax >= s // 8) & (tmin < (s + sl) // 8), hit, lambda: zeros)
+        acc = tuple(a | g for a, g in zip(acc, got))
+    return acc
 
 
 def _bcast_np(arr: np.ndarray) -> np.ndarray:
@@ -412,7 +473,7 @@ def _inflate_simd_kernel(
     def superstep(carry):
         (step, state, lo, hi, cnt, in_w, outpos, bfinal, fixed,
          copy_len, copy_dist, hlit, hdist, hclen, tb_idx, tb_nread,
-         rep_val, rep_cnt, prev_len, status) = carry
+         rep_val, rep_cnt, prev_len, status, far_steps) = carry
 
         live = (state != _DONE) & (state != _ERR)
         lo, hi, cnt, in_w = refill64(lo, hi, cnt, in_w)
@@ -682,7 +743,8 @@ def _inflate_simd_kernel(
         # only bytes written by earlier supersteps are ever read.
         # Source bytes come from the 4 KiB circular history ring (last
         # 4096 bytes, word rows = w & (RING_W-1)); distances past the
-        # ring window read the big out buffer under a gated cond. For
+        # ring window read the big out buffer under a gated cond (both
+        # as aligned 8-word tiles, below). For
         # d < 4 the 4 fetched bytes start at outpos-d and are replicated
         # modularly (byte j := B[j mod d]). When the output is
         # word-aligned (the steady state inside a long match — the first
@@ -698,37 +760,38 @@ def _inflate_simd_kernel(
         base = outpos - d
         bw = base >> 2
         bo = ((base & 3) << 3).astype(_U32)
-        rw0 = _gather(ring_ref[...], jnp.where(m, bw & (RING_W - 1), -1))
-        rw1 = _gather(ring_ref[...],
-                      jnp.where(m, (bw + 1) & (RING_W - 1), -1))
-        rw2 = _gather(ring_ref[...],
-                      jnp.where(elig8, (bw + 2) & (RING_W - 1), -1))
-        rw3 = _gather(ring_ref[...],
-                      jnp.where(elig16, (bw + 3) & (RING_W - 1), -1))
-        rw4 = _gather(ring_ref[...],
-                      jnp.where(elig16, (bw + 4) & (RING_W - 1), -1))
+        # The five words bw .. bw+4 (a 16-byte unaligned chunk) lie
+        # inside the aligned 8-word tiles bw >> 3 and (bw >> 3) + 1 at
+        # every alignment: two tile sweeps of the ring (its tile index
+        # wraps) and, for the lanes past the ring, two windowed tile
+        # sweeps of the big out buffer behind one set of slab gates.
+        t0 = bw >> 3
+        ring_t = RING_W // 8 - 1
+        rt0 = _gather_tile(ring_ref[...], jnp.where(m, t0 & ring_t, -1))
+        rt1 = _gather_tile(
+            ring_ref[...], jnp.where(m, (t0 + 1) & ring_t, -1))
         far = m & (d > RING_SAFE)
+        any_far = jnp.any(far)
+        far_steps = far_steps + any_far.astype(_I32)
 
         def far_fetch():
-            r0 = jnp.where(far, jnp.minimum(bw, ow - 1), -1)
-            r1 = jnp.where(far, jnp.minimum(bw + 1, ow - 1), -1)
-            r2 = jnp.where(far & elig8, jnp.minimum(bw + 2, ow - 1), -1)
-            r3 = jnp.where(far & elig16, jnp.minimum(bw + 3, ow - 1), -1)
-            r4 = jnp.where(far & elig16, jnp.minimum(bw + 4, ow - 1), -1)
-            return (_gather_ref_win(out_ref, r0, slab=slab),
-                    _gather_ref_win(out_ref, r1, slab=slab),
-                    _gather_ref_win(out_ref, r2, slab=slab),
-                    _gather_ref_win(out_ref, r3, slab=slab),
-                    _gather_ref_win(out_ref, r4, slab=slab))
+            last = ow // 8 - 1
+            return _gather_tiles_ref_win(
+                out_ref,
+                (jnp.where(far, jnp.minimum(t0, last), -1),
+                 jnp.where(far, jnp.minimum(t0 + 1, last), -1)),
+                slab=slab)
 
-        fw0, fw1, fw2, fw3, fw4 = lax.cond(
-            jnp.any(far), far_fetch,
-            lambda: (zrow_u, zrow_u, zrow_u, zrow_u, zrow_u))
-        w0 = jnp.where(far, fw0, rw0)
-        w1 = jnp.where(far, fw1, rw1)
-        w2 = jnp.where(far, fw2, rw2)
-        w3 = jnp.where(far, fw3, rw3)
-        w4 = jnp.where(far, fw4, rw4)
+        ztile = jnp.zeros((8, LANES), _U32)
+        ft0, ft1 = lax.cond(any_far, far_fetch, lambda: (ztile, ztile))
+        # the words are picked out of the 16 fetched rows by one-hot
+        # (two registers a word)
+        hist = jnp.concatenate(
+            [jnp.where(far, ft0, rt0), jnp.where(far, ft1, rt1)], axis=0)
+        k = bw & 7
+        w0, w1, w2, w3, w4 = (
+            _gather(hist, jnp.where(live_j, k + j, -1))
+            for j, live_j in enumerate((m, m, elig8, elig16, elig16)))
         sh = (_U32(32) - bo) & _U32(31)
         asm = jnp.where(bo == 0, w0, (w0 >> bo) | (w1 << sh))
         asm2 = jnp.where(bo == 0, w1, (w1 >> bo) | (w2 << sh))
@@ -824,7 +887,8 @@ def _inflate_simd_kernel(
 
         return (step + 1, new_state, lo, hi, cnt, in_w, outpos,
                 bfinal, fixed, copy_len, copy_dist, hlit, hdist, hclen,
-                tb_idx, tb_nread, rep_val, rep_cnt, prev_len, new_status)
+                tb_idx, tb_nread, rep_val, rep_cnt, prev_len, new_status,
+                far_steps)
 
     def cond(carry):
         step, state = carry[0], carry[1]
@@ -836,16 +900,17 @@ def _inflate_simd_kernel(
         jnp.int32(0), init_state, zrow_u, zrow_u, zrow, zrow, zrow,
         zrow, zrow, zrow, zrow,
         zrow, zrow, zrow, zrow, zrow, zrow, zrow, zrow, zrow,
+        jnp.int32(0),
     )
     final = lax.while_loop(cond, superstep, init)
     step, state, _lo, _hi, _cnt, _iw, outpos = final[:7]
-    status = final[19]
+    status, far_steps = final[19], final[20]
     # lanes still live at the step cap ran away
     status = jnp.where(
         (state != _DONE) & (state != _ERR), 6, status)
     meta_ref[...] = jnp.concatenate(
         [outpos, status, jnp.broadcast_to(step[None, None], (1, LANES)),
-         jnp.zeros((1, LANES), _I32)], axis=0)
+         jnp.broadcast_to(far_steps[None, None], (1, LANES))], axis=0)
 
 
 @functools.lru_cache(maxsize=16)
@@ -1105,7 +1170,11 @@ def _fetch_chunk(handle, lanes: int,
     An inflate launch's superstep count (``meta`` row 2) is booked as
     ``device.inflate.supersteps`` and as the d2h span's ``supersteps``
     label: over ``device.kernel_launches`` it is supersteps a launch,
-    under the kernel's seconds it is seconds a superstep."""
+    under the kernel's seconds it is seconds a superstep.  ``meta`` row
+    3, the supersteps in which some lane read history past the ring, is
+    booked beside it as ``device.inflate.far_supersteps`` and the label
+    ``far_supersteps``: over the supersteps it is the share that paid
+    the far sweeps of the out buffer."""
     words, meta = handle
     if labels is None:
         labels = {"kind": "inflate", "lanes": lanes}
@@ -1118,7 +1187,9 @@ def _fetch_chunk(handle, lanes: int,
         meta = np.asarray(meta)
         if kernel == "inflate_simd":
             at_end["supersteps"] = supersteps = int(meta[2, 0])
+            at_end["far_supersteps"] = far = int(meta[3, 0])
             _counter("device.inflate.supersteps").inc(supersteps)
+            _counter("device.inflate.far_supersteps").inc(far)
     _count_transfer("d2h", nbytes)
     return words.view(np.uint8), meta
 

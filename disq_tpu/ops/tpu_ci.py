@@ -68,6 +68,14 @@ def _inflate_kernel_only(raws: list, payloads: list):
     return ok, best, meta
 
 
+def _superstep_factors(best: float, meta) -> dict:
+    """A kernel-only launch's two factors: supersteps (``meta`` row 2)
+    and microseconds a superstep."""
+    steps = int(meta[2, 0])
+    return {"supersteps_per_launch": steps,
+            "us_per_superstep": round(best / steps * 1e6, 3)}
+
+
 def run_inflate_simd(results: list) -> None:
     from disq_tpu.ops.inflate_simd import (
         MAX_DEVICE_CSIZE, inflate_payloads_simd,
@@ -99,11 +107,12 @@ def run_inflate_simd(results: list) -> None:
     })
     assert ok, "SIMD inflate output != zlib"
 
-    ok_k, best_k, _meta = _inflate_kernel_only(raws, payloads)
+    ok_k, best_k, meta = _inflate_kernel_only(raws, payloads)
     results.append({
         "kernel": "inflate_simd_kernel_only",
         "shape": "128 lanes x 60000 B",
         "mb_per_sec": round(total / best_k / 1e6, 2),
+        **_superstep_factors(best_k, meta),
         "correct": ok_k,
     })
     assert ok_k, "SIMD inflate kernel-only launch output != zlib"
@@ -164,12 +173,13 @@ def run_inflate_simd_literal_heavy(results: list) -> None:
     rng = np.random.default_rng(7)
     raws = [rng.integers(0, 250, 25000, dtype=np.uint8).tobytes()
             for _ in range(128)]
-    ok, best, _meta = _inflate_kernel_only(raws, [_deflate(r) for r in raws])
+    ok, best, meta = _inflate_kernel_only(raws, [_deflate(r) for r in raws])
     total = sum(len(r) for r in raws)
     results.append({
         "kernel": "inflate_simd_literal_heavy_kernel_only",
         "shape": "128 lanes x 25000 B (no matches)",
         "mb_per_sec": round(total / best / 1e6, 2),
+        **_superstep_factors(best, meta),
         "correct": ok,
     })
     assert ok, "literal-heavy SIMD inflate output != zlib"
@@ -180,22 +190,24 @@ def run_inflate_simd_wgs30x(results: list, record_bytes: bytes) -> None:
     ``wgs30x`` record bytes each (a full BGZF block), zlib 6. The
     caller hands in the record bytes (``benchmark/gen.py`` +
     ``reference.encode_records``; this package does not import the
-    benchmark). Kernel-only, with the launch's two factors: supersteps
-    (meta row 2) and seconds a superstep."""
+    benchmark). Kernel-only, with the launch's two factors (supersteps,
+    seconds a superstep) and the share of its supersteps in which some
+    lane read history past the ring (meta row 3)."""
     block = 65280
     assert len(record_bytes) >= 128 * block, (
         f"{len(record_bytes)} record bytes do not fill 128 lanes")
     raws = [record_bytes[i * block: (i + 1) * block] for i in range(128)]
     payloads = [_deflate(r) for r in raws]
     ok, best, meta = _inflate_kernel_only(raws, payloads)
-    steps = int(meta[2, 0])
+    factors = _superstep_factors(best, meta)
     results.append({
         "kernel": "inflate_simd_wgs30x_kernel_only",
         "shape": "128 lanes x 65280 B of wgs30x records, zlib 6",
         "mb_per_sec": round(128 * block / best / 1e6, 2),
         "ratio_zlib6": round(128 * block / sum(map(len, payloads)), 3),
-        "supersteps_per_launch": steps,
-        "us_per_superstep": round(best / steps * 1e6, 3),
+        **factors,
+        "far_superstep_share": round(
+            int(meta[3, 0]) / factors["supersteps_per_launch"], 4),
         "correct": ok,
     })
     assert ok, "wgs30x SIMD inflate output != its input"

@@ -661,8 +661,10 @@ def analyze(spans, run, runs, dropped: int = 0) -> str:
 
     # the inflate kernel's two factors: supersteps a launch (the d2h
     # spans' supersteps labels, meta row 2 of each launch) and seconds
-    # a superstep (the dispatcher's device.launch.wait over them)
-    launches = steps = 0
+    # a superstep (the dispatcher's device.launch.wait over them); and
+    # the share of the supersteps in which some lane read history past
+    # the ring (the far_supersteps labels, meta row 3)
+    launches = steps = far_steps = 0
     wait_s = 0.0
     for s in spans:
         labels = s.get("labels") or {}
@@ -673,11 +675,13 @@ def analyze(spans, run, runs, dropped: int = 0) -> str:
         elif s["name"] == "device.launch.d2h" and "supersteps" in labels:
             launches += 1
             steps += int(labels["supersteps"])
+            far_steps += int(labels.get("far_supersteps", 0))
     if steps:
         out.append(
             f"inflate_supersteps: {steps / launches:,.0f} a launch over "
             f"{launches} launches, {wait_s / steps * 1e6:.2f} us a "
-            "superstep (device.launch.wait)")
+            f"superstep (device.launch.wait), {far_steps / steps * 100:.1f}% "
+            "of them read history past the ring")
         out.append("")
 
     top = order[0]

@@ -573,6 +573,230 @@ class TestSchedulePin:
         assert meta[2, 0] <= fused_supersteps(tokens)
 
 
+# ---- the copy phase's history reads: aligned 8-word tiles ----------
+
+
+def _tile_buffer(rows):
+    """(rows, 128) u32, every word its own value, the high bit used."""
+    return (np.arange(rows * 128, dtype=np.uint32).reshape(rows, 128)
+            * np.uint32(2654435761))
+
+
+def _want_tiles(buf, tiles):
+    want = np.zeros((8, 128), buf.dtype)
+    for lane, t in enumerate(tiles):
+        if t >= 0:
+            want[:, lane] = buf[8 * t: 8 * t + 8, lane]
+    return want
+
+
+def _tile_indices(n_tiles, seed):
+    """A tile a lane: random ones, -1 (nothing), the first, the last."""
+    tiles = np.random.default_rng(seed).integers(0, n_tiles, 128)
+    tiles[[3, 77]] = -1
+    tiles[5], tiles[6] = 0, n_tiles - 1
+    return tiles.astype(np.int32)
+
+
+class TestTileGather:
+    @pytest.mark.parametrize("rows", [8, 1024])
+    def test_tile_gather_equals_numpy_indexing(self, rows):
+        import jax.numpy as jnp
+
+        from disq_tpu.ops.inflate_simd import _gather_tile
+
+        buf = _tile_buffer(rows)
+        tiles = _tile_indices(rows // 8, rows)
+        got = _gather_tile(jnp.asarray(buf), jnp.asarray(tiles[None]))
+        assert got.dtype == jnp.uint32 and got.shape == (8, 128)
+        assert (np.asarray(got) == _want_tiles(buf, tiles)).all()
+        signed = buf.view(np.int32)
+        got = _gather_tile(jnp.asarray(signed), jnp.asarray(tiles[None]))
+        assert (np.asarray(got) == _want_tiles(signed, tiles)).all()
+
+    def test_the_rings_wrap(self):
+        # a lane whose first tile is the ring's last reads tile 0 next
+        import jax.numpy as jnp
+
+        from disq_tpu.ops.inflate_simd import RING_W, _gather_tile
+
+        ring = _tile_buffer(RING_W)
+        last = RING_W // 8 - 1
+        t0 = np.full(128, last, np.int32)
+        t0[::2] = np.arange(64) * 2
+        t1 = (t0 + 1) & last
+        assert t1[1] == 0 and t1[0] == 1
+        for tiles in (t0, t1):
+            got = _gather_tile(jnp.asarray(ring), jnp.asarray(tiles[None]))
+            assert (np.asarray(got) == _want_tiles(ring, tiles)).all()
+
+    @pytest.mark.parametrize("case", ["spread", "one-slab", "none-live",
+                                      "pair-across-a-slab-edge"])
+    def test_windowed_tile_gather_over_slabs(self, case):
+        import jax.numpy as jnp
+
+        from disq_tpu.ops.inflate_simd import _gather_tiles_ref_win
+
+        rows, slab = 4096, 1024           # four slabs of 128 tiles
+        buf = _tile_buffer(rows)
+        if case == "spread":
+            t0 = _tile_indices(rows // 8, 4)
+        elif case == "one-slab":
+            t0 = 128 + _tile_indices(128, 5)
+            t0[[3, 77]] = -1
+        elif case == "none-live":
+            t0 = np.full(128, -1, np.int32)
+        else:
+            t0 = np.full(128, 255, np.int32)   # its second tile: slab 2
+            t0[9] = -1
+        # the copy phase's pair: a tile and the next, clamped at the last
+        t1 = np.where(t0 < 0, -1, np.minimum(t0 + 1, rows // 8 - 1))
+        got = _gather_tiles_ref_win(
+            jnp.asarray(buf),
+            (jnp.asarray(t0[None]), jnp.asarray(t1[None].astype(np.int32))),
+            slab=slab)
+        assert len(got) == 2
+        for g, tiles in zip(got, (t0, t1)):
+            assert g.dtype == jnp.uint32
+            assert (np.asarray(g) == _want_tiles(buf, tiles)).all()
+        # one vector alone, and a buffer no larger than a slab
+        (alone,) = _gather_tiles_ref_win(
+            jnp.asarray(buf), (jnp.asarray(t0[None]),), slab=slab)
+        assert (np.asarray(alone) == _want_tiles(buf, t0)).all()
+        (small,) = _gather_tiles_ref_win(
+            jnp.asarray(buf[:slab]), (jnp.asarray(t0[None] % 128),),
+            slab=slab)
+        assert (np.asarray(small)
+                == _want_tiles(buf[:slab], t0 % 128)).all()
+
+
+_TILE_HEAD = 32800          # bytes before the hand-built block: 1,025 tiles
+_TILE_DISTS = (16, 18, 1001, 4087, 4088, 4089, 16585, 32768)
+# a 258-byte match at output word 8,200 + lead / 4: its sixteen 16-byte
+# chunks read five words from word-in-tile offset k, then k + 4, ...
+_TILE_CASES = (
+    [(d, lead) for d in _TILE_DISTS for lead in range(0, 32, 4)]
+    # d < 4: four fetched bytes replicated modularly, at every offset
+    + [(d, lead) for d in (1, 2, 3) for lead in range(4)]
+)
+
+
+def _tile_head():
+    """32,800 bytes, no two 16-byte stretches alike (a copy from the
+    wrong word shows), that zlib still turns into long matches."""
+    rng = np.random.default_rng(29)
+    cell = rng.integers(0, 256, (_TILE_HEAD // 64, 64), dtype=np.uint8)
+    return np.concatenate([cell, cell], axis=1).tobytes()[:_TILE_HEAD]
+
+
+def tile_stream(d, lead):
+    """The head, a full flush, then a fixed block: ``lead`` literals, a
+    258-byte match from ``d`` back, a literal."""
+    c = zlib.compressobj(9, zlib.DEFLATED, -15)
+    head = c.compress(_tile_head()) + c.flush(zlib.Z_FULL_FLUSH)
+    b = Bits()
+    fixed_block(b, list(range(97, 97 + lead)) + [(258, d), ord("z")])
+    return head + b.bytes()
+
+
+@pytest.fixture(scope="module")
+def tile_launch():
+    payloads = [tile_stream(d, lead) for d, lead in _TILE_CASES]
+    from disq_tpu.ops.inflate_simd import buckets_for
+
+    outs, meta = raw_launch(
+        payloads, *buckets_for(payloads, _TILE_HEAD + 32 + 258 + 1))
+    return payloads, outs, meta
+
+
+class TestTileReads:
+    def test_the_cases_start_at_every_word_of_a_tile(self):
+        for d in _TILE_DISTS:
+            ks = {((_TILE_HEAD + lead - d) >> 2) & 7
+                  for dd, lead in _TILE_CASES if dd == d}
+            assert ks == set(range(8)), d
+        assert max(_TILE_DISTS[:5]) == 4088 < min(_TILE_DISTS[5:])
+
+    @pytest.mark.parametrize("lane", range(len(_TILE_CASES)),
+                             ids=[f"d{d}-lead{a}" for d, a in _TILE_CASES])
+    def test_match_equals_zlib(self, tile_launch, lane):
+        payloads, outs, meta = tile_launch
+        want = zlib.decompress(payloads[lane], -15)
+        d, lead = _TILE_CASES[lane]
+        at = _TILE_HEAD + lead
+        assert len(want) == at + 259
+        if d >= 258:
+            assert want[at: at + 258] == want[at - d: at - d + 258]
+        assert meta[1, lane] == 0
+        assert meta[0, lane] == len(want)
+        assert outs[lane] == want
+
+    def test_the_launch_read_past_the_ring(self, tile_launch):
+        meta = tile_launch[2]
+        assert 0 < meta[3, 0] <= meta[2, 0]
+        assert (meta[3] == meta[3, 0]).all()
+
+
+def inflate_by(route, payloads, usizes):
+    """One launch's decoded lanes, straight through the wrapper
+    (``direct``) or through the decode service (``service``)."""
+    if route == "direct":
+        return inflate_payloads_simd(payloads, usizes=usizes,
+                                     interpret=True)
+    from disq_tpu.runtime.device_service import DeviceDecodeService
+
+    svc = DeviceDecodeService(flush_timeout_s=0.05, interpret=True)
+    try:
+        blob, offs = svc.submit_inflate(payloads, usizes).result(300)
+    finally:
+        svc.close()
+    return [blob[offs[i]: offs[i + 1]].tobytes()
+            for i in range(len(payloads))]
+
+
+def _far_tokens(dist):
+    """Literals, then one match from ``dist`` back: past the ring for
+    ``dist`` > 4,088."""
+    lits = [65 + (i * 7 + i // 26) % 26 for i in range(4200)]
+    return lits + [(40, dist), ord("z")]
+
+
+class TestFarSuperstepCount:
+    @pytest.mark.parametrize("dist,far", [(4088, False), (4089, True)],
+                             ids=["all-near", "far"])
+    def test_meta_row_3_counts_the_far_supersteps(self, dist, far):
+        tokens = _far_tokens(dist)
+        payload = _fixed(tokens)
+        outs, meta = raw_launch([payload], cw=2048, ow=2048)
+        assert meta[1, 0] == 0
+        assert outs[0] == zlib.decompress(payload, -15)
+        # the schedule is what it was: the tile reads cost no step
+        assert meta[2, 0] <= fused_supersteps(tokens)
+        # 40 bytes from word-aligned output at d >= 16: 16 + 16 + 8
+        assert meta[3, 0] == (3 if far else 0)
+
+    @pytest.mark.parametrize("route", ["direct", "service"])
+    def test_far_supersteps_booked_once_a_launch(self, route):
+        from disq_tpu.runtime.tracing import (
+            REGISTRY, spans, telemetry_snapshot)
+
+        payloads = [_fixed(_far_tokens(4089)), _fixed(_far_tokens(64))]
+        raws = [zlib.decompress(p, -15) for p in payloads]
+        usizes = [len(r) for r in raws]
+        far = REGISTRY.counter("device.inflate.far_supersteps")
+        steps = REGISTRY.counter("device.inflate.supersteps")
+        base = far.total(), steps.total()
+        got = inflate_by(route, payloads, usizes)
+        assert got == raws
+        assert far.total() - base[0] == 3
+        booked = [s for s in spans() if s["name"] == "device.launch.d2h"
+                  and s["labels"].get("kind") == "inflate"]
+        assert booked[-1]["labels"]["far_supersteps"] == 3
+        assert booked[-1]["labels"]["supersteps"] == steps.total() - base[1]
+        assert ("device.inflate.far_supersteps"
+                in telemetry_snapshot()["counters"])
+
+
 class TestSuperstepCounter:
     @pytest.mark.parametrize("route", ["direct", "service"])
     def test_supersteps_booked_once_a_launch(self, route):
@@ -586,19 +810,7 @@ class TestSuperstepCounter:
         steps = REGISTRY.counter("device.inflate.supersteps")
         launches = REGISTRY.counter("device.kernel_launches")
         base = steps.total(), launches.value(kernel="inflate_simd")
-        if route == "direct":
-            got = inflate_payloads_simd(payloads, usizes=usizes,
-                                        interpret=True)
-        else:
-            from disq_tpu.runtime.device_service import DeviceDecodeService
-
-            svc = DeviceDecodeService(flush_timeout_s=0.05, interpret=True)
-            try:
-                blob, offs = svc.submit_inflate(payloads, usizes).result(300)
-            finally:
-                svc.close()
-            got = [blob[offs[i]: offs[i + 1]].tobytes()
-                   for i in range(len(raws))]
+        got = inflate_by(route, payloads, usizes)
         assert got == raws
         assert launches.value(kernel="inflate_simd") - base[1] == 1
         assert steps.total() - base[0] == meta[2, 0] > 0

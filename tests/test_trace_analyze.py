@@ -160,6 +160,31 @@ class TestVerdict:
         assert "inflate_supersteps" not in trace_report.analyze(
             SPANS, "r1", ["r1"])
 
+    @pytest.mark.parametrize("far,want", [
+        ((17100, 16150), "95.0% of them read history past the ring"),
+        ((0, 0), "0.0% of them read history past the ring"),
+        (None, "0.0% of them read history past the ring"),
+    ], ids=["far", "all-near", "label-absent"])
+    def test_inflate_far_share_beside_the_two_factors(self, far, want):
+        """The share of supersteps that paid the far sweeps, from the
+        d2h spans' ``far_supersteps`` label (meta row 3); a log from
+        before the label reads 0."""
+        spans = []
+        for i, steps in enumerate((18000, 17000)):
+            labels = {"kind": "inflate", "launch": i, "supersteps": steps}
+            if far is not None:
+                labels["far_supersteps"] = far[i]
+            spans += [
+                _span("device.launch.wait", 2.0 * i, 0.2,
+                      kind="inflate", launch=i),
+                _span("device.launch.d2h", 2.0 * i + 0.2, 0.01, **labels),
+            ]
+        line = next(ln for ln in trace_report.analyze(
+            spans, "r1", ["r1"]).splitlines()
+            if ln.startswith("inflate_supersteps"))
+        assert "17,500 a launch over 2 launches" in line
+        assert line.endswith(want)
+
     def test_no_spans(self):
         assert "no spans" in trace_report.analyze([], None, [])
 
