@@ -75,6 +75,5 @@ from disq_tpu.runtime import (  # noqa: F401
     stop_span_log,
     synced_timer,
     telemetry_snapshot,
-    telemetry_summary,
     trace_phase,
 )

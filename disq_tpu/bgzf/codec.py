@@ -1,10 +1,10 @@
-"""BGZF inflate/deflate — host codec path.
+"""BGZF inflate/deflate: block framing and the codec entry points.
 
 Replaces htsjdk's ``BlockCompressedInputStream`` / ``OutputStream``
-(SURVEY.md §2.8). The per-block codec here is host zlib; the native C++
-threaded codec (``disq_tpu.native``) plugs in behind the same functions
-when built, and a Pallas inflate kernel is the planned device path — all
-three share this module's block framing.
+(SURVEY.md §2.8). A block has one host decoder (the native C++
+threaded codec of ``disq_tpu.native`` when built, else host zlib) and
+one device kernel (``ops/inflate_simd``); both share this module's
+block framing.
 
 **Canonical deflate pin** (the byte-identity contract: a sorted BAM and
 its BAI written twice, by any path that uses host deflate, are the same
@@ -13,18 +13,16 @@ in this framework uses exactly these parameters, so repeated writes of the
 same records are byte-identical.
 
 **Host-vs-device inflate policy.** The default codec path is the
-threaded C++ host inflater (~450 MB/s on a many-core host); the
-128-lane SIMD Pallas kernel (``DISQ_TPU_DEVICE_INFLATE=1``, judge-
-measurable via ``disq_tpu.ops.tpu_ci``) runs at ~43 MB/s/chip. On a
-one-chip dev box the host path wins and stays the default. The device
-path exists because the ratio that matters at fleet scale is per-CHIP:
-TPU pods scale chips, not host cores — a v5e-8 host typically exposes
-~1 vCPU per chip of this box's class, so the per-chip host budget is
-~tens of MB/s while each chip brings its own 43+ MB/s *and* leaves the
-host free for IO. The device path also keeps decompressed shards
-HBM-resident for the downstream parse/sort kernels instead of
-round-tripping through host memory. Flip the default only when
-device-side decode is measured faster end-to-end on the target
+threaded C++ host inflater; the 128-lane SIMD Pallas kernel is the
+opt-in (``DISQ_TPU_DEVICE_INFLATE=1``). Its rate on the chip is the
+``inflate_simd_*`` rows that ``disq_tpu.ops.tpu_ci`` writes into
+``TPU_KERNELS.json``. On a one-chip dev box the host path wins and
+stays the default. The device path exists because the ratio that
+matters at fleet scale is per-CHIP: TPU pods scale chips, not host
+cores, and the device path leaves the host free for IO. It also keeps
+decompressed shards HBM-resident for the downstream parse/sort kernels
+instead of round-tripping through host memory. Flip the default only
+when device-side decode is measured faster end-to-end on the target
 topology; until then the flag is the opt-in.
 """
 
@@ -85,15 +83,14 @@ def inflate_blocks(
     independent raw-DEFLATE streams — embarrassingly parallel); falls
     back to per-block host zlib. Set ``DISQ_TPU_DEVICE_INFLATE=1`` to
     route through the 128-lane SIMD Pallas kernel instead
-    (``disq_tpu.ops.inflate_simd`` — the device path; CRC checked on
-    host), or ``=legacy`` for the round-1 scalar kernel
-    (``disq_tpu.ops.inflate``).
+    (``inflate_blocks_device``; CRC checked on host).
 
     ``keep_device`` changes the return to ``(blob, handle)``: on the
-    direct SIMD device path the handle is the still-HBM-resident
+    device path's direct route the handle is the still-HBM-resident
     kernel output (``DeviceBlobHandle``) the fused resident-decode
-    chain parses without re-uploading; every other route returns
-    ``(blob, None)`` and the caller falls back to one upload.
+    chain parses without re-uploading; the service route and the host
+    path return ``(blob, None)`` and the caller falls back to one
+    upload.
     """
     import numpy as np
 
@@ -148,84 +145,58 @@ def _inflate_blocks_timed(data, blocks, base, verify_crc, as_array,
 def inflate_blocks_device(
     data: bytes, blocks: Sequence[BgzfBlock], base: int = 0,
     verify_crc: bool = True, as_array: bool = False,
-    keep_device: bool = False, to_columnar=None,
+    keep_device: bool = False,
 ):
     """Device path of ``inflate_blocks``: the 128-lane SIMD Pallas
-    kernel (``ops/inflate_simd``) with ISIZE
-    validated against the kernel's per-lane output length and CRC on
-    host. ``DISQ_TPU_DEVICE_INFLATE=legacy`` selects the round-1
-    one-block-per-grid-program kernel (``ops/inflate``) for A/B runs.
+    kernel (``ops/inflate_simd``) with ISIZE validated against the
+    kernel's per-lane output length and CRC on host.  It is reached by
+    one of two routes:
 
-    With ``DISQ_TPU_DEVICE_SERVICE=1`` the block batch is submitted to
-    the cross-shard decode service (``runtime/device_service.py``):
-    blocks from concurrently-decoding shards coalesce into full
-    128-lane launches, and the decoded bytes land in one contiguous
-    blob with no per-block ``bytes`` round-trips.  Payloads are sliced
-    as ``memoryview``\\ s on the SIMD paths (nothing here copies the
+    - With ``DISQ_TPU_DEVICE_SERVICE=1`` the block batch is submitted
+      to the cross-shard decode service (``runtime/device_service.py``):
+      blocks from concurrently-decoding shards coalesce into full
+      128-lane launches, and the decoded bytes land in one contiguous
+      blob with no per-block ``bytes`` round-trips.
+    - Otherwise the direct launch loop
+      (``inflate_simd.inflate_payloads_simd``) runs this call's blocks
+      alone.
+
+    Payloads are sliced as ``memoryview``\\ s (nothing here copies the
     compressed bytes); batch CRC verification runs threaded, off the
     kernel's critical path (the service keeps decoding other shards'
     chunks while this thread verifies).  ``as_array`` returns the blob
     as a uint8 array instead of bytes.
 
     ``keep_device`` returns ``(blob, DeviceBlobHandle-or-None)``: on
-    the direct SIMD path the kernel's output chunks stay resident in
-    HBM for the fused parse chain (service/legacy routes hand back
-    None — their outputs live in the owner submissions' host blobs).
-
-    ``to_columnar`` is the fused inflate → parse → columnar route
-    (ROADMAP item 1): a ``{"n_ref": …, "lo_u": …, "end_u": …}`` spec
-    makes this call return a device-backed
-    ``runtime/columnar.ColumnarBatch`` parsed in the same launch chain
-    — record offsets are scanned on the host copy (which CRC
-    verification requires anyway), but the decoded payload bytes are
-    parsed where the inflate kernel left them and the fixed columns
-    stay in HBM until fetched."""
-    import os
-
+    the direct route the kernel's output chunks stay resident in HBM
+    for the fused parse chain (the service route hands back None: its
+    outputs live in the owner submissions' host blobs)."""
     import numpy as np
 
     if not blocks:
-        if to_columnar is not None:
-            from disq_tpu.runtime.columnar import ColumnarBatch
-            from disq_tpu.bam.columnar import ReadBatch
-
-            return ColumnarBatch.from_host(ReadBatch.empty())
         empty = np.empty(0, dtype=np.uint8) if as_array else b""
         return (empty, None) if keep_device else empty
-    legacy = os.environ.get(
-        "DISQ_TPU_DEVICE_INFLATE", "").lower() == "legacy"
     mv = memoryview(data)
     payloads = []
     for b in blocks:
         off = b.pos - base
         xlen = struct.unpack_from("<H", data, off + 10)[0]
-        p = mv[off + 12 + xlen: off + b.csize - BGZF_FOOTER_SIZE]
-        payloads.append(bytes(p) if legacy else p)
+        payloads.append(mv[off + 12 + xlen: off + b.csize - BGZF_FOOTER_SIZE])
     usizes = [b.usize for b in blocks]
-    want_handle = keep_device or to_columnar is not None
+    from disq_tpu.runtime import device_service
+
     handle = None
-    if legacy:
-        from disq_tpu.ops.inflate import inflate_payloads
-        from disq_tpu.ops.inflate_simd import assemble_blob
-
-        blob, offsets = assemble_blob(
-            inflate_payloads(payloads, usizes=usizes))
+    if device_service.enabled():
+        blob, offsets = device_service.get_service().submit_inflate(
+            payloads, usizes).result()
     else:
-        from disq_tpu.runtime import device_service
+        from disq_tpu.ops.inflate_simd import inflate_payloads_simd
 
-        if device_service.enabled():
-            blob, offsets = device_service.get_service().submit_inflate(
-                payloads, usizes).result()
-        else:
-            from disq_tpu.ops.inflate_simd import inflate_payloads_simd
-
-            if want_handle:
-                blob, offsets, handle = inflate_payloads_simd(
-                    payloads, usizes=usizes, as_array=True,
-                    keep_device=True)
-            else:
-                blob, offsets = inflate_payloads_simd(
-                    payloads, usizes=usizes, as_array=True)
+        blob, offsets, *kept = inflate_payloads_simd(
+            payloads, usizes=usizes, as_array=True,
+            keep_device=keep_device)
+        if kept:
+            handle = kept[0]
     try:
         if verify_crc:
             _verify_block_crcs(data, blocks, base, blob, offsets)
@@ -233,34 +204,8 @@ def inflate_blocks_device(
         if handle is not None:
             handle.release()
         raise
-    if to_columnar is not None:
-        return _blob_to_columnar(blob, handle, to_columnar)
-    if keep_device:
-        return (blob if as_array else blob.tobytes()), handle
-    return blob if as_array else blob.tobytes()
-
-
-def _blob_to_columnar(blob, handle, spec):
-    """The parse half of the ``to_columnar`` route: scan the record
-    chain on the host copy, then parse the device-resident blob into a
-    ``ColumnarBatch`` (re-uploading only when no kernel output stayed
-    on device)."""
-    from disq_tpu.bam.codec import scan_record_offsets
-    from disq_tpu.runtime.columnar import ColumnarBatch
-
-    lo_u = int(spec.get("lo_u", 0))
-    end_u = spec.get("end_u")
-    rec = blob[lo_u: len(blob) if end_u is None else int(end_u)]
-    try:
-        rec_offsets = scan_record_offsets(rec)
-    except BaseException:
-        if handle is not None:
-            handle.release()
-        raise
-    words = handle.assemble() if handle is not None else None
-    return ColumnarBatch.from_blob(
-        rec, rec_offsets, n_ref=spec.get("n_ref"),
-        device_words=words, origin=lo_u)
+    out = blob if as_array else blob.tobytes()
+    return (out, handle) if keep_device else out
 
 
 def _verify_block_crcs(data, blocks, base, blob, offsets) -> None:

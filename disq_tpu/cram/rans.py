@@ -248,13 +248,6 @@ def rans_decode(data: bytes) -> bytes:
     if order == 0:
         from disq_tpu.runtime.debug import env_flag
 
-        import os
-
-        if os.environ.get("DISQ_TPU_DEVICE_RANS", "").lower() == "legacy":
-            # round-1 scalar kernel (one stream per grid program)
-            from disq_tpu.ops.rans import rans0_decode_device
-
-            return rans0_decode_device([data])[0]
         if env_flag("DISQ_TPU_DEVICE_RANS"):
             from disq_tpu.runtime import device_service
 

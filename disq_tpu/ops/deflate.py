@@ -1,6 +1,6 @@
 """Device DEFLATE encode — dynamic-Huffman literal coding on TPU.
 
-The write-side counterpart of ``disq_tpu.ops.inflate`` (SURVEY.md §7
+The write-side counterpart of ``disq_tpu.ops.inflate_simd`` (SURVEY.md §7
 step 5: "per-shard BGZF deflate (kernel or host)"). The reference's
 write hot loop is htsjdk ``BlockCompressedOutputStream`` + zlib
 ``Deflater`` (SURVEY.md §2.8); the canonical byte-identity pin in this

@@ -2,11 +2,10 @@
 
 The north-star device codec (SURVEY.md §2.8 row 1, §7 step 2; reference
 behavior: htsjdk ``BlockCompressedInputStream`` + zlib ``Inflater``).
-The round-1 kernel (``ops/inflate.py``) decodes one block per grid
-program with a *scalar* state machine, one data-dependent SMEM step
-after another, and is latency-bound. This kernel is **lane-parallel
-SIMD** instead: 128 independent DEFLATE streams, one per vector lane,
-every piece of decoder state a ``(1, 128)`` vector.
+DEFLATE entropy decode is bit-serial inside a stream, so the kernel is
+**lane-parallel SIMD** across streams: 128 independent DEFLATE streams,
+one per vector lane, every piece of decoder state a ``(1, 128)``
+vector.
 
 Per superstep (one ``lax.while_loop`` iteration), every lane advances
 its own predicated state machine by pure vector selects; rare events
@@ -63,10 +62,10 @@ ring. Correct and Mosaic-friendly, but the sweeps scale with buffer
 size; the windowed slab gates above are what bounds them, and a gate
 is not free (PERF.md §5).
 
-Error codes in meta row 1 (shared with ``ops/inflate.py``): 0 ok ·
-1 bad btype · 2 stored-LEN mismatch · 3 bad Huffman code · 4 invalid
-distance · 5 output overflow · 6 ran past the compressed payload ·
-7 code-length repeat overflow · 8 ISIZE mismatch (host-side).
+Error codes in meta row 1: 0 ok · 1 bad btype · 2 stored-LEN mismatch ·
+3 bad Huffman code · 4 invalid distance · 5 output overflow · 6 ran past
+the compressed payload · 7 code-length repeat overflow · 8 ISIZE
+mismatch (host-side).
 """
 
 from __future__ import annotations
@@ -84,15 +83,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from disq_tpu.ops.inflate import (
-    _CLORDER,
-    _DBASE,
-    _DEXT,
-    _FIXED_LENS,
-    _LBASE,
-    _LEXT,
-    _NLIT,
-)
 from disq_tpu.runtime.tracing import (
     count_transfer as _count_transfer,
     counter as _counter,
@@ -122,6 +112,32 @@ _I32 = jnp.int32
 _HEADER, _SLEN, _SNLEN, _SCOPY = 0, 1, 2, 3
 _TBHDR, _TBCLLEN, _TBCODELEN = 4, 5, 6
 _DECODE, _COPY, _DONE, _ERR = 7, 8, 9, 10
+
+_NLIT = 288  # literal/length alphabet size
+# Length codes 257..285 (RFC 1951 §3.2.5).
+_LBASE = np.array(
+    [3, 4, 5, 6, 7, 8, 9, 10, 11, 13, 15, 17, 19, 23, 27, 31, 35, 43, 51,
+     59, 67, 83, 99, 115, 131, 163, 195, 227, 258], dtype=np.int32)
+_LEXT = np.array(
+    [0, 0, 0, 0, 0, 0, 0, 0, 1, 1, 1, 1, 2, 2, 2, 2, 3, 3, 3, 3, 4, 4, 4,
+     4, 5, 5, 5, 5, 0], dtype=np.int32)
+# Distance codes 0..29 (padded to the 32-symbol alphabet).
+_DBASE = np.array(
+    [1, 2, 3, 4, 5, 7, 9, 13, 17, 25, 33, 49, 65, 97, 129, 193, 257, 385,
+     513, 769, 1025, 1537, 2049, 3073, 4097, 6145, 8193, 12289, 16385,
+     24577, 0, 0], dtype=np.int32)
+_DEXT = np.array(
+    [0, 0, 0, 0, 1, 1, 2, 2, 3, 3, 4, 4, 5, 5, 6, 6, 7, 7, 8, 8, 9, 9, 10,
+     10, 11, 11, 12, 12, 13, 13, 0, 0], dtype=np.int32)
+# Order in which code-length code lengths are stored (RFC 1951 §3.2.7).
+_CLORDER = np.array(
+    [16, 17, 18, 0, 8, 7, 9, 6, 10, 5, 11, 4, 12, 3, 13, 2, 14, 1, 15],
+    dtype=np.int32)
+# Fixed-Huffman code lengths (RFC 1951 §3.2.6), lit then dist.
+_FIXED_LENS = np.concatenate(
+    [np.full(144, 8), np.full(112, 9), np.full(24, 7), np.full(8, 8),
+     np.full(32, 5)]
+).astype(np.int32)
 
 
 def _canonical_np(lens: np.ndarray, maxbits: int):

@@ -3,10 +3,9 @@
 Applies the lane-parallel architecture of ``ops/inflate_simd.py``
 (one stream per vector lane) to CRAM's rANS order-0 external-block codec
 (htsjdk ``RANSExternalCompressor`` / htslib ``rANS_static``; CRAM 3.0
-§13 — SURVEY.md §2.8 CRAM row). The round-1 kernel (``ops/rans.py``)
-decodes one stream per grid program with a scalar state machine and is
-latency-bound; here 128 independent streams decode at once, one per vector lane, with every piece of
-decoder state a ``(1, 128)`` vector.
+§13 — SURVEY.md §2.8 CRAM row): 128 independent streams decode at
+once, one per vector lane, with every piece of decoder state a
+``(1, 128)`` vector.
 
 rANS maps onto lanes even better than DEFLATE because the decode
 schedule is *position-oblivious*: the 4 interleaved states of stream
@@ -242,7 +241,7 @@ def _compiled(cw: int, ow: int, interpret: bool,
 
 def _parse_stream(k: int, s: bytes):
     """Host-side header/table parse (O(alphabet) per stream — the
-    per-byte loop is the kernel's). Mirrors ops/rans.py's guards."""
+    per-byte loop is the kernel's)."""
     import struct
 
     from disq_tpu.cram.rans import _read_freq_table0

@@ -118,54 +118,6 @@ def run_inflate_simd(results: list) -> None:
     assert ok_k, "SIMD inflate kernel-only launch output != zlib"
 
 
-def run_inflate_legacy(results: list) -> None:
-    from disq_tpu.ops.inflate import inflate_payloads
-
-    rng = np.random.default_rng(1)
-    raws = [_bam_like(8000, rng) for _ in range(8)]
-    payloads = [_deflate(r) for r in raws]
-    got = inflate_payloads(payloads, usizes=[len(r) for r in raws],
-                           interpret=False)
-    ok = all(g == r for g, r in zip(got, raws))
-    best = 1e9
-    for _ in range(2):
-        t0 = time.perf_counter()
-        inflate_payloads(payloads, usizes=[len(r) for r in raws],
-                         interpret=False)
-        best = min(best, time.perf_counter() - t0)
-    total = sum(len(r) for r in raws)
-    results.append({
-        "kernel": "inflate_legacy_scalar",
-        "shape": "8 blocks x 8000 B",
-        "mb_per_sec": round(total / best / 1e6, 2),
-        "correct": ok,
-    })
-    assert ok, "legacy inflate output != zlib"
-
-
-def run_rans(results: list) -> None:
-    from disq_tpu.cram.rans import rans_decode, rans_encode_order0
-    from disq_tpu.ops.rans import rans0_decode_device
-
-    rng = np.random.default_rng(2)
-    raw = np.repeat(rng.integers(30, 45, 4000, dtype=np.uint8), 16).tobytes()
-    enc = rans_encode_order0(raw)
-    got = rans0_decode_device([enc], interpret=False)[0]
-    ok = got == raw and rans_decode(enc) == raw
-    best = 1e9
-    for _ in range(2):
-        t0 = time.perf_counter()
-        rans0_decode_device([enc], interpret=False)
-        best = min(best, time.perf_counter() - t0)
-    results.append({
-        "kernel": "rans_order0_decode",
-        "shape": f"{len(raw)} B",
-        "mb_per_sec": round(len(raw) / best / 1e6, 2),
-        "correct": ok,
-    })
-    assert ok, "device rANS != host"
-
-
 def run_inflate_simd_literal_heavy(results: list) -> None:
     """Pair-literal regime: pure-literal streams (no LZ77 matches) are
     the kernel's worst case — the speculative second-symbol decode
@@ -742,7 +694,6 @@ def main(out_path: str = "TPU_KERNELS.json",
             rows.append(functools.partial(
                 run_inflate_simd_wgs30x, record_bytes=f.read()))
     for fn in (*rows,
-               run_inflate_legacy, run_rans,
                run_rans_simd, run_kernel_fuzz, run_deflate,
                run_device_pipeline_row, run_resident_decode,
                run_decode_service, run_resident_operators,

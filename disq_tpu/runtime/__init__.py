@@ -193,7 +193,6 @@ from disq_tpu.runtime.tracing import (  # noqa: F401
     start_span_log,
     stop_span_log,
     telemetry_snapshot,
-    telemetry_summary,
     trace_phase,
     wrap_span,
 )

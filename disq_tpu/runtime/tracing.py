@@ -8,7 +8,7 @@ layer shared by every subsystem:
   labeled ``Counter`` / ``Gauge`` (min/max/last/mean) / fixed-bucket
   ``Histogram`` handles, thread-safe and resettable.  Exported as
   Prometheus text exposition via ``metrics_text()`` and as a plain dict
-  via ``telemetry_snapshot()`` / ``telemetry_summary()``.
+  via ``telemetry_snapshot()``.
 - **Span timeline**: ``span(name, shard=…)`` context managers emit
   ``{ts, dur, name, labels}`` events with a process-wide ``run_id`` and
   monotonic timestamps into a bounded in-memory ring (default 64k
@@ -366,42 +366,6 @@ class MetricsRegistry:
                     out[m.kind + "s"][name] = snap
         return out
 
-    def summary(self) -> Dict[str, Any]:
-        """Compact one-level summary (what ``bench.py`` embeds):
-        counters as cross-label totals, gauges as last/max, histograms
-        as calls/total/p50/p99.  The lock (re-entrant) is held across
-        the whole walk so concurrent first-observations of a labelset
-        can't mutate a state dict mid-iteration."""
-        out: Dict[str, Any] = {"counters": {}, "gauges": {}, "phases": {}}
-        with self._lock:
-            items = sorted(self._metrics.items())
-            for name, m in items:
-                self._summarize_one(name, m, out)
-        return out
-
-    def _summarize_one(self, name: str, m, out: Dict[str, Any]) -> None:
-        # caller holds self._lock
-        if m.kind == "counter":
-            total = m.total()
-            if total:
-                out["counters"][name] = total
-        elif m.kind == "gauge":
-            snap = m._snapshot()
-            if snap:
-                merged = list(snap.values())
-                out["gauges"][name] = {
-                    "last": merged[-1]["last"],
-                    "max": max(g["max"] for g in merged),
-                }
-        else:
-            if m.count:
-                out["phases"][name] = {
-                    "calls": m.count,
-                    "total_s": round(m.sum, 6),
-                    "p50_s": round(m.percentile(50), 6),
-                    "p99_s": round(m.percentile(99), 6),
-                }
-
     def metrics_text(self) -> str:
         """Prometheus text exposition.  Dotted names become
         ``disq_tpu_``-prefixed underscore names; histograms get the
@@ -487,10 +451,6 @@ def metrics_text() -> str:
 
 def telemetry_snapshot() -> Dict[str, Any]:
     return REGISTRY.snapshot()
-
-
-def telemetry_summary() -> Dict[str, Any]:
-    return REGISTRY.summary()
 
 
 # ---------------------------------------------------------------------------

@@ -70,8 +70,9 @@ def pallas_interpret() -> bool:
 def enable_compile_cache() -> str:
     """Turn on jax's persistent compilation cache and return its
     directory.  Called by the program's entry points (``chip_smoke.py``,
-    ``bench.py``, ``scripts/serve.py``, ``python -m disq_tpu.ops.tpu_ci``)
-    before their first compile — never by library import.
+    ``benchmark/run.py``, ``scripts/serve.py``, ``python -m
+    disq_tpu.ops.tpu_ci``) before their first compile — never by
+    library import.
 
     ``JAX_COMPILATION_CACHE_DIR`` set: jax reads it on its own, so this
     sets nothing.  Unset: the cache lives at ``<checkout>/.jax_cache``,

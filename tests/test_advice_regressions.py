@@ -219,7 +219,7 @@ class TestRansTruncatedStreams:
 
     def test_device_rejects_huge_state(self):
         from disq_tpu.native import rans_encode0_native
-        from disq_tpu.ops.rans import rans0_decode_device
+        from disq_tpu.ops.rans_simd import rans0_decode_simd
         from disq_tpu.cram.rans import _read_freq_table0
 
         raw = bytes(np.random.default_rng(4).integers(0, 8, 1024, dtype=np.uint8))
@@ -231,7 +231,7 @@ class TestRansTruncatedStreams:
             4, "little"
         )
         with pytest.raises(ValueError, match="2\\^31"):
-            rans0_decode_device([bytes(stream)], interpret=True)
+            rans0_decode_simd([bytes(stream)], interpret=True)
 
 
 class TestEncodeContainerSlackRejected:

@@ -240,20 +240,21 @@ class TestDevicePipelineTelemetry:
 
 class TestOpsTelemetry:
     def test_inflate_payloads_books_device_metrics(self):
-        from disq_tpu.ops.inflate import inflate_payloads
+        from disq_tpu.ops.inflate_simd import inflate_payloads_simd
 
         raw = b"device telemetry " * 8
         comp = zlib.compress(raw, 6)[2:-4]  # raw DEFLATE
-        out = inflate_payloads([comp], usizes=[len(raw)],
-                               interpret=True)
+        out = inflate_payloads_simd([comp], usizes=[len(raw)],
+                                    interpret=True)
         assert out == [raw]
         assert REGISTRY.counter("device.kernel_launches").value(
-            kernel="inflate") == 1
+            kernel="inflate_simd") == 1
         assert REGISTRY.counter("device.bytes_to_device").total() > 0
         assert REGISTRY.counter("device.bytes_to_host").total() > 0
-        assert any(s["name"] == "device.kernel"
-                   and s["labels"].get("kernel") == "inflate"
-                   for s in spans())
+        for name in ("device.launch.wait", "device.launch.d2h"):
+            assert any(s["name"] == name
+                       and s["labels"].get("kind") == "inflate"
+                       for s in spans())
 
     def test_parse_host_entry_books_in_jit_passthrough_does_not(self):
         from disq_tpu.ops.parse import parse_fixed_words_pallas
@@ -290,17 +291,19 @@ class TestOpsTelemetry:
 
     def test_rans_books_device_metrics(self):
         from disq_tpu.cram.rans import rans_encode_order0
-        from disq_tpu.ops.rans import rans0_decode_device
+        from disq_tpu.ops.rans_simd import rans0_decode_simd
 
         raw = bytes(range(8)) * 40
         stream = rans_encode_order0(raw)
-        assert rans0_decode_device([stream], interpret=True) == [raw]
+        assert rans0_decode_simd([stream], interpret=True) == [raw]
         assert REGISTRY.counter("device.kernel_launches").value(
-            kernel="rans") == 1
+            kernel="rans_simd") == 1
         assert REGISTRY.counter("device.bytes_to_device").total() > 0
-        assert any(s["name"] == "device.kernel"
-                   and s["labels"].get("kernel") == "rans"
-                   for s in spans())
+        assert REGISTRY.counter("device.bytes_to_host").total() > 0
+        for name in ("device.launch.wait", "device.launch.d2h"):
+            assert any(s["name"] == name
+                       and s["labels"].get("kind") == "rans"
+                       for s in spans())
 
     def test_simd_unpack_flagged_lane_counts_host_fallback(self):
         from disq_tpu.ops import inflate_simd

@@ -15,7 +15,7 @@ import zlib
 import numpy as np
 import pytest
 
-from disq_tpu.ops import inflate as tables
+from disq_tpu.ops import inflate_simd as tables
 from disq_tpu.ops.inflate_simd import inflate_payloads_simd
 
 
@@ -258,6 +258,35 @@ class TestCopyWidthBoundaries:
 _LBASE, _LEXT, _DBASE, _DEXT, _CLORDER, _FIXED_LENS = (
     t.tolist() for t in (tables._LBASE, tables._LEXT, tables._DBASE,
                          tables._DEXT, tables._CLORDER, tables._FIXED_LENS))
+
+
+def test_tables_equal_rfc1951():
+    """The constants every device read decodes by, against RFC 1951
+    written out: §3.2.5 (length codes 257..285, distance codes 0..29,
+    the distance alphabet padded to 32 with zeros), §3.2.7 (the order
+    of the code-length code lengths), §3.2.6 (the fixed code)."""
+    lext = [0] * 8 + [1] * 4 + [2] * 4 + [3] * 4 + [4] * 4 + [5] * 4 + [0]
+    lbase = [3, 4, 5, 6, 7, 8, 9, 10, 11, 13, 15, 17, 19, 23, 27, 31, 35,
+             43, 51, 59, 67, 83, 99, 115, 131, 163, 195, 227, 258]
+    dext = [0, 0, 0, 0] + [e for e in range(1, 14) for _ in range(2)]
+    dbase = [1, 2, 3, 4, 5, 7, 9, 13, 17, 25, 33, 49, 65, 97, 129, 193,
+             257, 385, 513, 769, 1025, 1537, 2049, 3073, 4097, 6145, 8193,
+             12289, 16385, 24577]
+    # the codes tile their ranges: 3..257 (285 alone is 258), 1..32768
+    assert all(lbase[i] + (1 << lext[i]) == lbase[i + 1] for i in range(27))
+    assert lbase[27] + (1 << lext[27]) == 259
+    assert all(dbase[i] + (1 << dext[i]) == dbase[i + 1] for i in range(29))
+    assert dbase[29] + (1 << dext[29]) == 32769
+    assert (_LBASE, _LEXT) == (lbase, lext)
+    assert (_DBASE, _DEXT) == (dbase + [0, 0], dext + [0, 0])
+    assert _CLORDER == [16, 17, 18, 0, 8, 7, 9, 6, 10, 5, 11, 4, 12, 3, 13,
+                        2, 14, 1, 15]
+    assert tables._NLIT == 288
+    assert _FIXED_LENS == ([8] * 144 + [9] * 112 + [7] * 24 + [8] * 8
+                           + [5] * 32)
+    for t in (tables._LBASE, tables._LEXT, tables._DBASE, tables._DEXT,
+              tables._CLORDER, tables._FIXED_LENS):
+        assert t.dtype == np.int32
 
 
 class Bits:

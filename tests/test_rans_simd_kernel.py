@@ -115,28 +115,19 @@ class TestRans0Simd:
             rans0_decode_simd([bytes(enc)], interpret=True)
 
     def test_decode_dispatch_flag(self, monkeypatch):
-        # spy on both kernels so mis-routing can't hide behind the fact
-        # that either decodes correctly
-        import disq_tpu.ops.rans as legacy_mod
+        # spy on the kernel's entry point so mis-routing can't hide
+        # behind the fact that the host decodes correctly too
         import disq_tpu.ops.rans_simd as simd_mod
 
         calls = []
+        real = simd_mod.rans0_decode_simd
 
-        def spy(mod, name):
-            real = getattr(mod, name)
+        def wrapper(streams, interpret=None):
+            calls.append("rans0_decode_simd")
+            return real(streams, interpret=interpret)
 
-            def wrapper(streams, interpret=None):
-                calls.append(name)
-                return real(streams, interpret=interpret)
-
-            monkeypatch.setattr(mod, name, wrapper)
-
-        spy(simd_mod, "rans0_decode_simd")
-        spy(legacy_mod, "rans0_decode_device")
+        monkeypatch.setattr(simd_mod, "rans0_decode_simd", wrapper)
         raw = _markov(2000, 7)
         monkeypatch.setenv("DISQ_TPU_DEVICE_RANS", "1")
         assert rans_decode(rans_encode_order0(raw)) == raw
         assert calls == ["rans0_decode_simd"]
-        monkeypatch.setenv("DISQ_TPU_DEVICE_RANS", "legacy")
-        assert rans_decode(rans_encode_order0(raw)) == raw
-        assert calls == ["rans0_decode_simd", "rans0_decode_device"]

@@ -550,41 +550,6 @@ class TestResidentWithDeviceService:
         assert 0 < ds.count() < 72
 
 
-class TestToColumnarRoute:
-    def test_inflate_blocks_device_to_columnar(self, tmp_path):
-        """The codec-level fused route (bench config 10's path):
-        device inflate → in-place parse → ColumnarBatch, identical to
-        inflating + host-parsing the same blocks."""
-        from disq_tpu.bam.codec import decode_records, scan_record_offsets
-        from disq_tpu.bam.source import read_header
-        from disq_tpu.bgzf.codec import inflate_blocks_device
-        from disq_tpu.bgzf.guesser import find_block_table
-        from disq_tpu.fsw import PosixFileSystemWrapper
-        from disq_tpu.runtime.columnar import ColumnarBatch
-
-        path = _bam_file(tmp_path, n=40, blocksize=256)
-        fs = PosixFileSystemWrapper()
-        header, first_vo = read_header(fs, path)
-        blocks = [b for b in find_block_table(fs, path) if b.usize > 0]
-        data = open(path, "rb").read()
-        co, uo = first_vo >> 16, first_vo & 0xFFFF
-        lo_u = sum(b.usize for b in blocks if b.pos < co) + uo
-        cb = inflate_blocks_device(
-            data, blocks,
-            to_columnar={"n_ref": header.n_ref, "lo_u": lo_u})
-        assert isinstance(cb, ColumnarBatch) and cb.device_backed
-        # host-route baseline (block-identical bytes, no second device
-        # inflate on the clock)
-        from disq_tpu.bgzf.codec import inflate_blocks
-        blob = inflate_blocks(data, blocks, as_array=True)
-        rec = blob[lo_u:]
-        host = decode_records(rec, scan_record_offsets(rec),
-                              n_ref=header.n_ref)
-        assert cb.count == host.count == 40
-        _assert_identical(cb, host)
-        cb.release()
-
-
 class TestDeviceColumnsResident:
     def test_device_columns_zero_upload(self, tmp_path):
         import jax
