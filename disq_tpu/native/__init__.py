@@ -124,6 +124,11 @@ def _bind(lib: ctypes.CDLL) -> None:
         u8p, i64p, ctypes.c_int64, i64p, u8p, i64p, u32p, i64p, u8p,
         u8p, i64p, u8p,
     ]
+    lib.disq_bam_reference_lengths.restype = ctypes.c_int64
+    lib.disq_bam_reference_lengths.argtypes = [
+        u8p, ctypes.c_int64, i64p, ctypes.c_int64, ctypes.c_int64, i32p,
+        i64p,
+    ]
     lib.disq_rans_encode0.restype = ctypes.c_int64
     lib.disq_rans_encode0.argtypes = [u8p, ctypes.c_int64, u8p, ctypes.c_int64]
     lib.disq_rans_encode1.restype = ctypes.c_int64
@@ -182,6 +187,12 @@ def _load() -> ctypes.CDLL:
             raise ImportError(f"cannot load native library: {e}") from e
         _lib = lib
         return lib
+
+
+def loaded() -> bool:
+    """Whether the library is loaded: what a span that covers a routine
+    with a numpy fallback labels its route from, after the call."""
+    return _lib is not None
 
 
 def build_variant() -> str:
@@ -339,6 +350,27 @@ def decode_records_native(buf, offsets: np.ndarray):
         seq_offsets=seq_off, seqs=seqs, quals=quals,
         tag_offsets=tag_off, tags=tags,
     )
+
+
+def reference_lengths_native(buf, offsets: np.ndarray, base: int = 0):
+    """``(pos i32, reference length i64)`` of the records of ``buf``
+    from its fixed fields and CIGAR op words alone, one sequential C
+    pass.  ``offsets`` are the (N+1,) record offsets of these records
+    in a larger blob of which ``buf`` is the part starting at byte
+    ``base``."""
+    lib = _load()
+    arr = _as_u8(buf)
+    offsets = np.ascontiguousarray(offsets, dtype=np.int64)
+    n = max(0, len(offsets) - 1)
+    pos = np.empty(n, np.int32)
+    reflen = np.empty(n, np.int64)
+    rc = lib.disq_bam_reference_lengths(
+        _ptr(arr, ctypes.c_uint8), len(arr),
+        _ptr(offsets, ctypes.c_int64), base, n,
+        _ptr(pos, ctypes.c_int32), _ptr(reflen, ctypes.c_int64))
+    if rc != 0:
+        raise ValueError(f"record {-(rc + 1)}: malformed sections")
+    return pos, reflen
 
 
 def encode_records_native(batch) -> tuple[bytes, np.ndarray]:

@@ -87,10 +87,14 @@ def window_depth(
 
     ``batch`` may be a host ``ReadBatch`` or a resident
     ``runtime/columnar.ColumnarBatch`` — the window math consumes the
-    lazily-fetched refid/pos/flag columns plus the cigar-derived
-    alignment ends (host-side by nature), so a resident dataset pays
-    d2h only for the three columns this op actually reads, never a
-    record re-upload.
+    lazily-fetched refid/pos/flag columns plus the alignment ends,
+    which a resident batch derives from the CIGAR bytes of its record
+    blob and keeps (``ColumnarBatch.alignment_ends``): a resident
+    dataset pays d2h only for the three columns this op actually
+    reads, and neither a record re-upload nor a host record parse.
+    The host side is the span ``ops.depth.prepare`` (its ``ends``
+    label says what the batch answered the ends from), the scatter a
+    ``device.kernel{kernel=depth}`` span on one device as on a mesh.
 
     All references share ONE concatenated window space (per-ref window
     offsets), so the whole call is a single scatter+cumsum dispatch —
@@ -107,36 +111,40 @@ def window_depth(
             "lengths"
         )
 
-    sel = (batch.refid >= 0) & (batch.refid < len(ref_lengths)) & (
-        (batch.flag & 0x4) == 0
-    )
-    if not sel.any():
-        return {
-            r: np.zeros(n_win_per_ref[r], dtype=np.int32)
-            for r in range(len(ref_lengths))
-        }
-    rid = batch.refid[sel].astype(np.int64)
-    pos = batch.pos[sel].astype(np.int64)
-    ends = batch.alignment_ends()[sel].astype(np.int64)
-    per_ref_nw = np.asarray(n_win_per_ref, dtype=np.int64)
-    w_lo = ref_win_off[rid] + np.clip(pos // window, 0, per_ref_nw[rid] - 1)
-    w_hi = ref_win_off[rid] + np.clip((ends - 1) // window, 0, per_ref_nw[rid] - 1)
+    from disq_tpu.runtime.tracing import device_span, span
+
+    with span("ops.depth.prepare", records=int(batch.count)) as labels:
+        sel = (batch.refid >= 0) & (batch.refid < len(ref_lengths)) & (
+            (batch.flag & 0x4) == 0
+        )
+        if not sel.any():
+            return {
+                r: np.zeros(n_win_per_ref[r], dtype=np.int32)
+                for r in range(len(ref_lengths))
+            }
+        rid = batch.refid[sel].astype(np.int64)
+        pos = batch.pos[sel].astype(np.int64)
+        ends = batch.alignment_ends()[sel].astype(np.int64)
+        # a resident batch notes what it answered from
+        labels["ends"] = getattr(batch, "ends_source", "host")
+        per_ref_nw = np.asarray(n_win_per_ref, dtype=np.int64)
+        w_lo = (ref_win_off[rid] + np.clip(
+            pos // window, 0, per_ref_nw[rid] - 1)).astype(np.int32)
+        w_hi = (ref_win_off[rid] + np.clip(
+            (ends - 1) // window, 0, per_ref_nw[rid] - 1)).astype(np.int32)
     mesh = getattr(batch, "mesh", None)
     if mesh is not None:
         # mesh-native batch (runtime/mesh.py): shard the scatter over
         # the batch axis and psum the difference arrays — bit-exact vs
         # the single-device dispatch below
-        flat = _depth_psum(
-            w_lo.astype(np.int32), w_hi.astype(np.int32),
-            total_windows, mesh)
+        flat = _depth_psum(w_lo, w_hi, total_windows, mesh)
     else:
-        flat = np.asarray(
-            _depth_global(
-                jnp.asarray(w_lo.astype(np.int32)),
-                jnp.asarray(w_hi.astype(np.int32)),
-                n_windows=total_windows,
-            )
-        )
+        with device_span("device.kernel", kernel="depth",
+                         records=len(w_lo)) as fence:
+            out = fence.sync(_depth_global(
+                jnp.asarray(w_lo), jnp.asarray(w_hi),
+                n_windows=total_windows))
+        flat = np.asarray(out)
     return {
         r: flat[ref_win_off[r]: ref_win_off[r + 1]]
         for r in range(len(ref_lengths))

@@ -158,6 +158,41 @@ def clip_and_span(cigars: np.ndarray, cigar_offsets: np.ndarray
     return span, lead, trail
 
 
+def reference_spans_from_blob(blob: np.ndarray, offsets: np.ndarray,
+                              base: int = 0
+                              ) -> Tuple[np.ndarray, np.ndarray]:
+    """``(pos i32, reference length i64)`` of the records of ``blob``
+    from their fixed fields and CIGAR op words alone — the one
+    blob -> CIGAR-span routine of the tree
+    (``ColumnarBatch.reference_lengths`` / ``alignment_ends``, and
+    through them depth, pileup, the interval filters and the CRAM
+    writer). ``offsets`` are these records' (N+1,) offsets in a larger
+    blob of which ``blob`` is the part that starts at byte ``base``.
+    A record whose name and CIGAR sections do not fit inside it raises
+    the host parser's ``ValueError`` instead of being read past. The
+    sequential C pass when the native library loads, else the numpy
+    helpers above — equal values either way, and equal to
+    ``ReadBatch.reference_lengths``."""
+    try:
+        from disq_tpu.native import reference_lengths_native
+
+        return reference_lengths_native(blob, offsets, base)
+    except ImportError:
+        pass
+    offsets = np.asarray(offsets, dtype=np.int64) - base
+    rec_len = np.diff(offsets)
+    bad = (rec_len < 36) | (offsets[:-1] < 0) | (offsets[1:] > len(blob))
+    if not bad.any():
+        fields = record_fields_from_blob(blob, offsets)
+        bad = 36 + fields["l_read_name"] + 4 * fields["n_cigar"] > rec_len
+    if bad.any():
+        raise ValueError(
+            f"record {int(np.nonzero(bad)[0][0])}: malformed sections")
+    span, _lead, _trail = clip_and_span(
+        *cigar_arrays_from_blob(blob, fields))
+    return fields["pos"].astype(np.int32), span
+
+
 def qual_scores_from_blob(blob: np.ndarray,
                           fields: Dict[str, np.ndarray]) -> np.ndarray:
     """Per-record duplicate score = sum of base qualities >= 15 (the

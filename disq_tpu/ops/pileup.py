@@ -13,9 +13,9 @@ Mesh-aware via the exact ``shard_map`` + ``lax.psum`` machinery of
 reduction is bit-identical to the single-device scatter.
 
 A resident ``ColumnarBatch`` never host-parses records here: the
-alignment spans come from the vectorized cigar walk over the raw
-record bytes (``ops/markdup.cigar_arrays_from_blob``), the same
-host-assist precedent as ``window_depth``'s bound math.
+alignment ends come from the CIGAR pass over the raw record bytes
+that the batch runs and keeps (``ColumnarBatch.alignment_ends`` over
+``ops/markdup.reference_spans_from_blob``), as ``window_depth``'s do.
 """
 
 from __future__ import annotations
@@ -32,8 +32,7 @@ def _span_bounds(batch) -> Tuple[np.ndarray, np.ndarray, np.ndarray,
                                  np.ndarray]:
     """(refid, pos, end, mapped mask) for any batch flavor — resident
     batches derive the cigar spans from their record blob."""
-    from disq_tpu.ops.markdup import (
-        cigar_arrays_from_blob, clip_and_span, record_fields_from_blob)
+    from disq_tpu.ops.markdup import record_fields_from_blob
     from disq_tpu.runtime.columnar import ColumnarBatch
 
     if isinstance(batch, ColumnarBatch) and batch.device_backed:
@@ -41,10 +40,8 @@ def _span_bounds(batch) -> Tuple[np.ndarray, np.ndarray, np.ndarray,
         if src is not None:
             blob, offsets, order = src
             fields = record_fields_from_blob(blob, offsets, order)
-            cig, cig_off = cigar_arrays_from_blob(blob, fields)
-            span, _lead, _trail = clip_and_span(cig, cig_off)
             refid, pos, flag = fields["refid"], fields["pos"], fields["flag"]
-            end = pos + np.maximum(span, 1)
+            end = np.asarray(batch.alignment_ends(), np.int64)
             return refid, pos, end, (flag & 0x4) == 0
     refid = np.asarray(batch.refid, np.int64)
     pos = np.asarray(batch.pos, np.int64)

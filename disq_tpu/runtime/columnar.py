@@ -25,6 +25,9 @@ payload bytes never round-trip), and the parsed columns stay resident:
   permutation on device and fetches only the (n,) i32 order — the u64
   key vectors never move. ``ops/depth.py`` and every existing
   ``ReadBatch`` consumer work unchanged through the lazy properties.
+  ``alignment_ends()`` / ``reference_lengths()`` come from a
+  CIGAR-only pass over the record bytes, cached on the batch: depth,
+  pileup and the interval filters never host-parse a record.
 - **Host interop.** Ragged columns (names / cigars / seqs / quals /
   tags) come lazily from the host copy of the decoded blob (which the
   read path holds anyway for CRC verification and the record-offset
@@ -148,6 +151,26 @@ def _jax_fns():
             "record_check": record_check}
 
 
+class _SpanCache:
+    """The ``(alignment ends i32, reference lengths i64)`` of a record
+    blob, in the blob's own (source) order, and the lock under which
+    they are computed once. One holder per blob: ``permuted()`` views
+    share their source's, so whichever asks first pays the CIGAR pass
+    for all of them."""
+
+    __slots__ = ("lock", "spans")
+
+    def __init__(self) -> None:
+        self.lock = threading.Lock()
+        self.spans = None
+
+    def keep(self, ends: np.ndarray, reflen: np.ndarray) -> None:
+        # handed out as they are kept: a caller's in-place edit must
+        # raise, not move every later answer
+        ends.flags.writeable = reflen.flags.writeable = False
+        self.spans = (ends, reflen)
+
+
 class ColumnarBatch:
     """N alignment records with fixed columns resident on device (or a
     thin wrapper over a host ``ReadBatch``). Duck-compatible with
@@ -177,6 +200,12 @@ class ColumnarBatch:
         self._consumed: Dict[str, int] = {}
         self._ragged_rb: Optional[ReadBatch] = None
         self._rb: Optional[ReadBatch] = None
+        # host data about the record bytes, not about the device
+        # columns: release() and or_flags() leave it alone
+        self._span_cache = _SpanCache()
+        # what the last ask for ends / reference lengths was answered
+        # from: cached | ragged | cigar | host (``_span``)
+        self.ends_source: Optional[str] = None
         self._hbm = 0
         self._released = False
         # True when this batch is the sole owner of its record blob
@@ -499,6 +528,9 @@ class ColumnarBatch:
             out._blob = new_blob
             out._offsets = new_off
             out._blob_owned = True
+            spans = self._span_cache.spans
+            if spans is not None:
+                out._span_cache.keep(spans[0][src], spans[1][src])
             out._hbm = len(out._dev) * (k + pad) * 4
             track_hbm(out._hbm)
             _note_build(out._hbm)
@@ -573,10 +605,79 @@ class ColumnarBatch:
         return self._ragged_source().qual_string(i)
 
     def reference_lengths(self) -> np.ndarray:
-        return self._ragged_source().reference_lengths()
+        return self._span(1)
 
     def alignment_ends(self) -> np.ndarray:
-        return self._ragged_source().alignment_ends()
+        """0-based exclusive end positions, ``ReadBatch``'s values. A
+        batch that holds record bytes derives them from the CIGAR op
+        words alone, part by part, and keeps them (``_span``): no blob
+        join, no host record parse."""
+        return self._span(0)
+
+    def _span(self, which: int) -> np.ndarray:
+        """The ends i32 (0) or the reference lengths i64 (1) in
+        logical order, from what the batch holds, in this order: an
+        earlier CIGAR pass (``cached``), a host parse some consumer
+        already paid for (``ragged``; a host-built batch's own
+        columns, ``host``), the record bytes (``cigar``). Notes which
+        on ``ends_source``."""
+        spans = self._span_cache.spans
+        if spans is not None:
+            self.ends_source = "cached"
+        else:
+            rb = self._ragged_rb
+            if rb is not None:  # already in logical order, and free
+                self.ends_source = (
+                    "ragged" if self._offsets is not None else "host")
+                return (rb.reference_lengths() if which
+                        else rb.alignment_ends())
+            spans = self._spans_from_cigar()
+            self.ends_source = "cigar"
+        return (spans[which] if self._order is None
+                else spans[which][self._order])
+
+    def _spans_from_cigar(self):
+        """The CIGAR pass over the record bytes as they are held — one
+        blob or a concat's un-joined parts, whose record ranges the
+        rebased offsets give — cached in source order."""
+        from disq_tpu import native
+        from disq_tpu.ops.markdup import reference_spans_from_blob
+        from disq_tpu.runtime.tracing import counter, span
+
+        cache = self._span_cache
+        with cache.lock:
+            if cache.spans is not None:
+                return cache.spans
+            with self._lock:
+                parts = (self._blob_parts if self._blob is None
+                         else [self._blob])
+            offsets = self._offsets
+            n = len(offsets) - 1
+            with span("columnar.batch.ends", records=n,
+                      bytes=int(offsets[-1] - offsets[0])) as labels:
+                pos = np.empty(n, np.int32)
+                reflen = np.empty(n, np.int64)
+                lo = base = 0
+                for part in parts:
+                    hi = min(n, int(np.searchsorted(
+                        offsets, base + len(part))))
+                    try:
+                        pos[lo:hi], reflen[lo:hi] = (
+                            reference_spans_from_blob(
+                                part, offsets[lo: hi + 1], base))
+                    except ValueError as e:
+                        raise ValueError(
+                            f"records from {lo}: {e}") from None
+                    lo, base = hi, base + len(part)
+                if lo != n:
+                    raise ValueError(
+                        f"record {lo}: record offsets past the blob")
+                ends = pos + np.maximum(reflen, 1).astype(np.int32)
+                labels["source"] = (
+                    "native" if native.loaded() else "numpy")
+            counter("columnar.batch.ends_from_cigar").inc(n)
+            cache.keep(ends, reflen)
+            return cache.spans
 
     # -- resident device consumers ------------------------------------------
 
@@ -708,6 +809,7 @@ class ColumnarBatch:
         out._blob = self._blob
         out._blob_parts = self._blob_parts
         out._offsets = self._offsets
+        out._span_cache = self._span_cache
         out._order = base
         out._hbm = len(out._dev) * (self._n + pad) * 4
         track_hbm(out._hbm)
@@ -797,6 +899,11 @@ class ColumnarBatch:
                 at += b._n
                 pos += int(b._offsets[-1])
             self._offsets = offs
+            held = [b._span_cache.spans for b in batches]
+            if all(sp is not None for sp in held):
+                self._span_cache.keep(
+                    np.concatenate([sp[0] for sp in held]),
+                    np.concatenate([sp[1] for sp in held]))
             self._hbm = len(self._dev) * (self._n + pad) * 4
             from disq_tpu.runtime.tracing import track_hbm
 

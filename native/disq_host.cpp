@@ -346,6 +346,35 @@ int64_t disq_bam_fixed_columns(const uint8_t* buf, int64_t buf_len,
   return 0;
 }
 
+// CIGAR-only pass: each record's pos and the reference length its
+// CIGAR consumes (ops M, D, N, =, X), reading 36 fixed bytes and the
+// op words of a record and nothing else of it.  ``offsets`` index a
+// larger blob of which ``buf`` is the part that starts at ``base``.
+int64_t disq_bam_reference_lengths(const uint8_t* buf, int64_t buf_len,
+                                   const int64_t* offsets, int64_t base,
+                                   int64_t n, int32_t* pos,
+                                   int64_t* reflen) {
+  for (int64_t i = 0; i < n; i++) {
+    int64_t at = offsets[i] - base, end = offsets[i + 1] - base;
+    if (at < 0 || end < at + 36 || end > buf_len) return -1 - i;
+    const uint8_t* r = buf + at;
+    uint16_t nc;
+    std::memcpy(&nc, r + 16, 2);
+    if (36 + (int64_t)r[12] + 4LL * nc > end - at) return -1 - i;
+    std::memcpy(pos + i, r + 8, 4);
+    const uint8_t* c = r + 36 + r[12];
+    int64_t len = 0;
+    for (uint16_t k = 0; k < nc; k++, c += 4) {
+      uint32_t w;
+      std::memcpy(&w, c, 4);
+      // M=0 D=2 N=3 '='=7 X=8
+      if ((0x18Du >> (w & 0xF)) & 1) len += w >> 4;
+    }
+    reflen[i] = len;
+  }
+  return 0;
+}
+
 // Phase B: fill ragged columns (seq unpacked to one nibble code per byte).
 int64_t disq_bam_fill_ragged(const uint8_t* buf, const int64_t* offsets,
                              int64_t n, const int64_t* name_off,

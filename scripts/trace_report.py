@@ -119,8 +119,11 @@ CATEGORIES = (
     ("device_write", "W", ("device.deflate.",)),
     # HBM-resident fused decode (runtime/columnar.py): ColumnarBatch
     # build (upload-or-in-place parse chain), lazy per-column fetches,
-    # and release events carrying the batch's d2h-avoided bytes.
-    ("columnar", "C", ("columnar.",)),
+    # the CIGAR pass for the alignment ends, and release events
+    # carrying the batch's d2h-avoided bytes; with them the host side
+    # of windowed depth, which is those fetches, that pass and the
+    # window arithmetic on a resident batch.
+    ("columnar", "C", ("columnar.", "ops.depth.prepare")),
     # Hedged duplicate fetches (runtime/resilience.py): the duplicate's
     # own execution (hedge.fetch) and the loser's burned time
     # (hedge.waste) both paint H — a hedge racing its primary is
@@ -442,8 +445,10 @@ ADVICE = {
                     "the win",
     "columnar": "resident-decode build/fetch dominates: columns are "
                 "being materialized host-side after all — check which "
-                "consumer forces the fetches, or widen shards so one "
-                "parse launch covers more records",
+                "consumer forces the fetches (ops.depth.prepare is "
+                "depth's: its ends label says whether the batch "
+                "answered from its CIGAR bytes or a host parse), or "
+                "widen shards so one parse launch covers more records",
     "d2h_avoided": "the fused resident path is paying off: these "
                    "bytes stayed in HBM instead of crossing d2h — "
                    "keep consumers on the resident columns "

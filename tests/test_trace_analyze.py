@@ -93,6 +93,25 @@ class TestAttribution:
             assert bucket in trace_report.ADVICE
             assert bucket in trace_report.WORK_PRIORITY
 
+    def test_depth_host_side_is_a_columnar_row(self):
+        """``ops.depth.prepare`` (windowed depth's host side on a
+        resident batch: column fetches, the CIGAR pass, the window
+        arithmetic) reads in the ``columnar`` row, with the pass it
+        holds; the scatter that follows stays the device's."""
+        spans = [
+            _span("ops.depth.prepare", 0.0, 2.0, records=10, ends="cigar"),
+            _span("columnar.batch.ends", 0.5, 1.0, records=10,
+                  source="native"),
+            _span("device.kernel", 2.0, 1.0, kernel="depth"),
+        ]
+        buckets, *_rest, wall = trace_report.attribute_wall(spans)
+        assert wall == pytest.approx(3.0)
+        assert buckets == {
+            "columnar": pytest.approx(2.0),
+            "device": pytest.approx(1.0),
+        }
+        assert "ops.depth.prepare" in trace_report.ADVICE["columnar"]
+
     def test_empty(self):
         assert trace_report.attribute_wall([]) == ({}, 0.0, 0.0, 0.0)
 
