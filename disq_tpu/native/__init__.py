@@ -129,6 +129,11 @@ def _bind(lib: ctypes.CDLL) -> None:
         u8p, ctypes.c_int64, i64p, ctypes.c_int64, ctypes.c_int64, i32p,
         i64p,
     ]
+    lib.disq_bam_markdup_keys.restype = ctypes.c_int64
+    lib.disq_bam_markdup_keys.argtypes = [
+        u8p, ctypes.c_int64, i64p, ctypes.c_int64, i32p, i64p, i64p, i64p,
+        i64p,
+    ]
     lib.disq_rans_encode0.restype = ctypes.c_int64
     lib.disq_rans_encode0.argtypes = [u8p, ctypes.c_int64, u8p, ctypes.c_int64]
     lib.disq_rans_encode1.restype = ctypes.c_int64
@@ -371,6 +376,25 @@ def reference_lengths_native(buf, offsets: np.ndarray, base: int = 0):
     if rc != 0:
         raise ValueError(f"record {-(rc + 1)}: malformed sections")
     return pos, reflen
+
+
+def markdup_keys_native(buf, offsets: np.ndarray):
+    """``(pos i32, reference length, leading clip, trailing clip, score)``
+    (the last four i64) of the records of ``buf`` at ``offsets``, one
+    sequential C pass over their CIGAR op words and quality bytes."""
+    lib = _load()
+    arr = _as_u8(buf)
+    offsets = np.ascontiguousarray(offsets, dtype=np.int64)
+    n = max(0, len(offsets) - 1)
+    pos = np.empty(n, np.int32)
+    out = [np.empty(n, np.int64) for _ in range(4)]
+    rc = lib.disq_bam_markdup_keys(
+        _ptr(arr, ctypes.c_uint8), len(arr),
+        _ptr(offsets, ctypes.c_int64), n, _ptr(pos, ctypes.c_int32),
+        *(_ptr(a, ctypes.c_int64) for a in out))
+    if rc != 0:
+        raise ValueError(f"record {-(rc + 1)}: malformed sections")
+    return (pos, *out)
 
 
 def encode_records_native(batch) -> tuple[bytes, np.ndarray]:

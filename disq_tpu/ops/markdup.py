@@ -12,12 +12,14 @@ supplementary (``0x904``) are never examined and never marked.
 
 Resident batches never host-parse: the key columns (flag / refid /
 pos / clip extents / qual score) are derived **from the raw record
-bytes** by vectorized numpy passes over the blob the batch already
-holds (the same host-assist precedent as ``ops/depth.py``'s bound
-math), uploaded once, and the group scan — a stable device lexsort +
-segment-boundary detection, the same machinery family as
-``sort_permutation`` — marks duplicates in one launch. The duplicate
-bits are written back through ``ColumnarBatch.or_flags``: the
+bytes** the batch already holds — the fixed fields by numpy, reference
+span, clips and score by one sweep with memory O(records)
+(``key_sweep_from_blob``: a sequential C pass, numpy in bounded chunks
+where the native library is absent; the same host-assist precedent as
+``ops/depth.py``'s bound math) — uploaded once, and the group scan — a
+stable device lexsort + segment-boundary detection, the same machinery
+family as ``sort_permutation`` — marks duplicates in one launch. The
+duplicate bits are written back through ``ColumnarBatch.or_flags``: the
 resident flag column and the record blob bytes both carry ``0x400``,
 so the resident write path emits bytes identical to a host-marked
 file. Host ``ReadBatch`` inputs run the same key math over their
@@ -211,6 +213,54 @@ def qual_scores_from_flat(q: np.ndarray, seg_off: np.ndarray) -> np.ndarray:
                          np.asarray(seg_off, dtype=np.int64))
 
 
+# what the numpy sweep may index at once: the quality bytes of one
+# chunk of records, never of the whole blob
+SWEEP_CHUNK_BASES = 1 << 22
+
+
+def key_sweep_from_blob(blob: np.ndarray, offsets: np.ndarray):
+    """``(pos i32, reference length, leading clip, trailing clip,
+    score)`` (the last four i64) of every record of ``blob``, in the
+    blob's order: what duplicate marking needs of the CIGAR and the
+    qualities, in one sweep whose memory is O(records). The sequential
+    C pass when the native library loads; else the numpy helpers above
+    over chunks of records whose qualities together stay under
+    ``SWEEP_CHUNK_BASES`` (a record longer than that is a chunk of its
+    own) — equal values either way. A record whose sections do not fit
+    inside it raises the host parser's ``ValueError``."""
+    try:
+        from disq_tpu.native import markdup_keys_native
+
+        return markdup_keys_native(blob, offsets)
+    except ImportError:
+        pass
+    offsets = np.asarray(offsets, dtype=np.int64)
+    n = len(offsets) - 1
+    rec_len = np.diff(offsets)
+    bad = (rec_len < 36) | (offsets[:-1] < 0) | (offsets[1:] > len(blob))
+    if not bad.any():
+        fields = record_fields_from_blob(blob, offsets)
+        lseq = fields["l_seq"]
+        bad = (lseq < 0) | (36 + fields["l_read_name"]
+                            + 4 * fields["n_cigar"] + (lseq + 1) // 2
+                            + lseq > rec_len)
+    if bad.any():
+        raise ValueError(
+            f"record {int(np.nonzero(bad)[0][0])}: malformed sections")
+    span, lead, trail = clip_and_span(*cigar_arrays_from_blob(blob, fields))
+    score = np.empty(n, np.int64)
+    bases = np.zeros(n + 1, np.int64)
+    np.cumsum(lseq, out=bases[1:])
+    lo = 0
+    while lo < n:
+        hi = max(lo + 1, int(np.searchsorted(
+            bases, bases[lo] + SWEEP_CHUNK_BASES, side="right")) - 1)
+        score[lo:hi] = qual_scores_from_blob(
+            blob, {k: v[lo:hi] for k, v in fields.items()})
+        lo = hi
+    return fields["pos"].astype(np.int32), span, lead, trail, score
+
+
 # -- key construction --------------------------------------------------------
 
 
@@ -259,7 +309,7 @@ def _markdup_kernel():
     import jax.numpy as jnp
 
     @jax.jit
-    def run(refid, upos, orient, negscore, valid, n):
+    def markdup_group_scan(refid, upos, orient, negscore, valid, n):
         # u32/i32 keys only — jax's default 32-bit mode would silently
         # truncate an i64 sentinel
         m = refid.shape[0]
@@ -283,7 +333,7 @@ def _markdup_kernel():
         return dup, jnp.sum(live.astype(jnp.int32)), \
             jnp.sum(dup_sorted.astype(jnp.int32))
 
-    return run
+    return markdup_group_scan
 
 
 # -- per-shard marking -------------------------------------------------------
@@ -316,13 +366,18 @@ def _key_columns(batch) -> Tuple[Dict[str, np.ndarray], bool]:
     if isinstance(batch, ColumnarBatch) and batch.device_backed:
         src = batch.encode_source()
         if src is not None:
+            from disq_tpu import native
+            from disq_tpu.runtime.tracing import span
+
             blob, offsets, order = src
-            fields = record_fields_from_blob(blob, offsets, order)
-            cig, cig_off = cigar_arrays_from_blob(blob, fields)
-            span, lead, trail = clip_and_span(cig, cig_off)
-            score = qual_scores_from_blob(blob, fields)
+            with span("ops.markdup.keys", records=len(offsets) - 1,
+                      bytes=int(offsets[-1] - offsets[0])) as labels:
+                fields = record_fields_from_blob(blob, offsets, order)
+                reflen, lead, trail, score = batch.clips_and_scores()
+                labels["source"] = "native" if native.loaded() else "numpy"
+                labels["spans"] = batch.ends_source
             return {"flag": fields["flag"], "refid": fields["refid"],
-                    "pos": fields["pos"], "span": span, "lead": lead,
+                    "pos": fields["pos"], "span": reflen, "lead": lead,
                     "trail": trail, "score": score}, True
     flag = np.asarray(batch.flag, np.int64)
     refid = np.asarray(batch.refid, np.int64)
@@ -348,6 +403,16 @@ def _apply_mask(batch, dup_mask: np.ndarray):
     return batch
 
 
+def register_counters() -> None:
+    """The operator's counters at 0, so that a reader tells "did not
+    move" (no record examined, no duplicate found) from "no such
+    counter" (telemetry may have been reset since the last call)."""
+    from disq_tpu.runtime.tracing import counter
+
+    for name in ("ops.markdup.examined", "ops.markdup.duplicates"):
+        counter(name).inc(0)
+
+
 def markdup_batch(batch, boundary_bp: int = DEFAULT_BOUNDARY_BP
                   ) -> Tuple[object, MarkdupResult]:
     """Mark duplicates within one (coordinate-sorted) batch. Returns
@@ -371,6 +436,7 @@ def markdup_batch(batch, boundary_bp: int = DEFAULT_BOUNDARY_BP
                                   cols["score"], valid)
             examined, dups = int(valid.sum()), int(dup.sum())
         batch = _apply_mask(batch, dup)
+        counter("ops.markdup.examined").inc(int(examined))
         counter("ops.markdup.duplicates").inc(int(dups))
         res = MarkdupResult(dup, int(examined), int(dups))
         res.candidates = _boundary_candidates(

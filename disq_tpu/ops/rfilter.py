@@ -203,7 +203,8 @@ def _mask_kernel():
     import jax.numpy as jnp
 
     @jax.jit
-    def build(flag, mapq, nh, req, exc, minq, seed_mix, thresh, n):
+    def read_filter_mask(flag, mapq, nh, req, exc, minq, seed_mix, thresh,
+                         n):
         f = flag.astype(jnp.uint32)
         keep = (f & req) == req
         keep &= (f & exc) == 0
@@ -219,7 +220,7 @@ def _mask_kernel():
         keep &= jnp.arange(flag.shape[0], dtype=jnp.int32) < n
         return keep
 
-    return build
+    return read_filter_mask
 
 
 def resident_mask(rf: ReadFilter, batch) -> np.ndarray:

@@ -115,7 +115,7 @@ def _rg_kernel(n_rg: int):
     import jax.numpy as jnp
 
     @jax.jit
-    def run(rg, mapq, flag, n):
+    def rgstats_reduce(rg, mapq, flag, n):
         m = rg.shape[0]
         valid = (jnp.arange(m, dtype=jnp.int32) < n).astype(jnp.int32)
         comb = rg * 256 + mapq.astype(jnp.int32)
@@ -124,7 +124,7 @@ def _rg_kernel(n_rg: int):
         dups = jnp.zeros(n_rg, jnp.int32).at[rg].add(dupbit)
         return hist, dups
 
-    return run
+    return rgstats_reduce
 
 
 @functools.lru_cache(maxsize=8)

@@ -204,7 +204,8 @@ class ColumnarBatch:
         # columns: release() and or_flags() leave it alone
         self._span_cache = _SpanCache()
         # what the last ask for ends / reference lengths was answered
-        # from: cached | ragged | cigar | host (``_span``)
+        # from: cached | ragged | cigar | host (``_span``), or swept
+        # (``clips_and_scores``)
         self.ends_source: Optional[str] = None
         self._hbm = 0
         self._released = False
@@ -412,13 +413,15 @@ class ColumnarBatch:
             with self._lock:
                 if self._ragged_rb is None:
                     from disq_tpu.bam.codec import decode_records
-                    from disq_tpu.runtime.tracing import counter
+                    from disq_tpu.runtime.tracing import counter, span
 
-                    rb = decode_records(
-                        self._host_blob(), self._offsets,
-                        n_ref=self._n_ref)
-                    if self._order is not None:
-                        rb = rb.take(self._order)
+                    blob = self._host_blob()
+                    with span("columnar.batch.materialize",
+                              records=self._n, bytes=len(blob)):
+                        rb = decode_records(
+                            blob, self._offsets, n_ref=self._n_ref)
+                        if self._order is not None:
+                            rb = rb.take(self._order)
                     self._ragged_rb = rb
                     # the operator-suite resident-leg witness: a fully
                     # resident chain never host-parses records
@@ -678,6 +681,31 @@ class ColumnarBatch:
             counter("columnar.batch.ends_from_cigar").inc(n)
             cache.keep(ends, reflen)
             return cache.spans
+
+    def clips_and_scores(self):
+        """``(reference lengths, leading clips, trailing clips,
+        scores)``, i64 each, in logical order: duplicate marking's
+        inputs from one sweep over the record bytes
+        (``ops/markdup.key_sweep_from_blob``), no host record parse.
+        The reference lengths are the span cache's where it holds them
+        (``ends_source`` then says ``cached``); else the sweep's are
+        kept there with their ends (``swept``). Clips and scores are
+        not kept: markdup is their only reader."""
+        from disq_tpu.ops.markdup import key_sweep_from_blob
+
+        pos, reflen, lead, trail, score = key_sweep_from_blob(
+            self._host_blob(), self._offsets)
+        cache = self._span_cache
+        with cache.lock:
+            self.ends_source = "cached"
+            if cache.spans is None:
+                cache.keep(pos + np.maximum(reflen, 1).astype(np.int32),
+                           reflen)
+                self.ends_source = "swept"
+            reflen = cache.spans[1]
+        out = (reflen, lead, trail, score)
+        return out if self._order is None \
+            else tuple(a[self._order] for a in out)
 
     # -- resident device consumers ------------------------------------------
 

@@ -82,8 +82,10 @@ class MarkdupOp(_Op):
     name = "markdup"
 
     def __init__(self, boundary_bp: Optional[int] = None):
-        from disq_tpu.ops.markdup import DEFAULT_BOUNDARY_BP
+        from disq_tpu.ops.markdup import (
+            DEFAULT_BOUNDARY_BP, register_counters)
 
+        register_counters()
         self.boundary_bp = (DEFAULT_BOUNDARY_BP if boundary_bp is None
                             else int(boundary_bp))
         self._results: List = []

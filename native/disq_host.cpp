@@ -346,6 +346,18 @@ int64_t disq_bam_fixed_columns(const uint8_t* buf, int64_t buf_len,
   return 0;
 }
 
+// Reference bases the ``nc`` CIGAR op words at ``c`` consume.
+static inline int64_t cigar_reference_length(const uint8_t* c, uint16_t nc) {
+  int64_t len = 0;
+  for (uint16_t k = 0; k < nc; k++, c += 4) {
+    uint32_t w;
+    std::memcpy(&w, c, 4);
+    // M=0 D=2 N=3 '='=7 X=8
+    if ((0x18Du >> (w & 0xF)) & 1) len += w >> 4;
+  }
+  return len;
+}
+
 // CIGAR-only pass: each record's pos and the reference length its
 // CIGAR consumes (ops M, D, N, =, X), reading 36 fixed bytes and the
 // op words of a record and nothing else of it.  ``offsets`` index a
@@ -362,15 +374,64 @@ int64_t disq_bam_reference_lengths(const uint8_t* buf, int64_t buf_len,
     std::memcpy(&nc, r + 16, 2);
     if (36 + (int64_t)r[12] + 4LL * nc > end - at) return -1 - i;
     std::memcpy(pos + i, r + 8, 4);
-    const uint8_t* c = r + 36 + r[12];
-    int64_t len = 0;
-    for (uint16_t k = 0; k < nc; k++, c += 4) {
-      uint32_t w;
-      std::memcpy(&w, c, 4);
-      // M=0 D=2 N=3 '='=7 X=8
-      if ((0x18Du >> (w & 0xF)) & 1) len += w >> 4;
+    reflen[i] = cigar_reference_length(r + 36 + r[12], nc);
+  }
+  return 0;
+}
+
+// Duplicate-marking keys in one sweep: each record's pos, the reference
+// length its CIGAR consumes, the clipped bases (S=4 / H=5) at either end
+// and its score, the sum of its base qualities >= 15 (0xFF, "no
+// qualities", scores 0).  Memory is the five outputs: nothing here is
+// records x read length.  A clip counts at an end only as that end's
+// outermost op, or as the next one in when the outermost was a clip too
+// (H then S), as ``ops/markdup.clip_and_span`` has it.
+int64_t disq_bam_markdup_keys(const uint8_t* buf, int64_t buf_len,
+                              const int64_t* offsets, int64_t n,
+                              int32_t* pos, int64_t* reflen, int64_t* lead,
+                              int64_t* trail, int64_t* score) {
+  auto clip = [](uint32_t w) { return (w & 0xF) == 4 || (w & 0xF) == 5; };
+  for (int64_t i = 0; i < n; i++) {
+    int64_t at = offsets[i], end = offsets[i + 1];
+    if (at < 0 || end < at + 36 || end > buf_len) return -1 - i;
+    const uint8_t* r = buf + at;
+    uint16_t nc;
+    int32_t ls;
+    std::memcpy(&nc, r + 16, 2);
+    std::memcpy(&ls, r + 20, 4);
+    if (ls < 0) return -1 - i;
+    int64_t cig = 36 + (int64_t)r[12], qual = cig + 4LL * nc + (ls + 1) / 2;
+    if (qual + ls > end - at) return -1 - i;
+    std::memcpy(pos + i, r + 8, 4);
+    const uint8_t* c = r + cig;
+    reflen[i] = cigar_reference_length(c, nc);
+    int64_t lc = 0, tc = 0;
+    uint32_t w0, w1;
+    if (nc > 0) {
+      std::memcpy(&w0, c, 4);
+      if (clip(w0)) {
+        lc = w0 >> 4;
+        if (nc > 1) {
+          std::memcpy(&w1, c + 4, 4);
+          if (clip(w1)) lc += w1 >> 4;
+        }
+      }
+      std::memcpy(&w0, c + 4 * (nc - 1), 4);
+      if (clip(w0)) {
+        tc = w0 >> 4;
+        if (nc > 1) {
+          std::memcpy(&w1, c + 4 * (nc - 2), 4);
+          if (clip(w1)) tc += w1 >> 4;
+        }
+      }
     }
-    reflen[i] = len;
+    lead[i] = lc;
+    trail[i] = tc;
+    const uint8_t* q = r + qual;
+    int64_t sum = 0;
+    for (int32_t k = 0; k < ls; k++)
+      if (q[k] >= 15 && q[k] != 0xFF) sum += q[k];
+    score[i] = sum;
   }
   return 0;
 }

@@ -118,6 +118,15 @@ def decode_one(data: bytes, off: int) -> Tuple[ORecord, int]:
     return rec, off + 4 + block_size
 
 
+def decode_all(data: bytes, off: int = 0) -> List[ORecord]:
+    """Every record of ``data`` from ``off`` to its end."""
+    records = []
+    while off < len(data):
+        rec, off = decode_one(data, off)
+        records.append(rec)
+    return records
+
+
 # -- oracle-side BGZF + BAM file framing (independent of disq_tpu.bgzf) ----
 
 def _o_bgzf_block(payload: bytes) -> bytes:
@@ -187,11 +196,7 @@ def parse_bam(data: bytes) -> Tuple[str, List[Tuple[str, int]], List[ORecord]]:
         (l_ref,) = struct.unpack_from("<i", raw, p)
         p += 4
         refs.append((name, l_ref))
-    records = []
-    while p < len(raw):
-        rec, p = decode_one(raw, p)
-        records.append(rec)
-    return text, refs, records
+    return text, refs, decode_all(raw, p)
 
 
 # -- fixture synthesis ------------------------------------------------------
