@@ -16,15 +16,21 @@ on the state the one before left: phase A (header / stored / one
 table-build step / one literal-or-length symbol, or a literal pair),
 phase B (the distance of the length phase A has just read), then the
 copy phase (the lanes inside a match, those phase B has just put there
-included). So a token costs one superstep: a literal (two when both fit
-the output word), or a match's length + distance + first copy chunk
-together; a long match then takes one superstep more per chunk. ``meta``
-row 2 carries the launch's superstep count (counter
+included). So a token costs one superstep: a literal (two when the
+next token is a literal too), or a match's length + distance + first
+copy chunk together; a long match then takes one superstep more per chunk. An
+emit is placed at the output's byte offset ``off`` and may run past the
+output word's boundary: a literal pair is taken at every offset, and a
+copy chunk runs to the end of the fourth output word for d >= 16
+(``16 - off`` bytes), of the second for d >= 8 (``8 - off``), of the
+first below (``4 - off``), so a short match is one chunk wherever it
+starts. ``meta`` row 2 carries the launch's superstep count (counter
 ``device.inflate.supersteps``), row 3 how many of them read history
-past the ring (``device.inflate.far_supersteps``). A lane emits 1-2
-bytes per literal superstep, up to 4 per stored/short-copy superstep,
-and 8 or 16 (two or four output words) in the aligned steady state of a
-long match (d >= 8 / d >= 16).
+past the ring (``device.inflate.far_supersteps``), row 4 each lane's
+count of copy chunks that ran past the word boundary they started in
+(summed over the lanes: ``device.inflate.crossing_chunks``). A lane
+emits 1-2 bytes per literal superstep, up to 4 per stored superstep,
+and up to 4, 8 or 16 per copy superstep (d < 8 / d >= 8 / d >= 16).
 All data-dependent indexing is a one-hot sweep: the row gather
 ``sum(where(row_iota == idx, data, 0))``, pure compares, selects and a
 sublane reduction, which Mosaic lowers for any row count (whether
@@ -489,7 +495,8 @@ def _inflate_simd_kernel(
     def superstep(carry):
         (step, state, lo, hi, cnt, in_w, outpos, bfinal, fixed,
          copy_len, copy_dist, hlit, hdist, hclen, tb_idx, tb_nread,
-         rep_val, rep_cnt, prev_len, status, far_steps) = carry
+         rep_val, rep_cnt, prev_len, status, far_steps,
+         crossing) = carry
 
         live = (state != _DONE) & (state != _ERR)
         lo, hi, cnt, in_w = refill64(lo, hi, cnt, in_w)
@@ -497,9 +504,11 @@ def _inflate_simd_kernel(
 
         new_state = state
         new_status = status
-        # emit: up to 4 bytes per lane per superstep, clipped at the
-        # output word boundary so the big-out RMW is a single one-hot
-        # pass. packed = LE bytes, emit_k = byte count (0..4).
+        # emit: packed = the chunk's LE bytes from its first (a copy
+        # chunk's later words follow in the copy phase), emit_k = its
+        # byte count; the emit merge places it at the output's byte
+        # offset. A stored chunk stops at the word boundary (<= 4
+        # bytes: it is consumed from the 32-bit peek).
         emit_k = zrow
         packed = zrow_u
         off = outpos & 3
@@ -682,17 +691,18 @@ def _inflate_simd_kernel(
         packed = jnp.where(mlit, sym.astype(_U32), packed)
         # second literal: Huffman is prefix-free, so the bits after
         # symbol 1 are always the TRUE next symbol — decode it too and
-        # take the pair when both are literals and two bytes still fit
-        # the current output word (off <= 2, so the emit path is
-        # unchanged). Literal runs dominate the superstep count once
-        # long copies emit 16 bytes, so pairs nearly halve them.
+        # take the pair when both are literals, at every output offset
+        # (at off == 3 the second byte lands in the next output word:
+        # the emit merge places any chunk). Literal runs dominate the
+        # superstep count once long copies emit 16 bytes, so pairs
+        # nearly halve them.
         # Bit budget: two codes <= 30 bits of the >= 33 available.
         didx2, dbits2, dfound2 = _decode_canonical(
             bitbuf >> dbits.astype(_U32), 15,
             cntl_ref[...], firstl_ref[...], offl_ref[...],
             _FCNT_L, _FFIRST_L, _FOFF_L, fixed_b)
         sym2 = _gather(symdata, didx2)
-        mpair = mlit & dfound2 & (sym2 < 256) & (off <= 2)
+        mpair = mlit & dfound2 & (sym2 < 256)
         emit_k = jnp.where(mpair, 2, emit_k)
         packed = jnp.where(
             mpair, sym.astype(_U32) | (sym2.astype(_U32) << 8), packed)
@@ -762,17 +772,20 @@ def _inflate_simd_kernel(
         # ring window read the big out buffer under a gated cond (both
         # as aligned 8-word tiles, below). For
         # d < 4 the 4 fetched bytes start at outpos-d and are replicated
-        # modularly (byte j := B[j mod d]). When the output is
-        # word-aligned (the steady state inside a long match — the first
-        # partial step aligns it), TWO words emit straight from the
-        # source for d >= 8 and FOUR for d >= 16, cutting the superstep
-        # count of long copies 4x.
+        # modularly (byte j := B[j mod d]). A chunk starts at the
+        # output's byte offset, wherever that is, and runs to the end
+        # of the FOURTH output word for d >= 16 (16 - off bytes), of
+        # the SECOND for d >= 8 (8 - off), of the first below (4 - off):
+        # never more than d bytes, so the invariant above holds, and
+        # never a fifth output word. Most matches are shorter than that
+        # (wgs30x: mean 10 bytes at d >= 16), so a match is one chunk
+        # at any offset; a long one is word-aligned after its first.
         m = new_state == _COPY
         d = copy_dist
-        elig8 = m & (off == 0) & (d >= 8)
-        elig16 = elig8 & (d >= 16)
+        wide8 = m & (d >= 8)
+        wide16 = m & (d >= 16)
         ck = jnp.minimum(
-            jnp.where(elig16, 16, jnp.where(elig8, 8, kmax)), copy_len)
+            jnp.where(wide16, 16, jnp.where(wide8, 8, 4)) - off, copy_len)
         base = outpos - d
         bw = base >> 2
         bo = ((base & 3) << 3).astype(_U32)
@@ -807,7 +820,7 @@ def _inflate_simd_kernel(
         k = bw & 7
         w0, w1, w2, w3, w4 = (
             _gather(hist, jnp.where(live_j, k + j, -1))
-            for j, live_j in enumerate((m, m, elig8, elig16, elig16)))
+            for j, live_j in enumerate((m, m, wide8, wide16, wide16)))
         sh = (_U32(32) - bo) & _U32(31)
         asm = jnp.where(bo == 0, w0, (w0 >> bo) | (w1 << sh))
         asm2 = jnp.where(bo == 0, w1, (w1 >> bo) | (w2 << sh))
@@ -826,34 +839,47 @@ def _inflate_simd_kernel(
                                   jnp.where(d == 3, r3, asm)))
         emit_k = jnp.where(m, ck, emit_k)
         packed = jnp.where(m, cpk, packed)
-        packed_w1 = jnp.where(elig8, asm2, zrow_u)
-        packed_w2 = jnp.where(elig16, asm3, zrow_u)
-        packed_w3 = jnp.where(elig16, asm4, zrow_u)
         copy_len = jnp.where(m, copy_len - ck, copy_len)
         new_state = jnp.where(m & (copy_len == 0), _DECODE, new_state)
 
         # ---- emit merge ---------------------------------------------
-        # up to 4 output words per lane: the low word carries bytes at
-        # the current offset as before; words 1..3 exist only for
-        # 8/16-byte copy emits (off == 0 guaranteed there, so whole)
+        # Any chunk at any offset: the chunk's bytes from its first
+        # (packed, then asm2 .. asm4: zero outside the copy phase) and
+        # its count emit_k are placed at byte off of output word w0r
+        # and fill up to 4 output words. Word j holds the chunk's bytes
+        # 4j - off .. 4j - off + 3, the low bytes from the chunk word
+        # before; byte counts clip(off + emit_k - 4j, 0, 4) mask what
+        # lies past the chunk's end (word 0: less its low off bytes,
+        # which earlier supersteps wrote).
         emit_k = jnp.where(live & (new_state != _ERR), emit_k, zrow)
         over = (emit_k > 0) & (outpos + emit_k > ow * 4)
         new_status = jnp.where(over, 5, new_status)
         new_state = jnp.where(over, _ERR, new_state)
         emit_k = jnp.where(over, 0, emit_k)
         emitting = emit_k > 0
-        klo = jnp.minimum(emit_k, 4)
-        k1 = jnp.clip(emit_k - 4, 0, 4)
-        k2 = jnp.clip(emit_k - 8, 0, 4)
-        k3 = jnp.clip(emit_k - 12, 0, 4)
+        crossing = crossing + (
+            m & (off != 0) & (emit_k > kmax)).astype(_I32)
+        end = off + emit_k
+        klo = jnp.minimum(emit_k, kmax)
+        k1 = jnp.clip(end - 4, 0, 4)
+        k2 = jnp.clip(end - 8, 0, 4)
+        k3 = jnp.clip(end - 12, 0, 4)
         kmask = _mask_bits(klo << 3)
         kmask1 = _mask_bits(k1 << 3)
         kmask2 = _mask_bits(k2 << 3)
         kmask3 = _mask_bits(k3 << 3)
-        bits = (packed & kmask) << ((off << 3).astype(_U32))
-        bits1 = packed_w1 & kmask1
-        bits2 = packed_w2 & kmask2
-        bits3 = packed_w3 & kmask3
+        shl = (off << 3).astype(_U32)
+        shr = (_U32(32) - shl) & _U32(31)
+        aligned = off == 0
+
+        def placed(before, word, mask):
+            return jnp.where(
+                aligned, word, (before >> shr) | (word << shl)) & mask
+
+        bits = (packed & kmask) << shl
+        bits1 = placed(packed, asm2, kmask1)
+        bits2 = placed(asm2, asm3, kmask2)
+        bits3 = placed(asm3, asm4, kmask3)
         # big out: bytes land exactly once, buffer starts zeroed -> OR;
         # mask folded into the row (-1 matches nothing): pure one-hot,
         # slab-wise to bound scoped-vmem temps, and slab-gated on the
@@ -886,7 +912,7 @@ def _inflate_simd_kernel(
         rrow2 = jnp.where(emitting & (k2 > 0), (w0r + 2) & (RING_W - 1), -1)
         rrow3 = jnp.where(emitting & (k3 > 0), (w0r + 3) & (RING_W - 1), -1)
         curr = ring_ref[...]
-        bmask = kmask << ((off << 3).astype(_U32))
+        bmask = kmask << shl
         rri = _riota(RING_W)
         curr = jnp.where(rri == rrow, (curr & ~bmask) | bits, curr)
         curr = jnp.where(rri == rrow1, (curr & ~kmask1) | bits1, curr)
@@ -904,7 +930,7 @@ def _inflate_simd_kernel(
         return (step + 1, new_state, lo, hi, cnt, in_w, outpos,
                 bfinal, fixed, copy_len, copy_dist, hlit, hdist, hclen,
                 tb_idx, tb_nread, rep_val, rep_cnt, prev_len, new_status,
-                far_steps)
+                far_steps, crossing)
 
     def cond(carry):
         step, state = carry[0], carry[1]
@@ -916,17 +942,18 @@ def _inflate_simd_kernel(
         jnp.int32(0), init_state, zrow_u, zrow_u, zrow, zrow, zrow,
         zrow, zrow, zrow, zrow,
         zrow, zrow, zrow, zrow, zrow, zrow, zrow, zrow, zrow,
-        jnp.int32(0),
+        jnp.int32(0), zrow,
     )
     final = lax.while_loop(cond, superstep, init)
     step, state, _lo, _hi, _cnt, _iw, outpos = final[:7]
-    status, far_steps = final[19], final[20]
+    status, far_steps, crossing = final[19:22]
     # lanes still live at the step cap ran away
     status = jnp.where(
         (state != _DONE) & (state != _ERR), 6, status)
     meta_ref[...] = jnp.concatenate(
         [outpos, status, jnp.broadcast_to(step[None, None], (1, LANES)),
-         jnp.broadcast_to(far_steps[None, None], (1, LANES))], axis=0)
+         jnp.broadcast_to(far_steps[None, None], (1, LANES)),
+         crossing], axis=0)
 
 
 @functools.lru_cache(maxsize=16)
@@ -948,7 +975,7 @@ def _compiled(cw: int, ow: int, interpret: bool,
         kernel,
         out_shape=(
             jax.ShapeDtypeStruct((ow, LANES), _U32),
-            jax.ShapeDtypeStruct((4, LANES), _I32),
+            jax.ShapeDtypeStruct((5, LANES), _I32),
         ),
         in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)] * (2 + len(_CONST_TABLES)),
         out_specs=(
@@ -1190,7 +1217,11 @@ def _fetch_chunk(handle, lanes: int,
     3, the supersteps in which some lane read history past the ring, is
     booked beside it as ``device.inflate.far_supersteps`` and the label
     ``far_supersteps``: over the supersteps it is the share that paid
-    the far sweeps of the out buffer."""
+    the far sweeps of the out buffer.  ``meta`` row 4, each lane's copy
+    chunks that ran past the output word they started in, is summed
+    over the lanes into ``device.inflate.crossing_chunks`` and the label
+    ``crossing_chunks``: how often the one-chunk match engaged (0 for
+    a launch with no match in it)."""
     words, meta = handle
     if labels is None:
         labels = {"kind": "inflate", "lanes": lanes}
@@ -1204,8 +1235,10 @@ def _fetch_chunk(handle, lanes: int,
         if kernel == "inflate_simd":
             at_end["supersteps"] = supersteps = int(meta[2, 0])
             at_end["far_supersteps"] = far = int(meta[3, 0])
+            at_end["crossing_chunks"] = crossing = int(meta[4].sum())
             _counter("device.inflate.supersteps").inc(supersteps)
             _counter("device.inflate.far_supersteps").inc(far)
+            _counter("device.inflate.crossing_chunks").inc(crossing)
     _count_transfer("d2h", nbytes)
     return words.view(np.uint8), meta
 

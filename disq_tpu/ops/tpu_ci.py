@@ -143,8 +143,10 @@ def run_inflate_simd_wgs30x(results: list, record_bytes: bytes) -> None:
     caller hands in the record bytes (``benchmark/gen.py`` +
     ``reference.encode_records``; this package does not import the
     benchmark). Kernel-only, with the launch's two factors (supersteps,
-    seconds a superstep) and the share of its supersteps in which some
-    lane read history past the ring (meta row 3)."""
+    seconds a superstep), the share of its supersteps in which some
+    lane read history past the ring (meta row 3) and the copy chunks
+    that ran past the output word they started in (meta row 4, summed
+    over the lanes)."""
     block = 65280
     assert len(record_bytes) >= 128 * block, (
         f"{len(record_bytes)} record bytes do not fill 128 lanes")
@@ -160,6 +162,7 @@ def run_inflate_simd_wgs30x(results: list, record_bytes: bytes) -> None:
         **factors,
         "far_superstep_share": round(
             int(meta[3, 0]) / factors["supersteps_per_launch"], 4),
+        "crossing_chunks": int(meta[4].sum()),
         "correct": ok,
     })
     assert ok, "wgs30x SIMD inflate output != its input"

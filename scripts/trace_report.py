@@ -668,8 +668,10 @@ def analyze(spans, run, runs, dropped: int = 0) -> str:
     # spans' supersteps labels, meta row 2 of each launch) and seconds
     # a superstep (the dispatcher's device.launch.wait over them); and
     # the share of the supersteps in which some lane read history past
-    # the ring (the far_supersteps labels, meta row 3)
-    launches = steps = far_steps = 0
+    # the ring (the far_supersteps labels, meta row 3); and the copy
+    # chunks that ran past the output word they started in (the
+    # crossing_chunks labels, meta row 4 summed over a launch's lanes)
+    launches = steps = far_steps = crossing = 0
     wait_s = 0.0
     for s in spans:
         labels = s.get("labels") or {}
@@ -681,12 +683,14 @@ def analyze(spans, run, runs, dropped: int = 0) -> str:
             launches += 1
             steps += int(labels["supersteps"])
             far_steps += int(labels.get("far_supersteps", 0))
+            crossing += int(labels.get("crossing_chunks", 0))
     if steps:
         out.append(
             f"inflate_supersteps: {steps / launches:,.0f} a launch over "
             f"{launches} launches, {wait_s / steps * 1e6:.2f} us a "
             f"superstep (device.launch.wait), {far_steps / steps * 100:.1f}% "
-            "of them read history past the ring")
+            f"of them read history past the ring, {crossing / launches:,.0f} "
+            "copy chunks a launch crossed an output word's boundary")
         out.append("")
 
     top = order[0]

@@ -9,6 +9,7 @@ Reference behavior: htsjdk BlockCompressedInputStream + zlib Inflater
 (SURVEY.md §2.8 row 1).
 """
 
+import functools
 import os
 import zlib
 
@@ -398,9 +399,10 @@ def flat_lens(symbols, alphabet):
 
 def raw_launch(payloads, cw=128, ow=64):
     """One launch of the kernel itself: (each lane's output bytes, the
-    (4, 128) meta rows: outpos, status, supersteps). One geometry (512
-    compressed bytes in, 256 out) for all the small streams, so the
-    interpreter traces the kernel for them once."""
+    (5, 128) meta rows: outpos, status, supersteps, far supersteps,
+    each lane's crossing chunks). One geometry (512 compressed bytes
+    in, 256 out) for all the small streams, so the interpreter traces
+    the kernel for them once."""
     import jax.numpy as jnp
 
     from disq_tpu.ops import inflate_simd as S
@@ -559,17 +561,25 @@ class TestFusedMatchFaults:
                                   interpret=True)
 
 
-def fused_supersteps(tokens):
-    """Supersteps the fused schedule takes for one fixed-Huffman block:
-    the header, a step a literal (one for two where both fit the output
-    word), a step a copy chunk (the match's length and distance ride on
-    its first), the end-of-block."""
-    steps, outpos, i = 1, 0, 0
+def chunk_bytes(off, dist):
+    """The most a copy chunk takes from byte ``off`` of an output word:
+    to the end of the fourth output word for a distance of 16 or more,
+    of the second from 8, of the first below."""
+    return (16 if dist >= 16 else 8 if dist >= 8 else 4) - off
+
+
+def fused_schedule(tokens):
+    """(supersteps, crossing chunks) of the schedule for one
+    fixed-Huffman block: the header, a step a literal (one for two, at
+    every output offset), a step a copy chunk (the match's length and
+    distance ride on its first), the end-of-block. A chunk starts at
+    the output's byte offset and takes ``chunk_bytes``; it crosses when
+    it starts inside a word and ends in a later one."""
+    steps, crossing, outpos, i = 1, 0, 0, 0
     while i < len(tokens):
         t = tokens[i]
         if isinstance(t, int):
-            pair = (i + 1 < len(tokens) and isinstance(tokens[i + 1], int)
-                    and outpos & 3 <= 2)
+            pair = i + 1 < len(tokens) and isinstance(tokens[i + 1], int)
             i += 2 if pair else 1
             outpos += 2 if pair else 1
             steps += 1
@@ -577,14 +587,17 @@ def fused_supersteps(tokens):
         length, dist = t
         while length:
             off = outpos & 3
-            k = 16 if off == 0 and dist >= 16 else \
-                8 if off == 0 and dist >= 8 else 4 - off
-            k = min(k, length)
+            k = min(chunk_bytes(off, dist), length)
+            crossing += off != 0 and k > 4 - off
             length -= k
             outpos += k
             steps += 1
         i += 1
-    return steps + 1
+    return steps + 1, crossing
+
+
+def fused_supersteps(tokens):
+    return fused_schedule(tokens)[0]
 
 
 class TestSchedulePin:
@@ -599,7 +612,207 @@ class TestSchedulePin:
         outs, meta = raw_launch([payload])
         assert meta[1, 0] == 0
         assert outs[0] == zlib.decompress(payload, -15)
-        assert meta[2, 0] <= fused_supersteps(tokens)
+        assert meta[2, 0] == fused_supersteps(tokens)
+
+    @pytest.mark.parametrize("length,dist,n,chunks", [
+        (13, 16, 18, 1), (10, 40, 24, 1), (7, 8, 30, 1), (5, 9, 40, 1),
+        (16, 16, 15, 2), (3, 4, 60, 2), (33, 17, 7, 3)])
+    def test_a_match_is_one_chunk_wherever_it_starts(
+            self, length, dist, n, chunks):
+        # a literal between the matches walks their start over every
+        # offset of the output word; clipped at the word's boundary (the
+        # schedule before) each match that starts inside a word would
+        # take a step more. ``chunks``: the most a match may take.
+        tokens = list(bytes(range(65, 65 + dist)))
+        for i in range(n):
+            tokens += [97 + i % 26, (length, dist)]
+        payload = _fixed(tokens)
+        outs, meta = raw_launch([payload], ow=256)
+        steps, crossing = fused_schedule(tokens)
+        assert meta[1, 0] == 0
+        assert outs[0] == zlib.decompress(payload, -15)
+        assert (meta[2, 0], meta[4, 0]) == (steps, crossing)
+        # header, the lead's pairs, end-of-block; then a literal and
+        # the match's chunks a round
+        assert steps <= 2 + (dist + 1) // 2 + n * (1 + chunks)
+        assert (crossing > 0) == (dist >= 8)
+
+    @pytest.mark.parametrize("off", range(4))
+    def test_a_literal_pair_at_every_offset(self, off):
+        # 'a' and a run of it leave the output at byte ``off`` of a
+        # word; the 24 literals then go out as 12 pairs, the pairs at
+        # off 3 with their second byte in the next word
+        tokens = [97, (3 + off, 1)] + list(range(100, 124)) + [(5, 8)]
+        payload = _fixed(tokens)
+        outs, meta = raw_launch([payload])
+        assert meta[1, 0] == 0
+        assert outs[0] == zlib.decompress(payload, -15)
+        # header, 'a', the run (d 1: to the word's end, then the rest),
+        # 12 pairs, the match, the end-of-block
+        run = 1 if off == 0 else 2
+        assert meta[2, 0] == fused_supersteps(tokens) == 16 + run
+
+
+# ---- a chunk at any offset: the emit merge places it ----------------
+
+_ANY_HEAD = 20480
+
+
+@functools.lru_cache(maxsize=None)
+def any_head_tokens(nbytes):
+    """``nbytes`` of output as fixed-Huffman tokens: 64 literals, then
+    rounds of 4 fresh literals and a 249-byte match from 64 back. A
+    round is 253 bytes, so the fresh bytes fall on a new phase of the
+    64-byte pattern every round and no two stretches of the output stay
+    alike for long: a copy from the wrong place shows. Cheap to decode
+    (some 1,500 supersteps for 20 KiB) and a few hundred bytes long.
+    A tuple: the cases share it."""
+    assert nbytes >= 64
+    lits = iter(np.random.default_rng(33).integers(
+        0, 256, 64 + 4 * (nbytes // 253 + 1)).tolist())
+    tokens = [next(lits) for _ in range(64)]
+    left = nbytes - 64
+    while left >= 4 + 3:
+        take = min(249, left - 4)
+        tokens += [next(lits) for _ in range(4)] + [(take, 64)]
+        left -= 4 + take
+    return tuple(tokens + [next(lits) for _ in range(left)])
+
+
+def any_offset_tokens(head, off, length, dist):
+    """The head, ``off`` literals (the head ends on a word's boundary),
+    the match, a literal."""
+    assert head % 4 == 0 and dist <= head + off
+    return (list(any_head_tokens(head)) + list(range(200, 200 + off))
+            + [(length, dist), ord("z")])
+
+
+_ANY_DISTS = (4, 7, 8, 15, 16, 17, 4095, 4097, 20000)
+
+
+def _any_lengths(off):
+    return sorted({3, 4 - off, 5, 13 - off, 14 - off, 15 - off, 16 - off,
+                   17 - off, 17, 258} - {1, 2})
+
+
+# (head bytes, off, length, distance): every offset inside a word x the
+# distances round the chunk rule's steps (4 | 8 | 16), round the ring's
+# reach (RING_SAFE 4,088) and far past it x lengths round each chunk's
+# end; then chunks that cross from the ring's last row into its first
+# (output word 1,023 -> 1,024) and from an out slab into the next (word
+# 2,047 -> 2,048: the slab of this geometry is 2,048 rows), near and far
+_ANY_CASES = (
+    [(_ANY_HEAD, off, length, d) for off in (1, 2, 3) for d in _ANY_DISTS
+     for length in _any_lengths(off)]
+    + [(4092, off, 16 - off, d) for off in (1, 2, 3) for d in (16, 4090)]
+    + [(8188, off, 16 - off, d) for off in (1, 2, 3) for d in (16, 8000)]
+    + [(8188, 2, 6, 9), (4092, 3, 5, 8)]
+)
+_ANY_GEOMETRY = (256, 8192)     # cw, ow: 1 KiB in, 32 KiB out a lane
+
+
+@pytest.fixture(scope="module")
+def any_offset_launches():
+    """Every case's (tokens, payload, output, meta column, the model's
+    supersteps of its launch, the model's crossing chunks of its lane):
+    128 lanes a launch, one geometry."""
+    done = []
+    for lo in range(0, len(_ANY_CASES), 128):
+        tokens = [any_offset_tokens(*c) for c in _ANY_CASES[lo: lo + 128]]
+        payloads = [_fixed(t) for t in tokens]
+        outs, meta = raw_launch(payloads, *_ANY_GEOMETRY)
+        model = [fused_schedule(t) for t in tokens]
+        steps = max(s for s, _c in model)
+        done += [(t, p, o, meta[:, i], steps, model[i][1])
+                 for i, (t, p, o) in enumerate(zip(tokens, payloads, outs))]
+    return done
+
+
+class TestChunkAtAnyOffset:
+    def test_the_cases_cover_the_rule(self):
+        from disq_tpu.ops.inflate_simd import RING_SAFE, RING_W, _SLAB
+
+        cw, ow = _ANY_GEOMETRY
+        assert cw + ow < 20480 and _SLAB == 2048 < ow  # _compiled's slab
+        assert RING_W == 1024 and 4095 > RING_SAFE
+        assert max(len(_fixed(any_offset_tokens(*c))) for c in _ANY_CASES) \
+            <= cw * 4 - 16
+        crossing = [c for c in _ANY_CASES
+                    if c[2] > 4 - c[1] and c[3] >= 8]
+        assert {(c[0] + c[1]) >> 2 for c in crossing} >= {1023, 2047, 5120}
+        for off in (1, 2, 3):
+            assert {16 - off, 17 - off} <= set(_any_lengths(off))
+
+    @pytest.mark.parametrize(
+        "lane", range(len(_ANY_CASES)),
+        ids=[f"at{h + o}-len{n}-d{d}" for h, o, n, d in _ANY_CASES])
+    def test_lane_equals_zlib_in_the_models_steps(
+            self, any_offset_launches, lane):
+        tokens, payload, out, meta, steps, crossing = \
+            any_offset_launches[lane]
+        head, off, length, dist = _ANY_CASES[lane]
+        want = zlib.decompress(payload, -15)
+        at = head + off
+        assert len(want) == at + length + 1
+        assert want[at: at + min(length, dist)] == \
+            want[at - dist: at - dist + min(length, dist)]
+        assert meta[1] == 0 and meta[0] == len(want)
+        assert out == want
+        # the launch lasts as long as its slowest lane; each lane's
+        # crossing chunks are its own
+        assert (meta[2], meta[4]) == (steps, crossing)
+        # the case's own match: its first chunk crosses whenever it
+        # outruns the word it starts in and the distance allows
+        crossed = min(chunk_bytes(off, dist), length) > 4 - off
+        assert crossing - fused_schedule(tokens[:-2])[1] == crossed
+
+
+def _crossing_payloads():
+    """Two lanes with crossing chunks in them and one of literals."""
+    tokens = [any_offset_tokens(64, 1, 13, 20),
+              any_offset_tokens(600, 3, 40, 64),
+              list(range(60, 120))]
+    return tokens, [_fixed(t) for t in tokens]
+
+
+class TestCrossingChunkCounter:
+    @pytest.mark.parametrize("route", ["direct", "service"])
+    def test_crossing_chunks_booked_once_a_launch(self, route):
+        from disq_tpu.runtime.tracing import REGISTRY, telemetry_snapshot
+
+        tokens, payloads = _crossing_payloads()
+        raws = [zlib.decompress(p, -15) for p in payloads]
+        want = sum(fused_schedule(t)[1] for t in tokens)
+        assert want > 2 and fused_schedule(tokens[2])[1] == 0
+        crossing = REGISTRY.counter("device.inflate.crossing_chunks")
+        launches = REGISTRY.counter("device.kernel_launches")
+        base = crossing.total(), launches.value(kernel="inflate_simd")
+        got = inflate_by(route, payloads, [len(r) for r in raws])
+        assert got == raws
+        assert launches.value(kernel="inflate_simd") - base[1] == 1
+        assert crossing.total() - base[0] == want
+        assert last_inflate_d2h_labels()["crossing_chunks"] == want
+        assert ("device.inflate.crossing_chunks"
+                in telemetry_snapshot()["counters"])
+
+    def test_a_launch_without_a_match_books_none(self):
+        from disq_tpu.runtime.tracing import REGISTRY
+
+        raws = [random_bytes(200), b"only literals here", b""]
+        payloads = [deflate_fixed(raws[0]), _fixed(list(raws[1])),
+                    deflate_stored(raws[2])]
+        crossing = REGISTRY.counter("device.inflate.crossing_chunks")
+        base = crossing.total()
+        assert inflate_by("direct", payloads, [len(r) for r in raws]) == raws
+        assert crossing.total() == base
+        assert last_inflate_d2h_labels()["crossing_chunks"] == 0
+
+    def test_meta_row_4_is_a_count_a_lane(self):
+        tokens, payloads = _crossing_payloads()
+        _outs, meta = raw_launch(payloads, cw=256, ow=256)
+        assert meta.shape == (5, 128)
+        assert meta[4, :3].tolist() == [fused_schedule(t)[1] for t in tokens]
+        assert not meta[4, 3:].any()
 
 
 # ---- the copy phase's history reads: aligned 8-word tiles ----------
@@ -783,6 +996,15 @@ def inflate_by(route, payloads, usizes):
             for i in range(len(payloads))]
 
 
+def last_inflate_d2h_labels():
+    """The labels of the newest ``device.launch.d2h`` span of an
+    inflate launch: where ``_fetch_chunk`` books a launch's counts."""
+    from disq_tpu.runtime.tracing import spans
+
+    return [s for s in spans() if s["name"] == "device.launch.d2h"
+            and s["labels"].get("kind") == "inflate"][-1]["labels"]
+
+
 def _far_tokens(dist):
     """Literals, then one match from ``dist`` back: past the ring for
     ``dist`` > 4,088."""
@@ -806,8 +1028,7 @@ class TestFarSuperstepCount:
 
     @pytest.mark.parametrize("route", ["direct", "service"])
     def test_far_supersteps_booked_once_a_launch(self, route):
-        from disq_tpu.runtime.tracing import (
-            REGISTRY, spans, telemetry_snapshot)
+        from disq_tpu.runtime.tracing import REGISTRY, telemetry_snapshot
 
         payloads = [_fixed(_far_tokens(4089)), _fixed(_far_tokens(64))]
         raws = [zlib.decompress(p, -15) for p in payloads]
@@ -818,10 +1039,9 @@ class TestFarSuperstepCount:
         got = inflate_by(route, payloads, usizes)
         assert got == raws
         assert far.total() - base[0] == 3
-        booked = [s for s in spans() if s["name"] == "device.launch.d2h"
-                  and s["labels"].get("kind") == "inflate"]
-        assert booked[-1]["labels"]["far_supersteps"] == 3
-        assert booked[-1]["labels"]["supersteps"] == steps.total() - base[1]
+        assert last_inflate_d2h_labels()["far_supersteps"] == 3
+        assert (last_inflate_d2h_labels()["supersteps"]
+                == steps.total() - base[1])
         assert ("device.inflate.far_supersteps"
                 in telemetry_snapshot()["counters"])
 
@@ -829,8 +1049,7 @@ class TestFarSuperstepCount:
 class TestSuperstepCounter:
     @pytest.mark.parametrize("route", ["direct", "service"])
     def test_supersteps_booked_once_a_launch(self, route):
-        from disq_tpu.runtime.tracing import (
-            REGISTRY, spans, telemetry_snapshot)
+        from disq_tpu.runtime.tracing import REGISTRY, telemetry_snapshot
 
         raws = [text_like(150 + 17 * i) for i in range(5)]
         payloads = [deflate(r) for r in raws]
@@ -843,7 +1062,5 @@ class TestSuperstepCounter:
         assert got == raws
         assert launches.value(kernel="inflate_simd") - base[1] == 1
         assert steps.total() - base[0] == meta[2, 0] > 0
-        booked = [s for s in spans() if s["name"] == "device.launch.d2h"
-                  and s["labels"].get("kind") == "inflate"]
-        assert booked[-1]["labels"]["supersteps"] == meta[2, 0]
+        assert last_inflate_d2h_labels()["supersteps"] == meta[2, 0]
         assert "device.inflate.supersteps" in telemetry_snapshot()["counters"]

@@ -72,7 +72,8 @@ def test_device_kernels_on_chip(tmp_path):
     assert rows["inflate_simd"]["mb_per_sec"] > 1.0
     assert rows["rans_order0_simd"]["correct"]
     # a launch's two factors on every kernel-only inflate row, and on
-    # the benchmark's bytes the share of supersteps past the ring
+    # the benchmark's bytes the share of supersteps past the ring and
+    # the copy chunks that crossed an output word's boundary
     for kernel in ("inflate_simd_kernel_only",
                    "inflate_simd_literal_heavy_kernel_only",
                    "inflate_simd_wgs30x_kernel_only"):
@@ -80,6 +81,7 @@ def test_device_kernels_on_chip(tmp_path):
         assert rows[kernel]["us_per_superstep"] > 0
     assert 0 < rows["inflate_simd_wgs30x_kernel_only"][
         "far_superstep_share"] <= 1
+    assert rows["inflate_simd_wgs30x_kernel_only"]["crossing_chunks"] > 0
     # refresh the repo-root artifact for the judge
     with open(os.path.join(REPO, "TPU_KERNELS.json"), "w") as f:
         json.dump(artifact, f, indent=1)

@@ -141,6 +141,24 @@ class TestCriticalPath:
         ]
 
 
+def _supersteps_line(supersteps, **labelled):
+    """The analyzer's ``inflate_supersteps`` line for inflate launches
+    of these superstep counts; ``labelled`` maps further d2h labels to
+    a value a launch (None: a log from before the label)."""
+    spans = []
+    for i, steps in enumerate(supersteps):
+        labels = {k: v[i] for k, v in labelled.items() if v is not None}
+        spans += [
+            _span("device.launch.wait", 2.0 * i, 0.2,
+                  kind="inflate", launch=i),
+            _span("device.launch.d2h", 2.0 * i + 0.2, 0.01, kind="inflate",
+                  launch=i, supersteps=steps, **labels),
+        ]
+    return next(ln for ln in trace_report.analyze(
+        spans, "r1", ["r1"]).splitlines()
+        if ln.startswith("inflate_supersteps"))
+
+
 class TestVerdict:
     def test_analyze_report_golden(self):
         out = trace_report.analyze(SPANS, "r1", ["r1"])
@@ -188,21 +206,26 @@ class TestVerdict:
         """The share of supersteps that paid the far sweeps, from the
         d2h spans' ``far_supersteps`` label (meta row 3); a log from
         before the label reads 0."""
-        spans = []
-        for i, steps in enumerate((18000, 17000)):
-            labels = {"kind": "inflate", "launch": i, "supersteps": steps}
-            if far is not None:
-                labels["far_supersteps"] = far[i]
-            spans += [
-                _span("device.launch.wait", 2.0 * i, 0.2,
-                      kind="inflate", launch=i),
-                _span("device.launch.d2h", 2.0 * i + 0.2, 0.01, **labels),
-            ]
-        line = next(ln for ln in trace_report.analyze(
-            spans, "r1", ["r1"]).splitlines()
-            if ln.startswith("inflate_supersteps"))
+        line = _supersteps_line((18000, 17000), far_supersteps=far)
         assert "17,500 a launch over 2 launches" in line
-        assert line.endswith(want)
+        assert want in line
+
+    @pytest.mark.parametrize("crossing,want", [
+        ((460000, 440000), "450,000 copy chunks a launch crossed"),
+        ((0, 0), " 0 copy chunks a launch crossed"),
+        (None, " 0 copy chunks a launch crossed"),
+    ], ids=["crossing", "no-match", "label-absent"])
+    def test_inflate_crossing_chunks_beside_the_far_share(
+            self, crossing, want):
+        """How often a copy chunk ran past the output word it started
+        in, from the d2h spans' ``crossing_chunks`` label (meta row 4
+        summed over the lanes); a log from before the label reads 0."""
+        line = _supersteps_line((15000, 14000), far_supersteps=(7500, 7000),
+                                crossing_chunks=crossing)
+        assert "14,500 a launch over 2 launches" in line
+        assert "50.0% of them read history past the ring" in line
+        assert want in line
+        assert line.endswith("an output word's boundary")
 
     def test_no_spans(self):
         assert "no spans" in trace_report.analyze([], None, [])
