@@ -72,7 +72,6 @@ class BamSource:
         from disq_tpu.runtime import (
             check_read_batch,
             debug_enabled,
-            reduce_counters,
             trace_phase,
         )
 
@@ -84,20 +83,18 @@ class BamSource:
             header, first_voffset = ctx.retrier.call(
                 read_header, fs, path, what="header")
         if traversal is not None:
+            from disq_tpu.runtime.columnar import concat_batches
             from disq_tpu.traversal.bai_query import read_with_traversal
 
-            # Index-driven reads retry transient faults whole-phase (the
-            # read is bounded by the queried intervals); corrupt blocks
-            # inside the traversal always raise, regardless of policy.
+            # Index-driven reads run their chunk runs through the shard
+            # executor like splits: transient faults retry per shard,
+            # and the kept shards concatenate as a whole-file read's do
+            # (device-backed when every one of them is).
             with trace_phase("bam.read.traversal"):
-                batch = ctx.retrier.call(
-                    read_with_traversal, fs, path, header, traversal, self,
-                    what="traversal",
-                )
-            counters = reduce_counters([])
-            counters.retried_reads += ctx.retrier.retried
+                batch = concat_batches(read_with_traversal(
+                    fs, path, header, traversal, self, ctx))
             return ReadsDataset(header=header, reads=batch,
-                                counters=counters)
+                                counters=self._reduced_counters(ctx))
         with trace_phase("bam.read.splits"):
             from disq_tpu.runtime.columnar import concat_batches
 
@@ -110,12 +107,18 @@ class BamSource:
             batch = concat_batches(batches)
         if debug_enabled():
             check_read_batch(batch, n_ref=header.n_ref)
+        return ReadsDataset(header=header, reads=batch,
+                            counters=self._reduced_counters(ctx))
+
+    def _reduced_counters(self, ctx):
+        from disq_tpu.runtime import reduce_counters
+
         counters = reduce_counters(self._last_counters)
         # Header/boundary-phase retries happened outside any shard.
         counters.retried_reads += ctx.retrier.retried
         counters.skipped_blocks += ctx.skipped_blocks
         counters.quarantined_blocks += ctx.quarantined_blocks
-        return ReadsDataset(header=header, reads=batch, counters=counters)
+        return counters
 
     # -- split machinery ----------------------------------------------------
 
@@ -142,20 +145,11 @@ class BamSource:
         byte-identical to the sequential path."""
         import functools
 
-        from disq_tpu.runtime import ShardCounters, ShardTask
-        from disq_tpu.runtime.errors import (
-            DisqOptions,
-            context_for_storage,
-            deadline_fallback_for,
-        )
-        from disq_tpu.runtime.executor import (
-            executor_for_storage,
-            read_ledger_for_storage,
-        )
+        from disq_tpu.runtime.errors import context_for_storage
+        from disq_tpu.runtime.executor import read_ledger_for_storage
 
         if ctx is None:
             ctx = context_for_storage(self._storage, path)
-        opts = getattr(self._storage, "_options", None) or DisqOptions()
         splits = compute_path_splits(fs, path, split_size or self.split_size)
         sbi = ctx.retrier.call(self._try_load_sbi, fs, path, what="sbi")
         boundaries = self._split_boundaries(
@@ -167,29 +161,76 @@ class BamSource:
             lo, hi = boundaries[i], boundaries[i + 1]
             shard_ctx = ctx.for_shard(i)
             shard_ctxs.append(shard_ctx)
-            tasks.append(ShardTask(
-                shard_id=i,
-                fetch=functools.partial(
+            tasks.append(self._shard_task(
+                shard_ctx,
+                functools.partial(
                     self._fetch_range, fs, path, lo, hi, shard_ctx),
-                decode=functools.partial(
+                functools.partial(
                     self._decode_fetched, header, ctx=shard_ctx),
-                retrier=shard_ctx.retrier,
-                what=f"shard{i}",
-                # Deadline escalation terminal under skip/quarantine:
-                # an over-budget shard is set aside as one empty batch.
-                deadline_fallback=deadline_fallback_for(
-                    opts, shard_ctx,
-                    lambda: (ReadBatch.empty(), (0, 0, 0))),
                 # Compressed byte window (coffsets) — the scheduler's
                 # locality coordinate.
-                byte_range=(lo >> 16, (hi >> 16) + 1),
-            ))
+                (lo >> 16, (hi >> 16) + 1)))
+        return self._run_shard_tasks(
+            fs, path, tasks, shard_ctxs,
+            read_ledger_for_storage(self._storage, path, len(tasks)))
+
+    def read_chunk_runs(self, fs, path, header, runs, keep, ctx) -> list:
+        """One batch per run of virtual-offset chunks — an indexed
+        read's shards, through the same executor stages as splits:
+        ``runs`` holds ``((k, 2)`` chunk array, tag) pairs in file
+        order, and ``keep(batch, tag)`` is applied to each decoded run
+        inside its decode stage, so that what it drops is gone before
+        the shard is emitted.  The blocks of a run's chunks are one
+        batch to the inflater."""
+        import functools
+
+        def decode(shard_ctx, tag, fetched):
+            batch, stats = self._decode_fetched(
+                header, fetched, ctx=shard_ctx)
+            return keep(batch, tag), stats
+
+        tasks = []
+        shard_ctxs = []
+        for i, (chunks, tag) in enumerate(runs):
+            shard_ctx = ctx.for_shard(i)
+            shard_ctxs.append(shard_ctx)
+            tasks.append(self._shard_task(
+                shard_ctx,
+                functools.partial(
+                    self._fetch_chunks, fs, path, chunks, shard_ctx),
+                functools.partial(decode, shard_ctx, tag),
+                (int(chunks[0, 0]) >> 16, (int(chunks[-1, 1]) >> 16) + 1)))
+        return self._run_shard_tasks(fs, path, tasks, shard_ctxs, None)
+
+    def _shard_task(self, shard_ctx, fetch, decode, byte_range):
+        from disq_tpu.runtime import ShardTask
+        from disq_tpu.runtime.errors import DisqOptions, deadline_fallback_for
+
+        opts = getattr(self._storage, "_options", None) or DisqOptions()
+        return ShardTask(
+            shard_id=shard_ctx.shard_id,
+            fetch=fetch,
+            decode=decode,
+            retrier=shard_ctx.retrier,
+            what=f"shard{shard_ctx.shard_id}",
+            # Deadline escalation terminal under skip/quarantine:
+            # an over-budget shard is set aside as one empty batch.
+            deadline_fallback=deadline_fallback_for(
+                opts, shard_ctx,
+                lambda: (ReadBatch.empty(), (0, 0, 0))),
+            byte_range=byte_range,
+        )
+
+    def _run_shard_tasks(self, fs, path, tasks, shard_ctxs, ledger) -> list:
+        """The tasks through the shard executor; the emitted batches in
+        shard order, their counters on ``_last_counters``."""
+        from disq_tpu.runtime import ShardCounters
+        from disq_tpu.runtime.executor import executor_for_storage
         from disq_tpu.runtime.introspect import note_shard_counters
         from disq_tpu.runtime.scheduler import scheduled_map_ordered
 
         out = []
         self._last_counters = []
-        ledger = read_ledger_for_storage(self._storage, path, len(tasks))
         # scheduler off (default): scheduled_map_ordered IS
         # map_ordered_resumable; on: this process leases shards from
         # the shared cross-host queue and emits only the ones it wins.
@@ -405,41 +446,6 @@ class BamSource:
             i = j + 1
         return None
 
-    def _decode_range(
-        self,
-        fs: FileSystemWrapper,
-        path: str,
-        header: SamHeader,
-        lo_voffset: int,
-        hi_voffset: int,
-    ) -> ReadBatch:
-        return self._decode_range_with_stats(
-            fs, path, header, lo_voffset, hi_voffset
-        )[0]
-
-    def _decode_range_with_stats(
-        self,
-        fs: FileSystemWrapper,
-        path: str,
-        header: SamHeader,
-        lo_voffset: int,
-        hi_voffset: int,
-        ctx=None,
-    ) -> Tuple[ReadBatch, Tuple[int, int, int]]:
-        """Decode all records whose start lies in [lo, hi) virtual space
-        — the sequential fetch+decode composition; the executor runs the
-        same two stages (``_fetch_range`` → ``_decode_fetched``) on
-        separate pools."""
-        from disq_tpu.runtime.errors import ErrorPolicy, ShardErrorContext
-
-        if ctx is None:
-            ctx = ShardErrorContext(policy=ErrorPolicy.STRICT, path=path)
-        return self._decode_fetched(
-            header,
-            self._fetch_range(fs, path, lo_voffset, hi_voffset, ctx),
-            ctx=ctx,
-        )
-
     def _fetch_range(
         self,
         fs: FileSystemWrapper,
@@ -458,6 +464,28 @@ class BamSource:
                   lo=lo_voffset, hi=hi_voffset, path=path):
             return self._fetch_range_inner(
                 fs, path, lo_voffset, hi_voffset, ctx)
+
+    def _fetch_chunks(self, fs: FileSystemWrapper, path: str,
+                      chunks: np.ndarray, ctx) -> list:
+        """Stage A of a run of virtual-offset chunks (an indexed read's
+        shard): each chunk's blocks range-read and walked on their own,
+        so that the blocks between two chunks are neither read nor
+        decoded.  One ``bam.split.fetch`` span a run."""
+        from disq_tpu.runtime.tracing import span
+
+        with span("bam.split.fetch", shard=ctx.shard_id,
+                  lo=int(chunks[0, 0]), hi=int(chunks[-1, 1]), path=path,
+                  chunks=len(chunks)):
+            fetched = []
+            skipped = quarantined = 0
+            for lo, hi in chunks.tolist():
+                fetched.append(
+                    self._fetch_range_inner(fs, path, lo, hi, ctx))
+                # each walk starts its counts anew: the run's are the sum
+                skipped += ctx.skipped_blocks
+                quarantined += ctx.quarantined_blocks
+            ctx.skipped_blocks, ctx.quarantined_blocks = skipped, quarantined
+            return fetched
 
     def _fetch_range_inner(
         self,
@@ -525,7 +553,9 @@ class BamSource:
         from disq_tpu.runtime.tracing import span
 
         with span("bam.split.decode", shard=ctx.shard_id):
-            batch, stats = self._decode_fetched_inner(header, fetched, ctx)
+            inner = (self._decode_chunks if isinstance(fetched, list)
+                     else self._decode_fetched_inner)
+            batch, stats = inner(header, fetched, ctx)
             rf = self._read_filter()
             if rf is not None and batch.count:
                 from disq_tpu.ops.rfilter import apply_read_filter
@@ -693,6 +723,87 @@ class BamSource:
             except ValueError:
                 batch = ReadBatch.empty()
         return batch, stats
+
+    def _decode_chunks(
+        self,
+        header: SamHeader,
+        fetched: list,
+        ctx,
+    ) -> Tuple[ReadBatch, Tuple[int, int, int]]:
+        """Stage B of a run of chunks (``_fetch_chunks``): every block
+        of every chunk in ONE batch to the inflater — with the decode
+        service on, one submission, so that blocks of several chunks
+        share launches — then each chunk's records cut out at its
+        virtual offsets and all of them parsed as one batch.  The stats
+        count every block: chunks share none (a chunk that begins in
+        the block another ends in was merged into it by the index
+        query).
+
+        Anything out of the ordinary (a corrupt-header gap from the
+        salvage walk, a block that fails to inflate, damaged record
+        framing) goes chunk by chunk through ``_decode_fetched_inner``,
+        which applies the error policy with the block's coordinates."""
+        from disq_tpu.runtime.columnar import (
+            concat_batches, resident_decode_enabled)
+
+        parts = [f for f in fetched if f is not None and f[0]]
+        every = [b for f in parts for b in f[0]]
+        stats = (len(every), sum(b.csize for b in every),
+                 sum(b.usize for b in every))
+
+        def apart():
+            return concat_batches([
+                self._decode_fetched_inner(header, f, ctx)[0]
+                for f in parts]), stats
+
+        if not parts:
+            return ReadBatch.empty(), stats
+        if any(f[2] for f in parts):
+            return apart()
+        resident = (resident_decode_enabled(self._storage)
+                    and stats[2] < 2 ** 31)
+        # the chunks' compressed bytes end to end, their blocks rebased
+        # onto the joined buffer
+        joined, at = [], 0
+        for blocks, data, _gaps, lo, _hi in parts:
+            joined += [BgzfBlock(pos=b.pos - (lo >> 16) + at,
+                                 csize=b.csize, usize=b.usize)
+                       for b in blocks]
+            at += len(data)
+        try:
+            blob = inflate_blocks(
+                b"".join(f[1] for f in parts), joined, base=0,
+                as_array=True)
+            segments, offsets, at, total = [], [], 0, 0
+            for blocks, _data, _gaps, lo, hi in parts:
+                hi_block, hi_u = hi >> 16, hi & 0xFFFF
+                size = sum(b.usize for b in blocks)
+                end_u = size if hi_u == 0 else hi_u + sum(
+                    b.usize for b in blocks if b.pos < hi_block)
+                seg = blob[at + (lo & 0xFFFF): at + end_u]
+                at += size
+                if len(seg):
+                    offsets.append(scan_record_offsets(seg)[:-1] + total)
+                    segments.append(seg)
+                    total += len(seg)
+            offsets.append(np.array([total], np.int64))
+            offsets = np.concatenate(offsets)
+            record_bytes = (np.concatenate(segments) if segments
+                            else blob[:0])
+            if resident:
+                from disq_tpu.runtime.columnar import ColumnarBatch
+                from disq_tpu.runtime.mesh import mesh_for_storage
+
+                # a run's decoded size follows where its chunks' blocks
+                # fall: coarse upload shapes, or every run compiles
+                return ColumnarBatch.from_blob(
+                    record_bytes, offsets, n_ref=header.n_ref,
+                    mesh=mesh_for_storage(self._storage),
+                    coarse=True), stats
+            return decode_records(
+                record_bytes, offsets, n_ref=header.n_ref), stats
+        except ValueError:
+            return apart()
 
     def _decode_runs(
         self,

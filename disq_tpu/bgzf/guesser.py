@@ -174,7 +174,10 @@ def _walk_blocks_collect(
     parts: List[bytes] = []
     pos = first
     while pos < end and pos < file_length:
-        want = min(max(chunk, 2 * BGZF_MAX_BLOCK_SIZE), file_length - pos)
+        # no further than the last wanted block can reach: an indexed
+        # read's chunks are tens of blocks, not the staging chunk
+        want = min(max(chunk, 2 * BGZF_MAX_BLOCK_SIZE),
+                   end - pos + BGZF_MAX_BLOCK_SIZE, file_length - pos)
         buf = fs.read_range(path, pos, want)
         entries, consumed = _walk_buffer(buf, min(end - pos, len(buf)))
         if not entries:

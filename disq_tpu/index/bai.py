@@ -72,15 +72,6 @@ def bins_from_cigars(cigars_f, cigar_offsets, pos) -> np.ndarray:
     return reg2bin(beg, beg + np.maximum(span, 1))
 
 
-def reg2bins(beg: int, end: int) -> List[int]:
-    """All bins overlapping [beg, end) — the query-side companion."""
-    end -= 1
-    bins = [0]
-    for shift, offset in ((26, 1), (23, 9), (20, 73), (17, 585), (14, 4681)):
-        bins.extend(range(offset + (beg >> shift), offset + (end >> shift) + 1))
-    return bins
-
-
 @dataclass
 class RefIndex:
     bins: Dict[int, List[Tuple[int, int]]] = field(default_factory=dict)
@@ -90,6 +81,50 @@ class RefIndex:
     ref_end: int = 0
     n_mapped: int = 0
     n_unmapped: int = 0
+
+    def flat(self):
+        """The bins as arrays for the batched query: ``(bin ids
+        ascending, (n_bins + 1,) offsets into the chunk arrays, chunk
+        begins, chunk ends)``, i64 each.  Built on the first query and
+        kept: an index that has answered a query is not edited."""
+        flat = self.__dict__.get("_flat")
+        if flat is None:
+            ids = np.fromiter(sorted(self.bins), np.int64, len(self.bins))
+            lo = np.zeros(len(ids) + 1, np.int64)
+            np.cumsum([len(self.bins[int(b)]) for b in ids], out=lo[1:])
+            pairs = np.array(
+                [c for b in ids for c in self.bins[int(b)]],
+                dtype=np.uint64).reshape(-1, 2).astype(np.int64)
+            flat = self.__dict__["_flat"] = (
+                ids, lo, pairs[:, 0].copy(), pairs[:, 1].copy())
+        return flat
+
+
+def expand_ranges(lo: np.ndarray, hi: np.ndarray):
+    """Every index of the half-open ranges ``[lo[i], hi[i])`` in turn,
+    and the range each came from."""
+    counts = np.maximum(hi - lo, 0)
+    owner = np.repeat(np.arange(len(lo)), counts)
+    first = np.cumsum(counts) - counts
+    return lo[owner] + np.arange(len(owner)) - first[owner], owner
+
+
+def coalesce_chunks(cb: np.ndarray, ce: np.ndarray) -> np.ndarray:
+    """``(k, 2)`` i64 chunks in file order, merged where the next begins
+    in or before the compressed block the run so far ends in — never
+    across a gap of whole blocks, so no block is read that no chunk
+    touches."""
+    order = np.argsort(cb, kind="stable")
+    cb, ce = cb[order], np.maximum.accumulate(ce[order])
+    first = np.ones(len(cb), bool)
+    first[1:] = (cb[1:] >> 16) > (ce[:-1] >> 16)
+    starts = np.flatnonzero(first)
+    return np.stack(
+        [cb[starts], ce[np.append(starts[1:], len(cb)) - 1]], axis=1)
+
+
+# the five binning levels under bin 0: (shift, first bin id)
+_LEVELS = ((29, 0), (26, 1), (23, 9), (20, 73), (17, 585), (14, 4681))
 
 
 @dataclass
@@ -162,24 +197,53 @@ class BaiIndex:
     ) -> List[Tuple[int, int]]:
         """Coalesced chunk list possibly containing records overlapping
         0-based half-open [beg, end) on ``refid``."""
-        if refid < 0 or refid >= len(self.refs):
-            return []
+        return [(int(b), int(e)) for b, e in self.chunks_for_ranges(
+            refid, np.array([beg]), np.array([end]))]
+
+    def chunks_for_ranges(
+        self, refid: int, beg: np.ndarray, end: np.ndarray
+    ) -> np.ndarray:
+        """``(k, 2)`` i64 coalesced chunks, in file order, possibly
+        containing records overlapping any of the 0-based half-open
+        ``[beg[i], end[i])`` on ``refid``.  Array work throughout: the
+        bins each range touches by a sorted search a level, one floor
+        a bin (the least linear-index offset of the ranges that touch
+        it), one sort of the chunks kept."""
+        none = np.zeros((0, 2), np.int64)
+        if refid < 0 or refid >= len(self.refs) or len(beg) == 0:
+            return none
         r = self.refs[refid]
+        ids, chunk_lo, cb, ce = r.flat()
+        beg = np.maximum(np.asarray(beg, np.int64), 0)
+        last = np.maximum(np.asarray(end, np.int64) - 1, beg)
         window = beg >> LINEAR_SHIFT
-        min_off = int(r.linear[window]) if window < len(r.linear) else 0
-        chunks = []
-        for b in reg2bins(beg, end):
-            for cb, ce in r.bins.get(b, ()):
-                if ce > min_off:
-                    chunks.append((max(cb, min_off), ce))
-        chunks.sort()
-        merged: List[Tuple[int, int]] = []
-        for cb, ce in chunks:
-            if merged and cb >> 16 <= merged[-1][1] >> 16:
-                merged[-1] = (merged[-1][0], max(merged[-1][1], ce))
-            else:
-                merged.append((cb, ce))
-        return merged
+        floor = np.zeros(len(beg), np.int64)
+        inside = window < len(r.linear)
+        floor[inside] = r.linear[window[inside]].astype(np.int64)
+        hit, owner = [], []
+        for shift, base in _LEVELS:
+            idx, own = expand_ranges(
+                np.searchsorted(ids, base + (beg >> shift), "left"),
+                np.searchsorted(ids, base + (last >> shift), "right"))
+            hit.append(idx)
+            owner.append(own)
+        hit, owner = np.concatenate(hit), np.concatenate(owner)
+        if len(hit) == 0:
+            return none
+        # a bin's floor is the least of its ranges': the union of what
+        # each range alone would keep of the bin
+        order = np.lexsort((floor[owner], hit))
+        hit, bin_floor = hit[order], floor[owner][order]
+        first = np.ones(len(hit), bool)
+        first[1:] = hit[1:] != hit[:-1]
+        hit, bin_floor = hit[first], bin_floor[first]
+        idx, own = expand_ranges(chunk_lo[hit], chunk_lo[hit + 1])
+        keep = ce[idx] > bin_floor[own]
+        idx, own = idx[keep], own[keep]
+        if len(idx) == 0:
+            return none
+        return coalesce_chunks(
+            np.maximum(cb[idx], bin_floor[own]), ce[idx])
 
 
 def build_bai(

@@ -22,7 +22,6 @@ from disq_tpu.index.bai import (
     RefIndex,
     merge_bai_fragments,
     reg2bin,
-    reg2bins,
     BaiIndex,
 )
 

@@ -139,10 +139,19 @@ def window_depth(
         # the single-device dispatch below
         flat = _depth_psum(w_lo, w_hi, total_windows, mesh)
     else:
+        # bucket-padded like the batch's columns, so that the program
+        # does not follow a file's exact record count; pads scatter +1
+        # and -1 into the slot past the last window, which is cut off
+        from disq_tpu.util import bucket_pow2
+
+        pad = bucket_pow2(len(w_lo)) - len(w_lo)
         with device_span("device.kernel", kernel="depth",
                          records=len(w_lo)) as fence:
             out = fence.sync(_depth_global(
-                jnp.asarray(w_lo), jnp.asarray(w_hi),
+                jnp.asarray(np.pad(w_lo, (0, pad),
+                                   constant_values=total_windows)),
+                jnp.asarray(np.pad(w_hi, (0, pad),
+                                   constant_values=total_windows - 1)),
                 n_windows=total_windows))
         flat = np.asarray(out)
     return {

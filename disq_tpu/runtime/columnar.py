@@ -147,8 +147,28 @@ def _jax_fns():
         lo = (pos + 1).astype(jnp.uint32)
         return jnp.lexsort((lo, hi)).astype(jnp.int32)
 
+    @jax.jit
+    def place(out, part, at):
+        # every column of ``part`` written into ``out``'s from ``at``
+        # on, whole with its padding: what comes next overwrites it
+        def one(col, new):
+            wide = jnp.pad(col, (0, new.shape[0]))
+            return jax.lax.dynamic_update_slice(
+                wide, new, (at,))[: col.shape[0]]
+
+        return {name: one(out[name], part[name]) for name in out}
+
+    @jax.jit
+    def edge_fill(out, n):
+        # the tail past ``n`` duplicates the last record, as the
+        # bucket-padded parse leaves it
+        return {name: jnp.where(jnp.arange(col.shape[0]) < n, col,
+                                col[n - 1])
+                for name, col in out.items()}
+
     return {"jax": jax, "jnp": jnp, "coord_perm": coord_perm,
-            "record_check": record_check}
+            "record_check": record_check, "place": place,
+            "edge_fill": edge_fill}
 
 
 class _SpanCache:
@@ -240,6 +260,7 @@ class ColumnarBatch:
         origin: int = 0,
         interpret: Optional[bool] = None,
         mesh=None,
+        coarse: bool = False,
     ) -> "ColumnarBatch":
         """Fused device build: one upload (skipped when
         ``device_words`` carries the inflate kernels' still-resident
@@ -249,7 +270,9 @@ class ColumnarBatch:
         ``blob``/``offsets`` are the host record bytes + record-offset
         manifest (held for ragged columns and identity with the host
         parser); ``origin`` rebases the offsets into ``device_words``
-        when that blob covers more than the record range."""
+        when that blob covers more than the record range; ``coarse``
+        uploads the blob at ``parse_columns_resident``'s coarse
+        shapes."""
         from disq_tpu.runtime.device_pipeline import parse_columns_resident
         from disq_tpu.runtime.tracing import span
 
@@ -274,7 +297,7 @@ class ColumnarBatch:
             cols, _word_bytes, _ = parse_columns_resident(
                 blob, self._offsets, words_dev=device_words,
                 origin=origin if device_words is not None else 0,
-                interpret=interpret, mesh=mesh)
+                interpret=interpret, mesh=mesh, coarse=coarse)
             # keep only the 8 reachable fixed columns resident (plus
             # next_refid for validation below); the 4 parse-only
             # length fields are derivable from the ragged offsets and
@@ -730,6 +753,23 @@ class ColumnarBatch:
             for name in FIXED_COLUMNS
         }
 
+    def interval_operands(self):
+        """``(refid, pos, ends)`` as device arrays of the columns'
+        padded length, for a kernel that holds records to reference
+        spans: the two resident columns as they are, and the exclusive
+        alignment ends uploaded beside them (4 B a record; from the
+        CIGAR pass over the record bytes, which the batch keeps)."""
+        dev = self._dev_snapshot()
+        if dev is None:
+            raise ValueError("host-backed batch has no device columns")
+        from disq_tpu.runtime.tracing import count_transfer
+
+        ends = np.empty(int(dev["pos"].shape[0]), np.int32)
+        ends[: self._n] = self.alignment_ends()
+        ends[self._n:] = ends[self._n - 1]
+        count_transfer("h2d", ends.nbytes)
+        return dev["refid"], dev["pos"], _jax_fns()["jnp"].asarray(ends)
+
     def flagstat(self) -> Dict[str, int]:
         """flagstat over the resident flag column — no h2d re-upload,
         d2h is the 48-byte count row."""
@@ -883,7 +923,8 @@ class ColumnarBatch:
         resident = [b for b in batches
                     if isinstance(b, ColumnarBatch) and b.device_backed]
         if len(resident) == len(batches):
-            jnp = _jax_fns()["jnp"]
+            fns = _jax_fns()
+            jnp = fns["jnp"]
             self = cls()
             self._n = sum(b._n for b in batches)
             self._n_ref = batches[0]._n_ref
@@ -892,17 +933,30 @@ class ColumnarBatch:
             # results would retrace every downstream jit once per
             # distinct total record count
             pad = _bucket_n(self._n) - self._n
-            self._dev = {
-                name: jnp.pad(
-                    jnp.concatenate(
-                        [b._dev[name][: b._n] for b in batches]),
-                    (0, pad), mode="edge")
-                for name in FIXED_COLUMNS
-            }
+            mesh = batches[0]._mesh
+            if mesh is None:
+                # each shard's padded columns written whole at its
+                # offset: the programs are keyed by power-of-two
+                # lengths alone, where slices at the shards' exact
+                # counts compile anew for every file
+                cols = {name: jnp.zeros(self._n + pad, col.dtype)
+                        for name, col in batches[0]._dev.items()}
+                at = 0
+                for b in batches:
+                    cols = fns["place"](cols, b._dev, np.int32(at))
+                    at += b._n
+                self._dev = fns["edge_fill"](cols, np.int32(self._n))
+            else:
+                self._dev = {
+                    name: jnp.pad(
+                        jnp.concatenate(
+                            [b._dev[name][: b._n] for b in batches]),
+                        (0, pad), mode="edge")
+                    for name in FIXED_COLUMNS
+                }
             # mesh carriage: a concat of same-mesh shards stays one
             # sharded program (the slice/concat/pad above may have
             # collapsed placement — normalize back to batch sharding)
-            mesh = batches[0]._mesh
             if mesh is not None and all(
                     b._mesh is mesh for b in batches):
                 from disq_tpu.runtime.mesh import mesh_put

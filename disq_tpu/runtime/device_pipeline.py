@@ -49,13 +49,17 @@ import jax.numpy as jnp
 from disq_tpu.util import bucket_pow2 as _bucket
 
 
-def _pad_quantum(n: int) -> int:
+def _pad_quantum(n: int, coarse: bool = False) -> int:
     """Compile-shape quantization with bounded waste: power-of-two
     below 64K units (cheap), then 1/16-octave steps — retraces stay a
     handful per octave while zero-pad overhead is capped at ~6%
     (plain power-of-two would zero-fill and upload up to 2x the blob,
-    defeating the transfer win the resident path exists for)."""
-    if n <= 1 << 16:
+    defeating the transfer win the resident path exists for).
+    ``coarse``: the power of two throughout, for a caller that bounds
+    its sizes itself and whose many sizes would each be a shape (an
+    indexed read's chunk runs, a launch's lanes a worker each: they
+    fill the bucket they are cut to)."""
+    if coarse or n <= 1 << 16:
         return _bucket(n)
     step = 1 << max((n - 1).bit_length() - 5, 0)
     return -(-n // step) * step
@@ -282,6 +286,7 @@ def parse_columns_resident(
     origin: int = 0,
     interpret: bool = False,
     mesh=None,
+    coarse: bool = False,
 ) -> Tuple[Dict[str, jax.Array], int, int]:
     """One fused upload(+)gather(+)parse launch chain producing the raw
     device column dict (bucket-padded; callers slice to ``n``).
@@ -296,7 +301,8 @@ def parse_columns_resident(
     and HBM booked per copy — accounting stays per-device-correct),
     the bucket-padded starts shard over ``batch`` (power-of-two bucket
     sizes always divide the power-of-two axis), and the returned
-    columns are batch-sharded device arrays."""
+    columns are batch-sharded device arrays.  ``coarse`` pads the
+    uploaded blob in ``_pad_quantum``'s coarse steps."""
     from disq_tpu.runtime.tracing import (
         count_transfer, counter, device_span, span)
 
@@ -318,7 +324,7 @@ def parse_columns_resident(
         # per split, and an exact-shape upload would retrace the parse
         # jit once per shard — quantized shapes keep compiles to a
         # handful per run at <=~6% pad overhead on big shards
-        nwords = _pad_quantum(max(1, (len(blob) + 3) // 4))
+        nwords = _pad_quantum(max(1, (len(blob) + 3) // 4), coarse)
         padded = np.empty(nwords * 4, np.uint8)
         padded[: len(blob)] = blob
         padded[len(blob):] = 0

@@ -61,6 +61,9 @@ ALLOWED_PREFIXES = {
     # HBM-resident fused decode (runtime/columnar.py): ColumnarBatch
     # build/fetch/release spans and the resident-bytes gauge.
     "columnar",
+    # Indexed reads (traversal/bai_query.py): the plan and the overlap
+    # test of an interval read, and what it decoded and returned.
+    "traversal",
     # Cross-host shard scheduler (runtime/scheduler.py): queue depth,
     # lease/steal/locality accounting, membership gauge, worker RPC
     # spans.
