@@ -37,7 +37,11 @@ sublane reduction, which Mosaic lowers for any row count (whether
 ``take_along_axis`` would now lower as well has not been tried on this
 compiler), and its tile form ``_gather_tile``, which selects a lane's
 aligned 8-row tile (one stored register) instead of a lane's row: the
-same sweep, eight consecutive words a lane instead of one. Big-buffer
+same sweep, eight consecutive words a lane instead of one. The writes
+are its mirror image, ``_scatter_tile``: a superstep's up-to-four
+output words are placed once, on single registers, in two (8,128) tile
+patches, and a sweep merges the patches into a lane's two aligned
+tiles with two compares a stored register. Big-buffer
 sweeps (comp refill, output RMW, far-history reads) are additionally
 *windowed*: lanes advance in rough lockstep, so each slab's sweep is
 skipped when the live row window [min, max] misses it. Bool (1,128)
@@ -62,11 +66,15 @@ up to five consecutive history words a lane; they lie inside two
 aligned 8-word tiles, so it reads them with two tile sweeps of the
 1,024-row ring and, in the supersteps where some lane's distance
 reaches past the ring (``meta`` row 3 counts them), two windowed tile
-sweeps of the (OW,128) output that share their slab gates. Output
-writes are one-hot sweeps over the output's live slabs and the whole
-ring. Correct and Mosaic-friendly, but the sweeps scale with buffer
-size; the windowed slab gates above are what bounds them, and a gate
-is not free (PERF.md §5).
+sweeps of the (OW,128) output that share their slab gates. An emit's
+up-to-four output words lie inside two aligned tiles as well, so a
+superstep writes them as two tile patches: OR-ed into the output's live
+slabs behind the same kind of gates (the hull of the live tiles: two
+reductions), and merged under their byte masks into all of the ring
+(rows recycle, so bytes are replaced) in every superstep in which some
+lane emits. Correct and Mosaic-friendly, but the sweeps scale with
+buffer size; the windowed slab gates above are what bounds them, and a
+gate is not free (PERF.md §5).
 
 Error codes in meta row 1: 0 ok · 1 bad btype · 2 stored-LEN mismatch ·
 3 bad Huffman code · 4 invalid distance · 5 output overflow · 6 ran past
@@ -257,6 +265,16 @@ def _gather_tile(data, tiles):
     return lax.bitcast_convert_type(t, _U32) if unsigned else t
 
 
+def _tile_hull(tiles, n_tiles: int):
+    """(min, max) over the live tiles of the (1,128) index vectors
+    ``tiles``, as two scalars: the window the slab gates test. Tile -1
+    never anchors it; with no live tile the max is -1 (and the min
+    ``n_tiles``), so every gate stays shut."""
+    lo = functools.reduce(
+        jnp.minimum, [jnp.where(t < 0, jnp.int32(n_tiles), t) for t in tiles])
+    return jnp.min(lo), jnp.max(functools.reduce(jnp.maximum, tiles))
+
+
 def _gather_tiles_ref_win(ref, tiles, slab: int = _SLAB):
     """Windowed tile gather over a (possibly large) REF: one
     ``_gather_tile`` sweep for each (1,128) index vector of ``tiles``,
@@ -269,10 +287,7 @@ def _gather_tiles_ref_win(ref, tiles, slab: int = _SLAB):
     r = ref.shape[0]
     if r <= slab:
         return tuple(_gather_tile(ref[...], t) for t in tiles)
-    lo = functools.reduce(
-        jnp.minimum, [jnp.where(t < 0, jnp.int32(r // 8), t) for t in tiles])
-    tmin = jnp.min(lo)
-    tmax = jnp.max(functools.reduce(jnp.maximum, tiles))
+    tmin, tmax = _tile_hull(tiles, r // 8)
     zeros = (jnp.zeros((8, LANES), ref.dtype),) * len(tiles)
     acc = zeros
     for s in range(0, r, slab):
@@ -286,6 +301,55 @@ def _gather_tiles_ref_win(ref, tiles, slab: int = _SLAB):
             (tmax >= s // 8) & (tmin < (s + sl) // 8), hit, lambda: zeros)
         acc = tuple(a | g for a, g in zip(acc, got))
     return acc
+
+
+def _scatter_tile(data, tiles, patches, masks=None):
+    """Tile scatter, ``_gather_tile``'s mirror image: data (R,128) with
+    R a multiple of 8, ``tiles`` two (1,128) per-lane tile indices and
+    ``patches`` an (8,128) patch for each → data with lane l's tile
+    ``tiles[i][l]`` merged with ``patches[i][:, l]``, every other tile
+    as it was. Without ``masks`` the patch is OR-ed in (bytes that land
+    once in a zeroed buffer); with them (an (8,128) bit mask a patch)
+    it replaces the bits under the mask and keeps the rest. Tile -1
+    matches nothing; a lane's two tiles differ (or match nothing). The
+    sweep is two compares a stored register, whatever the patches
+    hold: a superstep's up-to-four output words are placed in their
+    two patches once, on single registers, not once a register of the
+    buffer."""
+    nt = data.shape[0] // 8
+    cur = data.reshape(nt, 8, LANES)
+    ti = lax.broadcasted_iota(_I32, (nt, 8, LANES), 0)
+    hit0, hit1 = (ti == t[None] for t in tiles)
+    if masks is None:
+        zero = jnp.zeros_like(patches[0])
+        new = cur | jnp.where(
+            hit0, patches[0], jnp.where(hit1, patches[1], zero))
+    else:
+        # both merged tiles are made on single registers' operands and
+        # then chosen: 0.07 us a superstep under choosing the mask and
+        # the patch first and merging once (PERF.md §5, PR 37)
+        new = jnp.where(
+            hit0, (cur & ~masks[0]) | patches[0],
+            jnp.where(hit1, (cur & ~masks[1]) | patches[1], cur))
+    return new.reshape(data.shape)
+
+
+def _scatter_tiles_ref_win(ref, tiles, patches, hull, slab: int = _SLAB):
+    """Windowed tile scatter into a (possibly large) zero-initialised
+    REF: ``_scatter_tile``'s OR-merge slab-wise (no full-buffer
+    temporary), behind ``_gather_tiles_ref_win``'s slab gates: a slab's
+    sweep is skipped (``pl.when``) when ``hull``, the ``_tile_hull`` of
+    ``tiles``, misses it. Two reductions for the hull, whatever the
+    patches hold; the caller makes them, and may gate more on them."""
+    r = ref.shape[0]
+    tmin, tmax = hull
+    for s in range(0, r, slab):
+        sl = min(slab, r - s)
+
+        @pl.when((tmax >= s // 8) & (tmin < (s + sl) // 8))
+        def _(s=s, sl=sl):
+            ref[s:s + sl, :] = _scatter_tile(
+                ref[s:s + sl, :], tuple(t - s // 8 for t in tiles), patches)
 
 
 def _bcast_np(arr: np.ndarray) -> np.ndarray:
@@ -880,45 +944,50 @@ def _inflate_simd_kernel(
         bits1 = placed(packed, asm2, kmask1)
         bits2 = placed(asm2, asm3, kmask2)
         bits3 = placed(asm3, asm4, kmask3)
-        # big out: bytes land exactly once, buffer starts zeroed -> OR;
-        # mask folded into the row (-1 matches nothing): pure one-hot,
-        # slab-wise to bound scoped-vmem temps, and slab-gated on the
-        # live write window (lanes advance in rough lockstep, so most
-        # supersteps touch one slab, not all eight)
+        # The four words w0r .. w0r + 3 lie inside the aligned 8-word
+        # tiles w0r >> 3 and (w0r >> 3) + 1 at every alignment, as the
+        # copy phase's five history words do: the words and their byte
+        # masks are placed once, on two registers each, in a 16-row
+        # patch at rows (w0r & 7) + j (a word past the chunk's end is
+        # zero under a zero mask, so is every word of a lane that emits
+        # nothing), and the patch's halves are merged into the big out
+        # buffer and into the ring as tiles.
         w0r = outpos >> 2
-        wrow = jnp.where(emitting, w0r, -1)
-        wrow1 = jnp.where(emitting & (k1 > 0), w0r + 1, -1)
-        wrow2 = jnp.where(emitting & (k2 > 0), w0r + 2, -1)
-        wrow3 = jnp.where(emitting & (k3 > 0), w0r + 3, -1)
-        wmin = jnp.min(jnp.where(wrow < 0, jnp.int32(ow), wrow))
-        wmax = jnp.maximum(
-            jnp.maximum(jnp.max(wrow), jnp.max(wrow1)),
-            jnp.maximum(jnp.max(wrow2), jnp.max(wrow3)))
-        for s in range(0, ow, slab):
-            sl = min(slab, ow - s)
+        wk = w0r & 7
+        si = _riota(16)
+        patch = pmask = jnp.zeros((16, LANES), _U32)
+        for j, (word, mask) in enumerate((
+                (bits, kmask << shl), (bits1, kmask1), (bits2, kmask2),
+                (bits3, kmask3))):
+            hit = si == wk + j
+            patch = jnp.where(hit, word, patch)
+            pmask = jnp.where(hit, mask, pmask)
+        patches = patch[:8], patch[8:]
+        wt = w0r >> 3
+        # the second tile only where the chunk's last byte lies in it:
+        # never past the buffer's last tile (``over`` above)
+        second = emitting & (wk + ((end - 1) >> 2) >= 8)
+        # big out: bytes land exactly once, buffer starts zeroed -> OR,
+        # slab-wise to bound scoped-vmem temps and slab-gated on the
+        # hull of the live tiles (lanes advance in rough lockstep, so
+        # most supersteps touch one slab, not all sixteen)
+        out_tiles = (jnp.where(emitting, wt, -1),
+                     jnp.where(second, wt + 1, -1))
+        hull = _tile_hull(out_tiles, ow // 8)
+        _scatter_tiles_ref_win(out_ref, out_tiles, patches, hull, slab=slab)
 
-            @pl.when((wmax >= s) & (wmin < s + sl))
-            def _(s=s, sl=sl):
-                ri = _riota(sl)
-                cur = out_ref[s:s + sl, :]
-                nxt = jnp.where(ri == wrow - s, cur | bits, cur)
-                nxt = jnp.where(ri == wrow1 - s, nxt | bits1, nxt)
-                nxt = jnp.where(ri == wrow2 - s, nxt | bits2, nxt)
-                out_ref[s:s + sl, :] = jnp.where(
-                    ri == wrow3 - s, nxt | bits3, nxt)
-        # history ring: same words, replace-semantics (rows recycle)
-        rrow = jnp.where(emitting, w0r & (RING_W - 1), -1)
-        rrow1 = jnp.where(emitting & (k1 > 0), (w0r + 1) & (RING_W - 1), -1)
-        rrow2 = jnp.where(emitting & (k2 > 0), (w0r + 2) & (RING_W - 1), -1)
-        rrow3 = jnp.where(emitting & (k3 > 0), (w0r + 3) & (RING_W - 1), -1)
-        curr = ring_ref[...]
-        bmask = kmask << shl
-        rri = _riota(RING_W)
-        curr = jnp.where(rri == rrow, (curr & ~bmask) | bits, curr)
-        curr = jnp.where(rri == rrow1, (curr & ~kmask1) | bits1, curr)
-        curr = jnp.where(rri == rrow2, (curr & ~kmask2) | bits2, curr)
-        ring_ref[...] = jnp.where(
-            rri == rrow3, (curr & ~kmask3) | bits3, curr)
+        # history ring: same patches, replace-semantics (rows recycle;
+        # word 0 keeps its low off bytes, which its mask leaves out),
+        # the tile index wrapped, every register, in every superstep
+        # in which some lane emits (the hull's max says so: no further
+        # reduction)
+        @pl.when(hull[1] >= 0)
+        def _():
+            ring_ref[...] = _scatter_tile(
+                ring_ref[...],
+                (jnp.where(emitting, wt & ring_t, -1),
+                 jnp.where(second, (wt + 1) & ring_t, -1)),
+                patches, masks=(pmask[:8], pmask[8:]))
         outpos = outpos + emit_k
 
         # ---- input-overrun guard ------------------------------------

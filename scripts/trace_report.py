@@ -673,6 +673,7 @@ def analyze(spans, run, runs, dropped: int = 0) -> str:
     # crossing_chunks labels, meta row 4 summed over a launch's lanes)
     launches = steps = far_steps = crossing = 0
     wait_s = 0.0
+    full: List[int] = []   # the full launches' own counts (128 lanes)
     for s in spans:
         labels = s.get("labels") or {}
         if labels.get("kind") != "inflate":
@@ -682,6 +683,8 @@ def analyze(spans, run, runs, dropped: int = 0) -> str:
         elif s["name"] == "device.launch.d2h" and "supersteps" in labels:
             launches += 1
             steps += int(labels["supersteps"])
+            if int(labels.get("lanes", 0)) == 128:
+                full.append(int(labels["supersteps"]))
             far_steps += int(labels.get("far_supersteps", 0))
             crossing += int(labels.get("crossing_chunks", 0))
     if steps:
@@ -690,7 +693,9 @@ def analyze(spans, run, runs, dropped: int = 0) -> str:
             f"{launches} launches, {wait_s / steps * 1e6:.2f} us a "
             f"superstep (device.launch.wait), {far_steps / steps * 100:.1f}% "
             f"of them read history past the ring, {crossing / launches:,.0f} "
-            "copy chunks a launch crossed an output word's boundary")
+            "copy chunks a launch crossed an output word's boundary"
+            + (f"; {len(full)} full launches of {min(full):,} to "
+               f"{max(full):,} supersteps" if full else ""))
         out.append("")
 
     top = order[0]

@@ -397,12 +397,13 @@ def flat_lens(symbols, alphabet):
     return {s: n.bit_length() - 1 for s in syms}
 
 
-def raw_launch(payloads, cw=128, ow=64):
+def raw_launch(payloads, cw=128, ow=64, whole=False):
     """One launch of the kernel itself: (each lane's output bytes, the
     (5, 128) meta rows: outpos, status, supersteps, far supersteps,
     each lane's crossing chunks). One geometry (512 compressed bytes
     in, 256 out) for all the small streams, so the interpreter traces
-    the kernel for them once."""
+    the kernel for them once. ``whole``: a lane's output is all of its
+    ``ow * 4`` bytes, not cut at its outpos."""
     import jax.numpy as jnp
 
     from disq_tpu.ops import inflate_simd as S
@@ -412,7 +413,8 @@ def raw_launch(payloads, cw=128, ow=64):
     words, meta = fn(jnp.asarray(comp), jnp.asarray(clen),
                      *(jnp.asarray(t) for t in S._CONST_TABLES))
     words, meta = np.asarray(words), np.asarray(meta)
-    outs = [np.ascontiguousarray(words[:, i]).tobytes()[: meta[0, i]]
+    outs = [np.ascontiguousarray(words[:, i]).tobytes()[
+                : ow * 4 if whole else meta[0, i]]
             for i in range(len(payloads))]
     return outs, meta
 
@@ -568,32 +570,40 @@ def chunk_bytes(off, dist):
     return (16 if dist >= 16 else 8 if dist >= 8 else 4) - off
 
 
-def fused_schedule(tokens):
-    """(supersteps, crossing chunks) of the schedule for one
-    fixed-Huffman block: the header, a step a literal (one for two, at
-    every output offset), a step a copy chunk (the match's length and
-    distance ride on its first), the end-of-block. A chunk starts at
-    the output's byte offset and takes ``chunk_bytes``; it crosses when
-    it starts inside a word and ends in a later one."""
-    steps, crossing, outpos, i = 1, 0, 0, 0
+def fused_emits(tokens):
+    """The emits of the schedule for one fixed-Huffman block, one an
+    emitting superstep: (outpos before it, its bytes, whether a copy
+    chunk). A step a literal (one for two, at every output offset), a
+    step a copy chunk (the match's length and distance ride on its
+    first); a chunk starts at the output's byte offset and takes
+    ``chunk_bytes``."""
+    emits, outpos, i = [], 0, 0
     while i < len(tokens):
         t = tokens[i]
         if isinstance(t, int):
             pair = i + 1 < len(tokens) and isinstance(tokens[i + 1], int)
             i += 2 if pair else 1
+            emits.append((outpos, 2 if pair else 1, False))
             outpos += 2 if pair else 1
-            steps += 1
             continue
         length, dist = t
         while length:
-            off = outpos & 3
-            k = min(chunk_bytes(off, dist), length)
-            crossing += off != 0 and k > 4 - off
+            k = min(chunk_bytes(outpos & 3, dist), length)
+            emits.append((outpos, k, True))
             length -= k
             outpos += k
-            steps += 1
         i += 1
-    return steps + 1, crossing
+    return emits
+
+
+def fused_schedule(tokens):
+    """(supersteps, crossing chunks) of that schedule: the header, a
+    step an emit, the end-of-block. A chunk crosses when it starts
+    inside a word and ends in a later one."""
+    emits = fused_emits(tokens)
+    crossing = sum(copy and at & 3 != 0 and k > 4 - (at & 3)
+                   for at, k, copy in emits)
+    return 1 + len(emits) + 1, crossing
 
 
 def fused_supersteps(tokens):
@@ -977,6 +987,361 @@ class TestTileReads:
         meta = tile_launch[2]
         assert 0 < meta[3, 0] <= meta[2, 0]
         assert (meta[3] == meta[3, 0]).all()
+
+
+# ---- the emit merge: the superstep's words as two tile patches ------
+
+
+def _want_scatter(buf, tiles, patches, masks=None):
+    want = buf.copy()
+    for i, (t, patch) in enumerate(zip(tiles, patches)):
+        for lane in np.nonzero(t >= 0)[0]:
+            rows = slice(8 * t[lane], 8 * t[lane] + 8)
+            if masks is not None:
+                want[rows, lane] &= ~masks[i][:, lane]
+            want[rows, lane] |= patch[:, lane]
+    return want
+
+
+def _patches(touch, seed):
+    """((P0, P1), (M0, M1)) as the merge builds them: up to four
+    consecutive words a lane from row ``k`` of a 16-row patch, byte
+    masks with them, zero elsewhere. ``touch``: the rows stay inside
+    the first tile, run on into the second, or there are none."""
+    rng = np.random.default_rng(seed)
+    patch = np.zeros((16, 128), np.uint32)
+    mask = np.zeros((16, 128), np.uint32)
+    byte_masks = np.array([0xFF, 0xFFFF, 0xFFFFFF, 0xFFFFFFFF, 0xFFFFFF00,
+                           0xFF000000], np.uint32)
+    for lane in range(128):
+        if touch == "one-tile":       # 1 .. 4 words, all below row 8
+            n = 1 + lane % 4
+            k = lane % (9 - n)
+        elif touch == "two-tiles":    # 2 .. 4 words across row 8
+            n = 2 + lane % 3
+            k = 7 - lane % (n - 1)
+        else:
+            n = k = 0
+        for j in range(n):
+            m = byte_masks[rng.integers(len(byte_masks))]
+            mask[k + j, lane] = m
+            patch[k + j, lane] = (rng.integers(
+                0, 2**32, dtype=np.uint64).astype(np.uint32) | 0x01010101) & m
+    if touch == "two-tiles":
+        assert patch[:8].any(axis=0).all() and patch[8:].any(axis=0).all()
+    if touch == "one-tile":
+        assert not patch[8:].any() and patch[:8].any(axis=0).all()
+    return (patch[:8], patch[8:]), (mask[:8], mask[8:])
+
+
+def _scatter_win(buf, tiles, patches, slab):
+    """``_scatter_tiles_ref_win`` on a ref holding ``buf``."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    from disq_tpu.ops.inflate_simd import (
+        _scatter_tiles_ref_win, _tile_hull,
+    )
+
+    def body(buf_ref, t0_ref, t1_ref, p0_ref, p1_ref, out_ref):
+        out_ref[...] = buf_ref[...]
+        tiles = t0_ref[...], t1_ref[...]
+        _scatter_tiles_ref_win(
+            out_ref, tiles, (p0_ref[...], p1_ref[...]),
+            _tile_hull(tiles, buf.shape[0] // 8), slab=slab)
+
+    return np.asarray(pl.pallas_call(
+        body, out_shape=jax.ShapeDtypeStruct(buf.shape, jnp.uint32),
+        interpret=True,
+    )(jnp.asarray(buf), *(jnp.asarray(t[None]) for t in tiles),
+      *(jnp.asarray(p) for p in patches)))
+
+
+class TestTileScatter:
+    @pytest.mark.parametrize("touch", ["one-tile", "two-tiles", "none"])
+    @pytest.mark.parametrize("rows", [8, 1024])
+    def test_tile_scatter_equals_numpy_indexing(self, rows, touch):
+        import jax.numpy as jnp
+
+        from disq_tpu.ops.inflate_simd import _scatter_tile
+
+        buf = _tile_buffer(rows)
+        n_tiles = rows // 8
+        t0 = _tile_indices(n_tiles, rows)
+        # the merge's pair: a tile and the next (the ring's wraps: with
+        # one tile in all, the next is the same one and must be dead)
+        t1 = np.where(t0 < 0, -1, (t0 + 1) % n_tiles).astype(np.int32)
+        if touch != "two-tiles" or n_tiles == 1:
+            t1[:] = -1
+        if touch == "none":
+            t0[::2] = -1
+        patches, masks = _patches(touch, rows)
+        tiles = tuple(jnp.asarray(t[None]) for t in (t0, t1))
+        for mk in (None, masks):
+            got = _scatter_tile(
+                jnp.asarray(buf), tiles,
+                tuple(jnp.asarray(p) for p in patches),
+                None if mk is None else tuple(jnp.asarray(m) for m in mk))
+            assert got.dtype == jnp.uint32 and got.shape == buf.shape
+            want = _want_scatter(buf, (t0, t1), patches, mk)
+            assert (np.asarray(got) == want).all()
+            assert (want == buf).all() == (touch == "none")
+
+    def test_the_rings_wrap_on_the_write_side(self):
+        # a lane whose first tile is the ring's last writes tile 0 next,
+        # bytes outside the masks kept (rows recycle: replace, not OR)
+        import jax.numpy as jnp
+
+        from disq_tpu.ops.inflate_simd import RING_W, _scatter_tile
+
+        ring = _tile_buffer(RING_W)
+        last = RING_W // 8 - 1
+        t0 = np.full(128, last, np.int32)
+        t0[::2] = np.arange(64) * 2
+        t1 = (t0 + 1) & last
+        assert t1[1] == 0 and t1[0] == 1
+        patches, masks = _patches("two-tiles", 3)
+        got = _scatter_tile(
+            jnp.asarray(ring), tuple(jnp.asarray(t[None]) for t in (t0, t1)),
+            tuple(jnp.asarray(p) for p in patches),
+            tuple(jnp.asarray(m) for m in masks))
+        want = _want_scatter(ring, (t0, t1), patches, masks)
+        assert (np.asarray(got) == want).all()
+        assert (want[:8, 1] != ring[:8, 1]).any()
+        assert (want[-8:, 1] != ring[-8:, 1]).any()
+
+    @pytest.mark.parametrize("touch", ["one-tile", "two-tiles", "none"])
+    @pytest.mark.parametrize("case", ["spread", "one-slab", "none-live",
+                                      "pair-across-a-slab-edge"])
+    def test_windowed_tile_scatter_over_slabs(self, case, touch):
+        rows, slab = 4096, 1024           # four slabs of 128 tiles
+        buf = _tile_buffer(rows)
+        if case == "spread":
+            t0 = _tile_indices(rows // 8, 4)
+        elif case == "one-slab":
+            t0 = 128 + _tile_indices(128, 5)
+            t0[[3, 77]] = -1
+        elif case == "none-live":
+            t0 = np.full(128, -1, np.int32)
+        else:
+            t0 = np.full(128, 255, np.int32)   # its second tile: slab 2
+            t0[9] = -1
+        # the merge's pair: a tile and the next; past the buffer's last
+        # tile the second is dead
+        t1 = np.where((t0 < 0) | (t0 + 1 >= rows // 8), -1,
+                      t0 + 1).astype(np.int32)
+        if touch != "two-tiles":
+            t1[:] = -1
+        patches, _masks = _patches(touch, 7)
+        got = _scatter_win(buf, (t0, t1), patches, slab)
+        assert (got == _want_scatter(buf, (t0, t1), patches)).all()
+        if case == "none-live" or touch == "none":
+            assert (got == buf).all()
+        # a buffer no larger than a slab: one slab, one gate
+        s0 = np.where(t0 < 0, -1, t0 % 128).astype(np.int32)
+        s1 = np.where((t1 < 0) | (s0 == 127), -1, s0 + 1).astype(np.int32)
+        small = _scatter_win(buf[:slab], (s0, s1), patches, slab)
+        assert (small == _want_scatter(buf[:slab], (s0, s1), patches)).all()
+
+
+# (start word in its tile, byte offset, the emit's bytes, distance):
+# every emit the rules allow at every start word of a tile and every
+# byte offset, 1 .. 16 - off bytes (one and two bytes as literals, the
+# rest the first chunk of a match from 19 back), so every straddle into
+# the next tile; then the short distances: d 1, 2, 3 (four fetched
+# bytes replicated modularly, a chunk to the word's end) and 8 <= d <
+# 16 (to the end of the second word), a second chunk following
+_EMIT_CASES = (
+    [(wk, off, n, 19) for wk in range(8) for off in range(4)
+     for n in range(1, 17 - off)]
+    + [(wk, off, 5, d) for wk in range(8) for off in range(4)
+       for d in (1, 2, 3)]
+    + [(wk, off, 10 - off, d) for wk in range(8) for off in range(4)
+       for d in (8, 15)]
+)
+
+
+def lead_tokens(at, rng):
+    """Tokens for ``at`` >= 32 bytes of output: an even count of random
+    literals (whole pairs), then a match of 3 or 4 bytes from 16 back
+    that ends at byte ``at`` exactly."""
+    lits = at - 3 - (at - 3) % 2
+    return rng.integers(0, 256, lits).tolist() + [(at - lits, 16)]
+
+
+def emit_tokens(wk, off, n, d, lane):
+    """A lead (no two lanes' alike) up to output byte ``32 + 4 wk +
+    off``, then the emit under test: one literal or two before a match,
+    or an ``n``-byte match from ``d`` back; a literal after it."""
+    rng = np.random.default_rng(1000 + lane)
+    tokens = lead_tokens(32 + 4 * wk + off, rng)
+    if n <= 2 and d == 19:
+        return tokens + rng.integers(0, 256, n).tolist() + [(3, 19), 122]
+    return tokens + [(n, d), 122]
+
+
+@pytest.fixture(scope="module")
+def emit_launches():
+    """Every case's (tokens, payload, output, meta column, the model's
+    supersteps of its launch), 128 lanes a launch."""
+    done = []
+    for lo in range(0, len(_EMIT_CASES), 128):
+        tokens = [emit_tokens(*c, lane=lo + i)
+                  for i, c in enumerate(_EMIT_CASES[lo: lo + 128])]
+        payloads = [_fixed(t) for t in tokens]
+        outs, meta = raw_launch(payloads)
+        steps = max(fused_supersteps(t) for t in tokens)
+        done += [(t, p, o, meta[:, i], steps)
+                 for i, (t, p, o) in enumerate(zip(tokens, payloads, outs))]
+    return done
+
+
+# the buffer's end (256 bytes a lane): (start word of the last tile,
+# byte offset, bytes past the end). The last emit runs from there to
+# the end exactly (status 0: its second tile would be past the
+# buffer's last and must match nothing) or ``past`` bytes over it
+# (status 5, nothing of that emit written)
+_END_CASES = [(wk, off, past) for wk in (4, 5, 6, 7) for off in range(4)
+              for past in (0, 1, 7)]
+
+
+def end_tokens(wk, off, past, lane):
+    at = 224 + 4 * wk + off
+    n = 256 - at + past
+    rng = np.random.default_rng(2000 + lane)
+    tokens = lead_tokens(at, rng)
+    if n <= 2:
+        return tokens + rng.integers(0, 256, n).tolist()
+    return tokens + [(n, 19)]
+
+
+@pytest.fixture(scope="module")
+def end_launch():
+    tokens = [end_tokens(*c, lane=i) for i, c in enumerate(_END_CASES)]
+    payloads = [_fixed(t) for t in tokens]
+    outs, meta = raw_launch(payloads, cw=128, ow=64, whole=True)
+    return tokens, payloads, outs, meta
+
+
+def _ring_wrap_tokens(start_word, off, back):
+    """A chunk of ``16 - off`` bytes from output word 1,020 +
+    ``start_word`` (1, 2 or 3): with the ring's 1,024 rows its words
+    sit in ring tile 127 and, from word 1,024, in tile 0; then a match
+    that reads the chunk back through the ring (``back``: how far behind
+    its own start it begins), and one that reads that match's own end
+    back: bytes written past the wrap, in ring tile 0 alone."""
+    n = 16 - off
+    return (list(any_head_tokens(4080 + 4 * start_word))
+            + list(range(200, 200 + off)) + [(n, 23), (n + back, n + back),
+                                             (9, 9), ord("z")])
+
+
+_WRAP_CASES = [(w, off, back) for w in (1, 2, 3) for off in range(4)
+               for back in (0, 5)]
+
+
+@pytest.fixture(scope="module")
+def wrap_launch():
+    tokens = [_ring_wrap_tokens(*c) for c in _WRAP_CASES]
+    payloads = [_fixed(t) for t in tokens]
+    outs, meta = raw_launch(payloads, *_ANY_GEOMETRY)
+    return tokens, payloads, outs, meta
+
+
+class TestEmitMerge:
+    def test_the_cases_cover_every_emit_the_rules_allow(self, emit_launches):
+        seen = set()
+        for tokens, *_rest in emit_launches:
+            seen |= {((at >> 2) & 7, at & 3, k, copy)
+                     for at, k, copy in fused_emits(tokens)}
+        placed = {c[:3] for c in seen}
+        assert placed >= {(wk, off, n) for wk in range(8) for off in range(4)
+                          for n in range(1, 17 - off)}
+        # no emit is longer: never a fifth output word
+        assert max(off + n for _wk, off, n in placed) == 16
+        # every straddle into the next tile: from start words 5, 6 and
+        # 7, with two to four live words
+        straddles = {(wk, (off + n + 3) // 4) for wk, off, n in placed
+                     if wk + (off + n + 3) // 4 > 8}
+        assert straddles == {(5, 4), (6, 3), (6, 4), (7, 2), (7, 3), (7, 4)}
+        # a literal pair whose second byte lies in the next word, and
+        # in the next tile
+        assert {(wk, 3, 2, False) for wk in range(8)} <= seen
+        ds = {c[3] for c in _EMIT_CASES}
+        assert ds >= {1, 2, 3} and any(8 <= d < 16 for d in ds)
+
+    @pytest.mark.parametrize(
+        "lane", range(len(_EMIT_CASES)),
+        ids=[f"word{wk}-off{off}-{n}bytes-d{d}"
+             for wk, off, n, d in _EMIT_CASES])
+    def test_lane_equals_zlib(self, emit_launches, lane):
+        tokens, payload, out, meta, steps = emit_launches[lane]
+        wk, off, n, d = _EMIT_CASES[lane]
+        want = zlib.decompress(payload, -15)
+        at = 32 + 4 * wk + off
+        # the emit under test is the model's, where the case says
+        emits = {e[0]: e for e in fused_emits(tokens)}
+        match = n > 2 or d != 19
+        assert emits[at] == (
+            at, min(n, chunk_bytes(off, d)) if match else n, match)
+        if match:
+            assert want[at: at + min(n, d)] == want[at - d: at - d + min(n, d)]
+        assert meta[1] == 0 and meta[0] == len(want)
+        assert out == want
+        assert meta[2] == steps
+
+    @pytest.mark.parametrize(
+        "lane", range(len(_WRAP_CASES)),
+        ids=[f"word{1020 + w}-off{off}-back{b}" for w, off, b in _WRAP_CASES])
+    def test_the_ring_wraps_under_a_chunk_and_reads_back(
+            self, wrap_launch, lane):
+        from disq_tpu.ops.inflate_simd import RING_W
+
+        tokens, payloads, outs, meta = wrap_launch
+        w, off, _back = _WRAP_CASES[lane]
+        at = 4080 + 4 * w + off
+        chunk = {e[0]: e for e in fused_emits(tokens[lane])}[at]
+        assert chunk == (at, 16 - off, True)
+        # its four words: ring tile 127, then tile 0
+        rows = [(r & (RING_W - 1)) >> 3
+                for r in range(at >> 2, (at + 16 - off + 3) >> 2)]
+        assert rows[0] == 127 and rows[-1] == 0
+        assert meta[1, lane] == 0
+        assert meta[3, lane] == 0          # read back through the ring
+        assert outs[lane] == zlib.decompress(payloads[lane], -15)
+
+    @pytest.mark.parametrize(
+        "lane", range(len(_END_CASES)),
+        ids=[f"word{56 + wk}-off{off}-past{past}"
+             for wk, off, past in _END_CASES])
+    def test_the_buffers_end(self, end_launch, lane):
+        tokens, payloads, outs, meta = end_launch
+        wk, off, past = _END_CASES[lane]
+        want = zlib.decompress(payloads[lane], -15)
+        assert len(want) == 256 + past
+        # the model: the first emit that would pass the end writes
+        # nothing and flags the lane
+        at, status = 0, 0
+        for at, k, _copy in fused_emits(tokens[lane]):
+            if at + k > 256:
+                status = 5
+                break
+            at += k
+        assert (status == 5) == (past > 0)
+        assert (meta[1, lane], meta[0, lane]) == (status, at)
+        assert outs[lane][:at] == want[:at]
+        assert outs[lane][at:] == bytes(256 - at)
+
+    def test_a_lane_over_the_end_harms_no_other(self, end_launch):
+        _tokens, payloads, outs, meta = end_launch
+        flagged = [past > 0 for _wk, _off, past in _END_CASES]
+        assert meta[1, :len(flagged)].tolist() == [5 * f for f in flagged]
+        # the lanes between the flagged ones: whole and right
+        assert [o for o, f in zip(outs, flagged) if not f] == [
+            zlib.decompress(p, -15)
+            for p, f in zip(payloads, flagged) if not f]
+        assert not meta[:2, len(flagged):].any()
 
 
 def inflate_by(route, payloads, usizes):

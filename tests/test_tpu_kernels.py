@@ -79,9 +79,20 @@ def test_device_kernels_on_chip(tmp_path):
                    "inflate_simd_wgs30x_kernel_only"):
         assert rows[kernel]["supersteps_per_launch"] > 0
         assert rows[kernel]["us_per_superstep"] > 0
-    assert 0 < rows["inflate_simd_wgs30x_kernel_only"][
-        "far_superstep_share"] <= 1
-    assert rows["inflate_simd_wgs30x_kernel_only"]["crossing_chunks"] > 0
+    wgs = rows["inflate_simd_wgs30x_kernel_only"]
+    assert 0 < wgs["far_superstep_share"] <= 1
+    assert wgs["crossing_chunks"] > 0
+    # the counts are exact functions of the input (seed 27's 24,000
+    # records, zlib 6) and of the schedule, not of the chip: a change
+    # of the superstep's price leaves them to the unit (PR 37)
+    assert (wgs["supersteps_per_launch"], wgs["crossing_chunks"]) == (
+        13704, 468730)
+    assert rows["inflate_simd_literal_heavy_kernel_only"][
+        "supersteps_per_launch"] == 13191
+    assert rows["inflate_simd_kernel_only"]["supersteps_per_launch"] == 5950
+    # the price since the emit merge is a tile scatter (PR 37: 5.4 us
+    # where the four-row merge read 6.2; TPU_KERNELS.json keeps both)
+    assert wgs["us_per_superstep"] < 5.8
     # refresh the repo-root artifact for the judge
     with open(os.path.join(REPO, "TPU_KERNELS.json"), "w") as f:
         json.dump(artifact, f, indent=1)

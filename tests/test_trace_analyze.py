@@ -227,6 +227,23 @@ class TestVerdict:
         assert want in line
         assert line.endswith("an output word's boundary")
 
+    @pytest.mark.parametrize("lanes,want", [
+        ((13, 128, 128, 40), "; 2 full launches of 13,900 to 14,100 "
+                             "supersteps"),
+        ((128, 128, 128, 128), "; 4 full launches of 9,000 to 14,100 "
+                               "supersteps"),
+        ((13, 40, 40, 40), "an output word's boundary"),
+        (None, "an output word's boundary"),
+    ], ids=["two-full", "all-full", "none-full", "label-absent"])
+    def test_inflate_full_launches_range_at_the_lines_end(self, lanes, want):
+        """The least and the most supersteps of a log's full launches
+        (the d2h spans' ``lanes`` label at 128): a short header or tail
+        launch does not set the range; a log without a full launch, or
+        from before the label, ends where the line did."""
+        line = _supersteps_line((9000, 14100, 13900, 10000), lanes=lanes)
+        assert "11,750 a launch over 4 launches" in line
+        assert line.endswith(want)
+
     def test_no_spans(self):
         assert "no spans" in trace_report.analyze([], None, [])
 
