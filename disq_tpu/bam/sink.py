@@ -250,7 +250,12 @@ class BamSink:
         if resident is not None:
             enc = resident.encode_shard(lo, hi)
             return _LazySlice(batch, lo, hi), enc, enc.record_offsets
-        part = batch.slice(lo, hi)
+        # a device-backed batch materialises in ``slice`` once, under
+        # its own lock: one writer parses and the others wait here
+        from disq_tpu.runtime.tracing import span
+
+        with span("bam.write.slice", shard=k, records=hi - lo):
+            part = batch.slice(lo, hi)
         blob, rec_offs = encode_records_with_offsets(part)
         return part, blob, rec_offs
 

@@ -164,8 +164,9 @@ def inflate_blocks_device(
     Payloads are sliced as ``memoryview``\\ s (nothing here copies the
     compressed bytes); batch CRC verification runs threaded, off the
     kernel's critical path (the service keeps decoding other shards'
-    chunks while this thread verifies).  ``as_array`` returns the blob
-    as a uint8 array instead of bytes.
+    chunks while this thread verifies), under the span
+    ``codec.inflate.verify{blocks, bytes}`` with the ``tobytes`` copy.
+    ``as_array`` returns the blob as a uint8 array instead of bytes.
 
     ``keep_device`` returns ``(blob, DeviceBlobHandle-or-None)``: on
     the direct route the kernel's output chunks stay resident in HBM
@@ -197,14 +198,21 @@ def inflate_blocks_device(
             keep_device=keep_device)
         if kept:
             handle = kept[0]
-    try:
-        if verify_crc:
-            _verify_block_crcs(data, blocks, base, blob, offsets)
-    except BaseException:
-        if handle is not None:
-            handle.release()
-        raise
-    out = blob if as_array else blob.tobytes()
+    # the device has answered: what follows is this thread's own host
+    # work on the decoded blob (``codec.inflate.batch`` minus it is the
+    # wait for the device)
+    from disq_tpu.runtime.tracing import span
+
+    with span("codec.inflate.verify", blocks=len(blocks),
+              bytes=len(blob)):
+        try:
+            if verify_crc:
+                _verify_block_crcs(data, blocks, base, blob, offsets)
+        except BaseException:
+            if handle is not None:
+                handle.release()
+            raise
+        out = blob if as_array else blob.tobytes()
     return (out, handle) if keep_device else out
 
 

@@ -325,10 +325,16 @@ def parse_columns_resident(
         # jit once per shard — quantized shapes keep compiles to a
         # handful per run at <=~6% pad overhead on big shards
         nwords = _pad_quantum(max(1, (len(blob) + 3) // 4), coarse)
-        padded = np.empty(nwords * 4, np.uint8)
-        padded[: len(blob)] = blob
-        padded[len(blob):] = 0
-        with span("device.transfer", direction="h2d"):
+        with span("columnar.batch.stage", bytes=nwords * 4):
+            padded = np.empty(nwords * 4, np.uint8)
+            padded[: len(blob)] = blob
+            padded[len(blob):] = 0
+        # the replicated blob lands on every device: book each copy
+        word_bytes = padded.nbytes * n_dev
+        up = word_bytes + starts_host.nbytes
+        # ``device_put`` is asynchronous: this span is the enqueue
+        with span("device.transfer", direction="h2d", site="parse_blob",
+                  bytes=up):
             if mesh is None:
                 words_dev = jax.device_put(jnp.asarray(padded.view("<u4")))
                 starts_dev = jax.device_put(jnp.asarray(starts_host))
@@ -337,11 +343,10 @@ def parse_columns_resident(
                     jnp.asarray(padded.view("<u4")), replicated(mesh))
                 starts_dev = jax.device_put(
                     jnp.asarray(starts_host), batch_sharding(mesh))
-        # the replicated blob lands on every device: book each copy
-        count_transfer("h2d", padded.nbytes * n_dev + starts_host.nbytes)
-        word_bytes = padded.nbytes * n_dev
+        count_transfer("h2d", up)
     else:
-        with span("device.transfer", direction="h2d"):
+        with span("device.transfer", direction="h2d", site="parse_starts",
+                  bytes=starts_host.nbytes):
             if mesh is None:
                 starts_dev = jax.device_put(jnp.asarray(starts_host))
             else:
@@ -422,9 +427,11 @@ class DevicePipelineResult:
         missing = [m for m in names if m not in self._np]
         if not missing:
             return
-        with span("device.transfer", direction="d2h"):
+        with span("device.transfer", direction="d2h",
+                  site="pipeline_fetch") as labels:
             got = {m: np.asarray(self._dev[m]) for m in missing}
-        count_transfer("d2h", sum(a.nbytes for a in got.values()))
+            labels["bytes"] = sum(a.nbytes for a in got.values())
+        count_transfer("d2h", labels["bytes"])
         self._np.update(got)
         if all(k in self._np for k in ("hi", "lo", "order", "fs")):
             self._release_hbm()
@@ -511,10 +518,11 @@ def run_device_pipeline(
     # explicit uploads — the ONLY host->device transfers in the flow.
     # Upload accounting covers what actually moves: the word-aligned
     # blob (pad bytes included) plus the starts vector.
-    with span("device.transfer", direction="h2d"):
+    with span("device.transfer", direction="h2d",
+              site="pipeline_upload") as labels:
         blob_dev, blob_bytes = upload_blob_words(blob)
         starts_dev = jax.device_put(jnp.asarray(starts_host))
-    up_bytes = blob_bytes + starts_host.nbytes
+        labels["bytes"] = up_bytes = blob_bytes + starts_host.nbytes
     count_transfer("h2d", up_bytes)
     track_hbm(up_bytes)
     try:

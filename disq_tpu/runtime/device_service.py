@@ -55,7 +55,14 @@ the per-service sequence number ``launch``: ``device.service.idle``
 when 0, and once more at close for the sleep that close ended),
 ``device.launch.pack`` (host lane packing), ``.submit`` (upload +
 enqueue), ``.wait`` (blocked on the kernel), ``.d2h`` (the copy back)
-and ``.deliver`` (lanes handed to their submissions).
+and ``.deliver`` (lanes handed to their submissions).  The sleep is
+also the counter ``device.service.idle_seconds{reason=empty|filling}``
+(nothing queued: the producers starve the device; lanes queued that
+wait for company under the flush timeout), exact between any two
+``telemetry_snapshot()``s: the service books the sleep so far whenever
+one is taken (``tracing.on_snapshot``), the dispatcher the rest when it
+wakes.  Under a profiler capture the sleep itself is the annotation
+``disq_tpu.device.service.idle``, on the device trace's clock.
 
 Enablement: ``DISQ_TPU_DEVICE_SERVICE=1`` — checked by the codec entry
 points alongside ``DISQ_TPU_DEVICE_INFLATE`` / ``DISQ_TPU_DEVICE_RANS``.
@@ -75,9 +82,12 @@ import numpy as np
 
 from disq_tpu.runtime import flightrec as _flightrec
 from disq_tpu.runtime.tracing import (
+    annotate as _annotate,
     counter as _counter,
     current_trace as _current_trace,
     observe_gauge as _observe_gauge,
+    off_snapshot as _off_snapshot,
+    on_snapshot as _on_snapshot,
     record_span as _record_span,
     span as _span,
     trace_scope as _trace_scope,
@@ -426,6 +436,14 @@ class DeviceDecodeService:
         # ``_cond.wait`` since the last one (``device.service.idle``)
         self._launch_seq = 0
         self._idle_s = 0.0
+        # under ``_cond``: why the dispatcher sleeps now and since when
+        # ``device.service.idle_seconds`` has not been told of it (None:
+        # awake).  Both reasons at 0 from the start, so a reader tells
+        # "did not sleep" from "no such counter"
+        self._sleep_reason = "empty"
+        self._sleep_t0: Optional[float] = None
+        self._book_sleep_locked(0.0)
+        _on_snapshot(self._settle_idle)
         # window sized for the standard full-BGZF geometry; the env
         # knobs in dispatch_window apply here too.  Scaled by the
         # device count: the window bounds launches IN FLIGHT, and with
@@ -621,6 +639,27 @@ class DeviceDecodeService:
             self._loop()
         except BaseException as e:  # noqa: BLE001 — fail pending, not hang
             self._abort_all(e)
+        finally:
+            _off_snapshot(self._settle_idle)
+
+    def _book_sleep_locked(self, now: float) -> None:
+        """The sleep up to ``now`` into ``device.service.idle_seconds``
+        under its reason (the other reason by 0: a telemetry reset must
+        not take it out of the snapshot)."""
+        slept = 0.0
+        if self._sleep_t0 is not None:
+            slept, self._sleep_t0 = now - self._sleep_t0, now
+        idle = _counter("device.service.idle_seconds")
+        for reason in ("empty", "filling"):
+            idle.inc(slept if reason == self._sleep_reason else 0,
+                     reason=reason)
+
+    def _settle_idle(self) -> None:
+        # any thread, before a telemetry snapshot is copied: no sleep
+        # is cut short for it, the counter is told how long it has
+        # lasted so far
+        with self._cond:
+            self._book_sleep_locked(time.perf_counter())
 
     def _loop(self) -> None:
         while True:
@@ -634,11 +673,19 @@ class DeviceDecodeService:
                         break  # overlap the wait with a materialize
                     if self._closed:
                         break
-                    # timed inline: a context-manager span here would
-                    # take the span lock inside the condition wait
-                    t_sleep = time.perf_counter()
-                    self._cond.wait(self._wait_s_locked())
-                    self._idle_s += time.perf_counter() - t_sleep
+                    # ``_idle_s`` is the whole sleep, booked as one
+                    # span by the launch that ends it; the counter is
+                    # told of it piecewise (``_book_sleep_locked``)
+                    wait_s = self._wait_s_locked()
+                    self._sleep_reason = (
+                        "empty" if wait_s is None else "filling")
+                    self._sleep_t0 = t_sleep = time.perf_counter()
+                    with _annotate("device.service.idle"):
+                        self._cond.wait(wait_s)
+                    now = time.perf_counter()
+                    self._idle_s += now - t_sleep
+                    self._book_sleep_locked(now)
+                    self._sleep_t0 = None
             if chunk is None and not self._inflight:
                 # closed and drained: the sleep that close ended is
                 # followed by no launch, so it is booked here

@@ -256,8 +256,10 @@ class ResidentShardEncoder:
         n = len(self._lens)
         self._perm_off = np.zeros(n + 1, dtype=np.int64)
         np.cumsum(self._lens, out=self._perm_off[1:])
-        with span("device.transfer", direction="h2d"):
+        with span("device.transfer", direction="h2d",
+                  site="write_blob") as labels:
             self._words, up = upload_blob_words(blob)
+            labels["bytes"] = up
         count_transfer("h2d", up)
         self._hbm = up
         track_hbm(up)

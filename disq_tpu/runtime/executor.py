@@ -75,7 +75,8 @@ from disq_tpu.runtime.errors import (
     ShardRetrier,
     is_transient,
 )
-from disq_tpu.runtime.tracing import observe_gauge, record_span, span
+from disq_tpu.runtime.tracing import (
+    annotate, observe_gauge, record_span, span)
 
 # Sentinel a fetch stage emits when the shard's deadline expired and
 # the task carries a fallback: the decode stage then produces the
@@ -185,6 +186,7 @@ class _BoundedStagePipeline:
         on_admit: Callable[[int], None],
         on_result: Callable[[List[float]], None],
         on_stall: Callable[[float, Any], None],
+        stall_name: str,
         drain_on_close: bool = False,
         stage_names: Sequence[str] = (),
         health=None,
@@ -197,6 +199,9 @@ class _BoundedStagePipeline:
         self.on_admit = on_admit
         self.on_result = on_result
         self.on_stall = on_stall
+        # the span name ``on_stall`` books its seconds under: the wait
+        # itself lies under that name in a profiler capture
+        self.stall_name = stall_name
         # The write direction drains running jobs at close so an
         # aborting sink never races an in-flight part write against its
         # own temp-dir cleanup; the read direction keeps wait=False (a
@@ -299,8 +304,9 @@ class _BoundedStagePipeline:
                 for i in range(len(tasks)):
                     with cond:
                         t0 = time.perf_counter()
-                        while i not in results and i not in errors:
-                            cond.wait()
+                        with annotate(self.stall_name):
+                            while i not in results and i not in errors:
+                                cond.wait()
                         self.on_stall(time.perf_counter() - t0, tasks[i])
                         if i in errors:
                             state["aborted"] = True
@@ -561,6 +567,7 @@ class ShardPipelineExecutor:
             on_admit=on_admit,
             on_result=on_result,
             on_stall=on_stall,
+            stall_name="executor.emit.stall",
             stage_names=("fetch", "decode"),
             health=self._health if token is not None else None,
             health_token=token,
@@ -915,6 +922,7 @@ class ShardWritePipeline:
             on_admit=on_admit,
             on_result=on_result,
             on_stall=on_stall,
+            stall_name="writer.emit.stall",
             drain_on_close=True,
             # "encode_seconds" -> heartbeat stage name "encode", etc.
             stage_names=[a.split("_", 1)[0] for a in attr_names],

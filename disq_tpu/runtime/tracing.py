@@ -23,8 +23,11 @@ layer shared by every subsystem:
   ``jax.profiler.TraceAnnotation("disq_tpu.<name>")``, so under a
   capture the program's spans lie in the same ``.xplane.pb``, on the
   same clock, as the ``XLA Ops`` line (Perfetto shows host and device
-  on one timeline).  ``record_span`` books a wait after the fact and
-  cannot be bridged.  ``DISQ_TPU_TRACE_DIR`` (or ``start_trace(dir)``)
+  on one timeline).  ``record_span`` books a duration after the fact;
+  a thread's wait that is booked so (the dispatcher's sleep, the
+  ordered emit's stall) opens the bare ``annotate(name)`` round the
+  wait itself, so it lies in the capture too.  ``DISQ_TPU_TRACE_DIR``
+  (or ``start_trace(dir)``)
   captures everything between the first ``trace_phase`` entered and
   process exit (or ``stop_trace()``).
 
@@ -314,6 +317,28 @@ class MetricsRegistry:
     def __init__(self) -> None:
         self._lock = threading.RLock()
         self._metrics: Dict[str, Any] = {}
+        self._settlers: List[Callable[[], None]] = []
+
+    def on_snapshot(self, fn: Callable[[], None]) -> None:
+        """Call ``fn`` before every ``snapshot()`` / ``metrics_text()``
+        copies the registry: the owner of a counter that grows with
+        time (the dispatcher's sleep) books what has passed so far, so
+        two snapshots differ by what lay between them."""
+        with self._lock:
+            self._settlers.append(fn)
+
+    def off_snapshot(self, fn: Callable[[], None]) -> None:
+        with self._lock:
+            if fn in self._settlers:
+                self._settlers.remove(fn)
+
+    def _settle(self) -> None:
+        # outside the registry lock: a settler takes its owner's lock
+        # first and the registry's (``inc``) inside it, as its owner does
+        with self._lock:
+            settlers = list(self._settlers)
+        for fn in settlers:
+            fn()
 
     def _get(self, name: str, factory: Callable[[], Any], kind: str):
         with self._lock:
@@ -358,6 +383,7 @@ class MetricsRegistry:
         out: Dict[str, Dict[str, Any]] = {
             "counters": {}, "gauges": {}, "histograms": {},
         }
+        self._settle()
         with self._lock:
             for name in sorted(self._metrics):
                 m = self._metrics[name]
@@ -387,6 +413,7 @@ class MetricsRegistry:
             return repr(round(v, 9)) if isinstance(v, float) else str(v)
 
         lines: List[str] = []
+        self._settle()
         with self._lock:
             items = sorted(self._metrics.items())
             for name, m in items:
@@ -451,6 +478,14 @@ def metrics_text() -> str:
 
 def telemetry_snapshot() -> Dict[str, Any]:
     return REGISTRY.snapshot()
+
+
+def on_snapshot(fn: Callable[[], None]) -> None:
+    REGISTRY.on_snapshot(fn)
+
+
+def off_snapshot(fn: Callable[[], None]) -> None:
+    REGISTRY.off_snapshot(fn)
 
 
 # ---------------------------------------------------------------------------
@@ -760,9 +795,12 @@ _annotation_cls = None  # jax.profiler.TraceAnnotation once jax is loaded
 _NO_ANNOTATION = contextlib.nullcontext()
 
 
-def _annotation(name: str):
-    """The profiler annotation of a span (no labels in its name, so a
-    reduction can key on it).  jax is never imported for it: a process
+def annotate(name: str):
+    """The profiler annotation of a span, bare: ``disq_tpu.<name>`` on
+    the capture's clock, no ring entry, no histogram, no lock taken (no
+    labels in its name, so a reduction can key on it).  ``span`` opens
+    one; a wait that ``record_span`` books after the fact opens one
+    round the wait itself.  jax is never imported for it: a process
     that has not loaded jax has no capture running."""
     global _annotation_cls
     cls = _annotation_cls
@@ -786,7 +824,7 @@ def span(name: str, **labels: Any) -> Iterator[Dict[str, Any]]:
     _resolve_span_env()
     t0 = time.perf_counter()
     try:
-        with _annotation(name):
+        with annotate(name):
             yield labels
     finally:
         _emit_span(name, t0, time.perf_counter() - t0, labels)
@@ -794,8 +832,10 @@ def span(name: str, **labels: Any) -> Iterator[Dict[str, Any]]:
 
 def record_span(name: str, seconds: float, **labels: Any) -> None:
     """Book an already-measured duration as a span ending now (for
-    waits timed inline — e.g. the executor's ordered-emit stall — where
-    a context manager would nest a lock inside a condition wait)."""
+    durations timed inline: the executor's ordered-emit stall, the
+    dispatcher's sleep, a launch's queueing delay).  It opens no
+    annotation; where the duration is a thread's wait, the waiter opens
+    ``annotate(name)`` round it."""
     _resolve_span_env()
     now = time.perf_counter()
     _emit_span(name, now - seconds, seconds, labels)

@@ -86,6 +86,11 @@ from typing import Any, Dict, List, Optional
 CATEGORIES = (
     ("fetch", "F", ("executor.fetch",)),
     ("decode", "D", ("executor.decode",)),
+    # The writers' slice of the batch (bam/sink.py), inside
+    # bam.write.encode and listed before it: on a device-backed batch
+    # one writer materialises the records and the others wait for it,
+    # which is not record encoding.
+    ("write_slice", "m", ("bam.write.slice",)),
     ("encode", "E", ("bam.write.encode", "vcf.write.encode",
                      "bcf.write.encode", "cram.write.encode",
                      "sam.write.encode")),
@@ -112,13 +117,19 @@ CATEGORIES = (
     # ran dry, so the host side upstream (fetch, parse, emit) is the
     # one to look at.
     ("service_idle", "i", ("device.service.idle",)),
+    # The host's check of what the device decoded (bgzf/codec.py), the
+    # tail of codec.inflate.batch: CRCs over the shared pool and the
+    # bytes copy, after the last lane of a shard is delivered.
+    ("verify", "V", ("codec.inflate.verify",)),
     # Symmetric device write path (ops/deflate.py +
     # runtime/device_write.py): Huffman table builds and resident
     # encode→deflate chunks — the write-side device work, separable
     # from read-side kernels in the verdict.
     ("device_write", "W", ("device.deflate.",)),
     # HBM-resident fused decode (runtime/columnar.py): ColumnarBatch
-    # build (upload-or-in-place parse chain), lazy per-column fetches,
+    # build (upload-or-in-place parse chain; columnar.batch.stage is
+    # the host copy of the decoded blob into its padded upload buffer
+    # inside it), lazy per-column fetches,
     # the CIGAR pass for the alignment ends, and release events
     # carrying the batch's d2h-avoided bytes; with them the host side
     # of windowed depth, which is those fetches, that pass and the
@@ -381,8 +392,8 @@ STALL_CATEGORIES = {"emit_stall", "retry", "quarantine", "watchdog"}
 # hedge-wasted time ranks last among work: it is burned concurrency,
 # attributed to its own bucket so the --analyze verdict can name it.
 WORK_PRIORITY = ("device", "transfer", "dispatch", "device_write",
-                 "columnar",
-                 "decode", "encode", "deflate",
+                 "columnar", "verify",
+                 "decode", "write_slice", "encode", "deflate",
                  "stage", "fetch", "hedge", "hedge_wasted",
                  # service queue wait ranks last: it only wins instants
                  # where nothing is making progress — lanes parked in
@@ -406,6 +417,17 @@ ADVICE = {
     "decode": "CPU-bound record decode: raise executor_workers or "
               "enable the device codec",
     "encode": "CPU-bound record encode: raise writer_workers",
+    "write_slice": "the writers' slice of the batch dominates: a "
+                   "device-backed batch is parsed on the host once, by "
+                   "one writer, while the others wait (bam.write.slice "
+                   "inside bam.write.encode) — keep the write on the "
+                   "resident encode path, or materialise before the "
+                   "writers start",
+    "verify": "the host's check of device-decoded blocks dominates "
+              "(codec.inflate.verify: CRCs and the bytes copy after "
+              "the device has answered) — it runs per shard on the "
+              "decode worker; smaller splits overlap it with other "
+              "shards' launches",
     "deflate": "CPU-bound compression: raise writer_workers (the "
                "native codec already threads within a shard)",
     "stage": "staging-latency-bound writes: raise writer_workers / "
@@ -474,6 +496,14 @@ ADVICE = {
                    "p99 in the queue; serve.admission{tenant=} names "
                    "who is queuing",
 }
+
+
+# Spans of the hand-over between the device's answer and the next
+# kernel, by the word --analyze prints them under (device.transfer
+# joins them by its site label).
+HAND_OVER = {"codec.inflate.verify": "verify",
+             "columnar.batch.stage": "stage",
+             "bam.write.slice": "write_slice"}
 
 
 def bucket_of(name: str) -> Optional[str]:
@@ -696,6 +726,31 @@ def analyze(spans, run, runs, dropped: int = 0) -> str:
             "copy chunks a launch crossed an output word's boundary"
             + (f"; {len(full)} full launches of {min(full):,} to "
                f"{max(full):,} supersteps" if full else ""))
+        out.append("")
+
+    # the hand-over between the device's answer and the next kernel:
+    # the host check of the decoded blob, its copy into the padded
+    # upload buffer, the uploads by site, and the writers' slice
+    hand: Dict[str, List[float]] = defaultdict(lambda: [0, 0.0, 0])
+    for s in spans:
+        labels = s.get("labels") or {}
+        key = HAND_OVER.get(s["name"])
+        if s["name"] == "device.transfer" and "site" in labels:
+            key = f"transfer{{site={labels['site']}}}"
+        if key is None:
+            continue
+        row = hand[key]
+        row[0] += 1
+        row[1] += s["dur"]
+        try:
+            row[2] += int(labels.get("bytes", 0))
+        except (TypeError, ValueError):
+            pass
+    if hand:
+        out.append("hand_over: " + "; ".join(
+            f"{key} {fmt_s(sec).strip()} in {n}"
+            + (f" ({nbytes / 1e6:.1f} MB)" if nbytes else "")
+            for key, (n, sec, nbytes) in sorted(hand.items())))
         out.append("")
 
     top = order[0]

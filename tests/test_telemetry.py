@@ -356,7 +356,7 @@ def test_without_jax_a_span_opens_no_annotation(monkeypatch):
     span's sake): the span books in the ring as before."""
     monkeypatch.setattr(tracing, "_annotation_cls", None)
     monkeypatch.delitem(sys.modules, "jax")
-    assert tracing._annotation("executor.fetch") is tracing._NO_ANNOTATION
+    assert tracing.annotate("executor.fetch") is tracing._NO_ANNOTATION
     with span("executor.fetch", shard=1):
         pass
     assert "jax" not in sys.modules
