@@ -603,7 +603,9 @@ class BamSource:
         the shard into a device-backed ``ColumnarBatch`` in the same
         launch chain as the device codecs — when the SIMD inflate
         kernel decoded the blocks, its still-HBM-resident output is
-        parsed in place (no re-upload).  Every salvage/tolerant path
+        parsed in place (no re-upload), or, decoded through the decode
+        service, the service's padded host buffer is uploaded as it is
+        (no staging copy).  Every salvage/tolerant path
         stays host-side, so error semantics (and owner-shard
         quarantine accounting) are identical.
         """
@@ -652,12 +654,16 @@ class BamSource:
         # tripping the corruption handler on valid data
         if resident and sum(b.usize for b in blocks) >= 2 ** 31:
             resident = False
-        dev_handle = None
+        dev_handle = staged = None
         try:
             if resident:
                 blob, dev_handle = inflate_blocks(
                     data, blocks, base=lo_block, as_array=True,
                     keep_device=True)
+                if isinstance(dev_handle, np.ndarray):
+                    # the service route's slot holds the padded host
+                    # buffer the blob heads, not a device handle
+                    staged, dev_handle = dev_handle, None
             else:
                 blob = inflate_blocks(
                     data, blocks, base=lo_block, as_array=True)
@@ -698,7 +704,7 @@ class BamSource:
 
                 batch = ColumnarBatch.from_blob(
                     record_bytes, offsets, n_ref=header.n_ref,
-                    device_words=words, origin=lo_u,
+                    device_words=words, origin=lo_u, staged=staged,
                     mesh=mesh_for_storage(self._storage))
             else:
                 batch = decode_records(

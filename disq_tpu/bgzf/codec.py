@@ -88,9 +88,11 @@ def inflate_blocks(
     ``keep_device`` changes the return to ``(blob, handle)``: on the
     device path's direct route the handle is the still-HBM-resident
     kernel output (``DeviceBlobHandle``) the fused resident-decode
-    chain parses without re-uploading; the service route and the host
-    path return ``(blob, None)`` and the caller falls back to one
-    upload.
+    chain parses without re-uploading; on its service route it is the
+    padded host buffer ``blob`` is the head of (a uint8 array of the
+    parse's upload shape), which the chain uploads as it is; the host
+    path returns ``(blob, None)`` and the caller copies the blob into
+    an upload buffer.
     """
     import numpy as np
 
@@ -162,16 +164,25 @@ def inflate_blocks_device(
       alone.
 
     Payloads are sliced as ``memoryview``\\ s (nothing here copies the
-    compressed bytes); batch CRC verification runs threaded, off the
-    kernel's critical path (the service keeps decoding other shards'
-    chunks while this thread verifies), under the span
-    ``codec.inflate.verify{blocks, bytes}`` with the ``tobytes`` copy.
-    ``as_array`` returns the blob as a uint8 array instead of bytes.
+    compressed bytes).  Every block is held to its footer's CRC32
+    before this returns.  On the service route the service does it,
+    launch by launch as the lanes land, on the host pool
+    (``submit_inflate(crcs=...)``): the blocks' CRCs are read here and
+    go with the submission, and ``result()`` hands out checked bytes.
+    On the direct route ``_verify_block_crcs`` checks the whole batch
+    once the last launch is back, threaded, with the device idle under
+    it (as the service route's check was for a pass's last split until
+    it moved under the launches).  The span
+    ``codec.inflate.verify{blocks, bytes}`` runs from the device's
+    answer to the return: the direct route's check, and on both the
+    ``tobytes`` copy.  ``as_array`` returns the blob as a uint8 array
+    instead of bytes.
 
-    ``keep_device`` returns ``(blob, DeviceBlobHandle-or-None)``: on
-    the direct route the kernel's output chunks stay resident in HBM
-    for the fused parse chain (the service route hands back None: its
-    outputs live in the owner submissions' host blobs)."""
+    ``keep_device`` returns ``(blob, handle)``: on the direct route a
+    ``DeviceBlobHandle`` (the kernel's output chunks stay resident in
+    HBM for the fused parse chain), on the service route the padded
+    buffer the service decoded into (``Submission.base``; ``blob`` is
+    its head), which the parse uploads whole."""
     import numpy as np
 
     if not blocks:
@@ -187,9 +198,14 @@ def inflate_blocks_device(
     from disq_tpu.runtime import device_service
 
     handle = None
-    if device_service.enabled():
-        blob, offsets = device_service.get_service().submit_inflate(
-            payloads, usizes).result()
+    on_service = device_service.enabled()
+    if on_service:
+        crcs = [_footer_crc(data, b, base)
+                for b in blocks] if verify_crc else None
+        sub = device_service.get_service().submit_inflate(
+            payloads, usizes, crcs=crcs, padded=keep_device)
+        blob, offsets = sub.result()
+        handle = sub.base
     else:
         from disq_tpu.ops.inflate_simd import inflate_payloads_simd
 
@@ -205,30 +221,37 @@ def inflate_blocks_device(
 
     with span("codec.inflate.verify", blocks=len(blocks),
               bytes=len(blob)):
-        try:
-            if verify_crc:
+        if verify_crc and not on_service:
+            try:
                 _verify_block_crcs(data, blocks, base, blob, offsets)
-        except BaseException:
-            if handle is not None:
-                handle.release()
-            raise
+            except BaseException:
+                if handle is not None:
+                    handle.release()
+                raise
         out = blob if as_array else blob.tobytes()
     return (out, handle) if keep_device else out
 
 
+def _footer_crc(data, block: BgzfBlock, base: int) -> int:
+    """The CRC32 a block's footer states for its decoded bytes."""
+    return struct.unpack_from(
+        "<I", data, block.pos - base + block.csize - BGZF_FOOTER_SIZE)[0]
+
+
 def _verify_block_crcs(data, blocks, base, blob, offsets) -> None:
-    """Batch CRC check of device-decoded output against the BGZF
-    footers, over zero-copy blob slices (no per-block bytes).  Big
+    """The direct route's batch CRC check (the service route checks
+    launch by launch: ``device_service.Submission.check``) of
+    device-decoded output against the BGZF footers, over zero-copy blob
+    slices (no per-block bytes), after the batch's last launch.  Big
     batches fan out over the shared pool — ``zlib.crc32`` releases the
-    GIL, so with the decode service on, one shard's verification
-    overlaps the dispatcher's next chunks instead of serializing the
-    whole queue behind it."""
+    GIL.  Booked into ``codec.inflate.crc_blocks{at=tail}``."""
+    from disq_tpu.runtime.tracing import counter
+
+    counter("codec.inflate.crc_blocks").inc(len(blocks), at="tail")
 
     def check(i: int) -> None:
-        b = blocks[i]
-        crc = struct.unpack_from(
-            "<I", data, b.pos - base + b.csize - BGZF_FOOTER_SIZE)[0]
-        if zlib.crc32(blob[int(offsets[i]): int(offsets[i + 1])]) != crc:
+        if zlib.crc32(blob[int(offsets[i]): int(offsets[i + 1])]) \
+                != _footer_crc(data, blocks[i], base):
             raise ValueError(f"BGZF CRC mismatch at block {i}")
 
     if len(blocks) >= 32:

@@ -107,6 +107,11 @@ def _bind(lib: ctypes.CDLL) -> None:
         u8p, i64p, i32p, i32p, i32p, ctypes.c_int64, u8p, i64p,
         ctypes.c_int32, ctypes.c_int32,
     ]
+    lib.disq_crc32_check.restype = ctypes.c_int64
+    lib.disq_crc32_check.argtypes = [
+        u8p, i64p, i64p, ctypes.c_int64,
+        ctypes.POINTER(ctypes.c_uint32),
+    ]
     lib.disq_bgzf_deflate_many.restype = ctypes.c_int64
     lib.disq_bgzf_deflate_many.argtypes = [
         u8p, i64p, ctypes.c_int64, u8p, ctypes.c_int64, i32p,
@@ -293,6 +298,23 @@ def inflate_blocks_native(
     if rc < 0:
         raise ValueError(f"BGZF CRC mismatch at block {-rc - 1}")
     return out if as_array else out.tobytes()
+
+
+def crc32_check_native(blob: np.ndarray, offsets: np.ndarray, idx,
+                       expect: np.ndarray) -> int:
+    """CRC32 of the blocks ``idx`` of a decoded blob where they lie
+    (block ``i`` is ``blob[offsets[i]:offsets[i + 1]]``) against
+    ``expect[i]``, in one call without the interpreter lock; returns
+    the position in ``idx`` of the first block that differs, or -1."""
+    lib = _load()
+    arr = _as_u8(blob)
+    offsets = np.ascontiguousarray(offsets, dtype=np.int64)
+    idx = np.ascontiguousarray(idx, dtype=np.int64)
+    expect = np.ascontiguousarray(expect, dtype=np.uint32)
+    return int(lib.disq_crc32_check(
+        _ptr(arr, ctypes.c_uint8), _ptr(offsets, ctypes.c_int64),
+        _ptr(idx, ctypes.c_int64), len(idx),
+        _ptr(expect, ctypes.c_uint32)))
 
 
 def decode_records_native(buf, offsets: np.ndarray):

@@ -261,6 +261,7 @@ class ColumnarBatch:
         interpret: Optional[bool] = None,
         mesh=None,
         coarse: bool = False,
+        staged: Optional[np.ndarray] = None,
     ) -> "ColumnarBatch":
         """Fused device build: one upload (skipped when
         ``device_words`` carries the inflate kernels' still-resident
@@ -269,10 +270,12 @@ class ColumnarBatch:
 
         ``blob``/``offsets`` are the host record bytes + record-offset
         manifest (held for ragged columns and identity with the host
-        parser); ``origin`` rebases the offsets into ``device_words``
-        when that blob covers more than the record range; ``coarse``
-        uploads the blob at ``parse_columns_resident``'s coarse
-        shapes."""
+        parser); ``origin`` rebases the offsets into ``device_words``,
+        or into ``staged`` (the decode service's padded buffer, of
+        which ``blob`` is the bytes from ``origin`` on: uploaded whole,
+        not copied), when that blob covers more than the record range;
+        ``coarse`` uploads the blob at ``parse_columns_resident``'s
+        coarse shapes."""
         from disq_tpu.runtime.device_pipeline import parse_columns_resident
         from disq_tpu.runtime.tracing import span
 
@@ -291,13 +294,16 @@ class ColumnarBatch:
         self._mesh = mesh
         with span("columnar.batch.build", records=n,
                   bytes=int(offsets[-1])):
-            # origin rebases offsets into a full-shard device blob;
-            # the upload fallback stages exactly the record slice, so
-            # its offsets are already correct
+            # origin rebases offsets into a full-shard blob (the
+            # device's, or the service's staged host buffer); the copy
+            # path stages exactly the record slice, so its offsets are
+            # already correct
+            whole = device_words is not None or staged is not None
             cols, _word_bytes, _ = parse_columns_resident(
                 blob, self._offsets, words_dev=device_words,
-                origin=origin if device_words is not None else 0,
-                interpret=interpret, mesh=mesh, coarse=coarse)
+                origin=origin if whole else 0,
+                interpret=interpret, mesh=mesh, coarse=coarse,
+                staged=staged)
             # keep only the 8 reachable fixed columns resident (plus
             # next_refid for validation below); the 4 parse-only
             # length fields are derivable from the ragged offsets and

@@ -47,22 +47,7 @@ import jax.numpy as jnp
 
 
 from disq_tpu.util import bucket_pow2 as _bucket
-
-
-def _pad_quantum(n: int, coarse: bool = False) -> int:
-    """Compile-shape quantization with bounded waste: power-of-two
-    below 64K units (cheap), then 1/16-octave steps — retraces stay a
-    handful per octave while zero-pad overhead is capped at ~6%
-    (plain power-of-two would zero-fill and upload up to 2x the blob,
-    defeating the transfer win the resident path exists for).
-    ``coarse``: the power of two throughout, for a caller that bounds
-    its sizes itself and whose many sizes would each be a shape (an
-    indexed read's chunk runs, a launch's lanes a worker each: they
-    fill the bucket they are cut to)."""
-    if coarse or n <= 1 << 16:
-        return _bucket(n)
-    step = 1 << max((n - 1).bit_length() - 5, 0)
-    return -(-n // step) * step
+from disq_tpu.util import pad_quantum as _pad_quantum
 
 
 def gather_record_words(blob_words: jax.Array,
@@ -287,6 +272,7 @@ def parse_columns_resident(
     interpret: bool = False,
     mesh=None,
     coarse: bool = False,
+    staged: Optional[np.ndarray] = None,
 ) -> Tuple[Dict[str, jax.Array], int, int]:
     """One fused upload(+)gather(+)parse launch chain producing the raw
     device column dict (bucket-padded; callers slice to ``n``).
@@ -295,6 +281,15 @@ def parse_columns_resident(
     upload entirely — the parse reads the inflate kernel's output where
     it already lives in HBM; ``origin`` rebases record offsets into
     that blob. Returns (cols, resident word bytes, record count).
+
+    ``staged`` is a host buffer that is an upload buffer already (the
+    decode service's ``Submission.base``: uint8, ``_pad_quantum`` words
+    long, zero past the decoded bytes) and holds ``blob`` from byte
+    ``origin`` on: it goes up whole, as it is, where any other host
+    blob is first copied into such a buffer.  Either way the span
+    ``columnar.batch.stage`` and the counter
+    ``columnar.batch.stage_bytes{how=in_place|copied}`` book the padded
+    size.
 
     With ``mesh`` (runtime/mesh.py batch-axis mesh) the parse runs as
     ONE sharded program: the word blob replicates to every device (h2d
@@ -324,11 +319,16 @@ def parse_columns_resident(
         # per split, and an exact-shape upload would retrace the parse
         # jit once per shard — quantized shapes keep compiles to a
         # handful per run at <=~6% pad overhead on big shards
-        nwords = _pad_quantum(max(1, (len(blob) + 3) // 4), coarse)
-        with span("columnar.batch.stage", bytes=nwords * 4):
-            padded = np.empty(nwords * 4, np.uint8)
-            padded[: len(blob)] = blob
-            padded[len(blob):] = 0
+        nbytes = (staged.nbytes if staged is not None else 4 * _pad_quantum(
+            max(1, (len(blob) + 3) // 4), coarse))
+        with span("columnar.batch.stage", bytes=nbytes):
+            padded = staged
+            if padded is None:
+                padded = np.empty(nbytes, np.uint8)
+                padded[: len(blob)] = blob
+                padded[len(blob):] = 0
+        counter("columnar.batch.stage_bytes").inc(
+            nbytes, how="copied" if staged is None else "in_place")
         # the replicated blob lands on every device: book each copy
         word_bytes = padded.nbytes * n_dev
         up = word_bytes + starts_host.nbytes

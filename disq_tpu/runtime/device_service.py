@@ -25,6 +25,19 @@ and writes each decoded lane straight from the kernel's transposed
 output into the owning submission's preallocated blob — zero
 intermediate ``bytes`` objects on the device path.
 
+An inflate submission's blob is the finished product when its last
+lane lands.  Given the blocks' footer CRC32s, the service checks each
+launch's lanes against them as it delivers that launch — a few
+host-pool tasks a launch, never on the dispatcher thread — and
+``result()`` returns only when every block is stored AND checked (a
+mismatch fails the owner with ``BGZF CRC mismatch at block i``), so no
+whole-split check is left for after the pass's last launch, when the
+device has nothing else to do.  Asked for a ``padded`` blob (the caller
+will parse it on the device), the service allocates it at the parse's
+upload shape (``util.pad_quantum`` words, the tail zeroed at
+submission) and keeps that buffer as ``Submission.base``: the parse
+uploads it whole instead of copying the blob into a buffer of its own.
+
 Multi-chip (the mesh-native pipeline, ``runtime/mesh.py``): when the
 mesh knob is armed at service creation, each codec keeps one sub-queue
 PER DEVICE and the single dispatcher feeds them all — a submission's
@@ -75,6 +88,7 @@ from __future__ import annotations
 
 import threading
 import time
+import zlib
 from collections import Counter, deque
 from typing import Any, Deque, Dict, List, Optional, Sequence, Tuple
 
@@ -95,6 +109,10 @@ from disq_tpu.runtime.tracing import (
 
 LANES = 128  # mirrors ops/inflate_simd.LANES (not imported: keep this
 #              module importable without pulling jax in)
+# blocks a CRC task: a full launch's lanes go to the host pool in four
+# tasks of ~2 MB, not one a block (a task's hand-over costs what the
+# CRC of a block does) and not one a launch (the pool has four threads)
+CHECK_LANES = 32
 
 
 class _Lane:
@@ -122,22 +140,34 @@ class Submission:
     (usizes are always known for BGZF) that lanes are written into as
     they materialize; rANS submissions collect per-stream ``parts``.
     The first failing owner lane records the error and releases the
-    waiter — late lanes of a failed submission are dropped."""
+    waiter — late lanes of a failed submission are dropped.
+
+    With ``crcs`` (the blocks' footer CRC32s) every block is pending
+    twice, once until it is stored and once until ``check`` has held
+    its stored bytes to its CRC, so ``result()`` hands out checked
+    bytes only.  ``base`` is the buffer ``blob`` is a prefix of when
+    the submission was made ``padded`` (else None): word-aligned,
+    ``util.pad_quantum`` words long, zero past the blob."""
 
     __slots__ = ("_event", "_lock", "_pending", "_error", "blob",
-                 "offsets", "parts")
+                 "offsets", "parts", "crcs", "base")
 
     def __init__(self, blob: Optional[np.ndarray] = None,
                  offsets: Optional[np.ndarray] = None,
-                 parts_n: Optional[int] = None) -> None:
+                 parts_n: Optional[int] = None,
+                 crcs: Optional[np.ndarray] = None,
+                 base: Optional[np.ndarray] = None) -> None:
         self._event = threading.Event()
         self._lock = threading.Lock()
         self.blob = blob
         self.offsets = offsets
+        self.crcs = crcs
+        self.base = base
         self.parts: Optional[List[Optional[bytes]]] = (
             [None] * parts_n if parts_n is not None else None)
         self._pending = (parts_n if parts_n is not None
-                         else len(offsets) - 1)
+                         else (len(offsets) - 1)
+                         * (1 if crcs is None else 2))
         self._error: Optional[BaseException] = None
         if self._pending == 0:
             self._event.set()
@@ -169,6 +199,51 @@ class Submission:
             if self._pending <= 0:
                 self._event.set()
 
+    def check(self, indices: Sequence[int]) -> None:
+        """Hold the stored blocks ``indices`` to their footer CRC32s
+        (any thread, after their ``deliver``); a no-op without
+        ``crcs``.  The first mismatch fails the submission with the
+        direct route's error; each index settles its second pending
+        unit either way."""
+        if self.crcs is None:
+            return
+        try:
+            # failed already: its bytes go nowhere
+            if self._error is None:
+                bad = self._first_bad(indices)
+                _counter("codec.inflate.crc_blocks").inc(
+                    len(indices) if bad < 0 else bad + 1, at="launch")
+                if bad >= 0:
+                    self.fail(ValueError(
+                        f"BGZF CRC mismatch at block {indices[bad]}"))
+        except BaseException as e:  # noqa: BLE001 — never strand a waiter
+            self.fail(e)
+        with self._lock:
+            self._pending -= len(indices)
+            if self._pending <= 0:
+                self._event.set()
+
+    def _first_bad(self, indices: Sequence[int]) -> int:
+        """The position in ``indices`` of the first block whose stored
+        bytes do not have its CRC32, or -1.  One native call a task
+        when the host library is built: a ``zlib.crc32`` a block takes
+        and drops the interpreter lock a block, 128 times a launch, and
+        each time the dispatcher's Python (packing the next launch)
+        waits to be woken (measured: its ``pack`` 3 -> 8 ms a
+        launch)."""
+        try:
+            from disq_tpu.native import crc32_check_native
+
+            return crc32_check_native(
+                self.blob, self.offsets, indices, self.crcs)
+        except ImportError:
+            pass
+        for k, i in enumerate(indices):
+            lo, hi = int(self.offsets[i]), int(self.offsets[i + 1])
+            if zlib.crc32(self.blob[lo:hi]) != self.crcs[i]:
+                return k
+        return -1
+
     def fail(self, exc: BaseException) -> None:
         with self._lock:
             if self._error is None:
@@ -177,9 +252,10 @@ class Submission:
             self._event.set()
 
     def result(self, timeout: Optional[float] = None):
-        """Block until every lane landed (or the first owner-lane
-        error); returns ``(blob, offsets)`` for inflate submissions,
-        the parts list for rANS ones."""
+        """Block until every lane landed and, with ``crcs``, was
+        checked (or the first owner-lane error); returns ``(blob,
+        offsets)`` for inflate submissions, the parts list for rANS
+        ones."""
         if not self._event.wait(timeout):
             raise TimeoutError("device decode service result timed out")
         if self._error is not None:
@@ -197,13 +273,15 @@ class _InflateEngine:
     ``host_map`` (from the owning service) fans multi-lane host-zlib
     fallbacks out over the service's host pool so a degraded shard's
     re-inflates don't serialize on the dispatcher thread and stall
-    every co-batched shard's queue."""
+    every co-batched shard's queue; ``host_check`` hands a launch's
+    delivered lanes to the same pool for their CRCs."""
 
     kind = "inflate"
 
-    def __init__(self, interpret: bool, host_map) -> None:
+    def __init__(self, interpret: bool, host_map, host_check) -> None:
         self._interpret = bool(interpret)
         self._host_map = host_map
+        self._host_check = host_check
 
     def launch(self, lanes: Sequence[_Lane], labels: Dict[str, Any]):
         import jax.numpy as jnp
@@ -244,6 +322,7 @@ class _InflateEngine:
         with _span("device.launch.deliver", **labels):
             IS.ARENAS.release(("inflate", cw), arena)
             flagged: List[_Lane] = []
+            stored: Dict[Submission, List[int]] = {}
             for j, lane in enumerate(lanes):
                 n, status = int(meta[0, j]), int(meta[1, j])
                 if status != 0 or n != lane.expect:
@@ -254,6 +333,9 @@ class _InflateEngine:
                 else:
                     IS.last_stats["device_lanes"] += 1
                     lane.sub.deliver(lane.index, lanes_u8[j, :n])
+                    if lane.sub.crcs is not None:
+                        stored.setdefault(lane.sub, []).append(lane.index)
+            self._host_check(stored)
             if flagged:
                 self._host_map(
                     flagged,
@@ -404,12 +486,14 @@ class DeviceDecodeService:
             from disq_tpu.util import pallas_interpret
 
             interpret = pallas_interpret()
-        # outstanding fire-and-forget host-fallback lanes (drained at
-        # close so shutdown never strands a waiter); the pool itself is
-        # the process-wide disq_tpu.util.shared_host_pool
-        self._fallback_pending = 0
+        # outstanding fire-and-forget pool tasks: host-fallback lanes
+        # and launches' CRC checks (drained at close so shutdown never
+        # strands a waiter); the pool itself is the process-wide
+        # disq_tpu.util.shared_host_pool
+        self._pool_pending = 0
         self._engines = {
-            "inflate": _InflateEngine(interpret, self._host_map),
+            "inflate": _InflateEngine(
+                interpret, self._host_map, self._host_check),
             "rans": _RansEngine(interpret, self._host_map),
             "deflate": _DeflateEngine(interpret, self._host_map),
         }
@@ -461,20 +545,39 @@ class DeviceDecodeService:
     def alive(self) -> bool:
         return self._thread.is_alive() and not self._closed
 
-    def submit_inflate(self, payloads: Sequence,
-                       usizes: Sequence[int]) -> Submission:
+    def submit_inflate(self, payloads: Sequence, usizes: Sequence[int],
+                       crcs: Optional[Sequence[int]] = None,
+                       padded: bool = False) -> Submission:
         """Submit one shard's raw-DEFLATE block batch; the result is
         ``(blob, offsets)`` — decoded bytes of every block, contiguous
         in submission order.  Oversize blocks decode on THIS thread
-        (host zlib), exactly like the per-shard dispatch."""
+        (host zlib), exactly like the per-shard dispatch.
+
+        ``crcs``: the blocks' expected CRC32s; every block is then
+        checked against its own as its launch is delivered, before
+        ``result()`` returns (``Submission.check``).  ``padded``: the
+        blob is the head of a buffer of the resident parse's upload
+        shape (``Submission.base``), its tail zeroed here."""
         from disq_tpu.ops import inflate_simd as IS
 
         n = len(payloads)
         offsets = np.zeros(n + 1, dtype=np.int64)
         np.cumsum(np.asarray([int(u) for u in usizes], np.int64),
                   out=offsets[1:])
-        sub = Submission(blob=np.empty(int(offsets[-1]), np.uint8),
-                         offsets=offsets)
+        total = int(offsets[-1])
+        base = None
+        if padded:
+            from disq_tpu.util import pad_quantum
+
+            base = np.empty(
+                pad_quantum(max(1, (total + 3) // 4)) * 4, np.uint8)
+            base[total:] = 0
+            blob = base[:total]
+        else:
+            blob = np.empty(total, np.uint8)
+        if crcs is not None:
+            crcs = np.asarray(crcs, np.uint32)
+        sub = Submission(blob=blob, offsets=offsets, crcs=crcs, base=base)
         ctx = _current_trace()
         lanes: List[_Lane] = []
         for i, p in enumerate(payloads):
@@ -483,6 +586,7 @@ class DeviceDecodeService:
                 _counter("device.host_fallback_blocks").inc(
                     reason="oversize")
                 sub.deliver_local(i, IS.host_inflate(p, int(usizes[i])))
+                sub.check((i,))
             else:
                 # ts stamped at enqueue (see _enqueue)
                 lanes.append(_Lane(sub, i, p, int(usizes[i]), 0.0, ctx))
@@ -597,40 +701,59 @@ class DeviceDecodeService:
                 lane.sub.fail(e)
             else:
                 lane.sub.deliver(lane.index, val)
+                lane.sub.check((lane.index,))
 
         if len(lanes) <= 1:
             for lane in lanes:
                 one(lane)
             return
-        from disq_tpu.util import shared_host_pool
-
-        def tracked(lane: _Lane) -> None:
-            try:
-                one(lane)
-            finally:
-                with self._cond:
-                    self._fallback_pending -= 1
-                    self._cond.notify_all()
-
         # fire-and-forget: each lane delivers (or fails its owner) from
         # the pool; the dispatcher goes straight back to launching
+        self._pool_submit([(one, lane) for lane in lanes])
+
+    def _host_check(self, stored: Dict[Submission, List[int]]) -> None:
+        """CRC the blocks one launch just stored, by owner, on the host
+        pool: ``CHECK_LANES`` blocks a task.  The dispatcher only hands
+        them over (8 MB of CRC a launch would make it the wall of a
+        mesh read, where a launch lands every ~19 ms)."""
+        self._pool_submit([
+            (sub.check, idx[k: k + CHECK_LANES])
+            for sub, idx in stored.items()
+            for k in range(0, len(idx), CHECK_LANES)])
+
+    def _pool_submit(self, tasks: List[Tuple[Any, Any]]) -> None:
+        """Run each ``fn(arg)`` on the process-wide host pool, counted
+        in ``_pool_pending`` until it returns (``close`` waits for
+        them); neither ``one`` nor ``Submission.check`` raises."""
+        if not tasks:
+            return
+        from disq_tpu.util import shared_host_pool
+
+        def tracked(fn, arg) -> None:
+            try:
+                fn(arg)
+            finally:
+                with self._cond:
+                    self._pool_pending -= 1
+                    self._cond.notify_all()
+
         with self._cond:
-            self._fallback_pending += len(lanes)
+            self._pool_pending += len(tasks)
         pool = shared_host_pool()
-        for lane in lanes:
-            pool.submit(tracked, lane)
+        for fn, arg in tasks:
+            pool.submit(tracked, fn, arg)
 
     def close(self, timeout: float = 30.0) -> None:
         """Drain the queue (remaining partial chunks flush with
-        ``reason=drain``), wait out any in-flight host-fallback lanes,
-        and stop the dispatcher."""
+        ``reason=drain``), wait out any in-flight host-fallback lanes
+        and CRC checks, and stop the dispatcher."""
         with self._cond:
             self._closed = True
             self._cond.notify_all()
         self._thread.join(timeout)
         with self._cond:
             self._cond.wait_for(
-                lambda: self._fallback_pending <= 0, timeout)
+                lambda: self._pool_pending <= 0, timeout)
 
     # -- dispatcher ---------------------------------------------------------
 

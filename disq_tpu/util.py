@@ -15,6 +15,24 @@ def bucket_pow2(n: int, lo: int = 64) -> int:
         b *= 2
     return b
 
+
+def pad_quantum(n: int, coarse: bool = False) -> int:
+    """Compile-shape quantization with bounded waste: power-of-two
+    below 64K units (cheap), then 1/16-octave steps — retraces stay a
+    handful per octave while zero-pad overhead is capped at ~6%
+    (plain power-of-two would zero-fill and upload up to 2x the blob,
+    defeating the transfer win the resident path exists for).
+    ``coarse``: the power of two throughout, for a caller that bounds
+    its sizes itself and whose many sizes would each be a shape (an
+    indexed read's chunk runs, a launch's lanes a worker each: they
+    fill the bucket they are cut to).  Pure arithmetic, here and not
+    beside the parse that uploads at these shapes, because the decode
+    service allocates a split's blob at them and imports no jax."""
+    if coarse or n <= 1 << 16:
+        return bucket_pow2(n)
+    step = 1 << max((n - 1).bit_length() - 5, 0)
+    return -(-n // step) * step
+
 _HOST_POOL = None
 _HOST_POOL_LOCK = threading.Lock()
 

@@ -148,6 +148,29 @@ static int inflate_one(const uint8_t* src, uint32_t csize, uint8_t* dst,
 }
 #endif
 
+// CRC32 of decoded blocks where they lie: block idx[k] is
+// blob[offsets[idx[k]], offsets[idx[k] + 1]) and has to have the CRC32
+// expect[idx[k]] (its BGZF footer's). Returns the position k of the
+// first block that differs, or -1. One call a batch: the caller (the
+// decode service's per-launch check) holds no interpreter lock inside
+// it, where a zlib.crc32 a block takes and drops the lock a block.
+int64_t disq_crc32_check(const uint8_t* blob, const int64_t* offsets,
+                         const int64_t* idx, int64_t n,
+                         const uint32_t* expect) {
+  for (int64_t k = 0; k < n; ++k) {
+    const int64_t i = idx[k];
+    const uint8_t* p = blob + offsets[i];
+    const size_t len = (size_t)(offsets[i + 1] - offsets[i]);
+#ifdef DISQ_HAVE_LIBDEFLATE
+    const uint32_t got = libdeflate_crc32(0, p, len);
+#else
+    const uint32_t got = (uint32_t)crc32(0L, p, (uInt)len);
+#endif
+    if (got != expect[i]) return k;
+  }
+  return -1;
+}
+
 // Batched BGZF inflate. data: staged compressed bytes; block_off[i] is the
 // offset of block i's *gzip header* within data; hdr_len[i] the header
 // length (12+XLEN); csize[i] the total block size; usize[i] the payload's

@@ -117,9 +117,11 @@ CATEGORIES = (
     # ran dry, so the host side upstream (fetch, parse, emit) is the
     # one to look at.
     ("service_idle", "i", ("device.service.idle",)),
-    # The host's check of what the device decoded (bgzf/codec.py), the
-    # tail of codec.inflate.batch: CRCs over the shared pool and the
-    # bytes copy, after the last lane of a shard is delivered.
+    # The host's work on what the device decoded (bgzf/codec.py), the
+    # tail of codec.inflate.batch after the last lane of a shard is
+    # delivered: the direct route's CRCs over the shared pool, and the
+    # bytes copy (the decode service checks each launch's blocks as it
+    # delivers them, so its route leaves the copy alone here).
     ("verify", "V", ("codec.inflate.verify",)),
     # Symmetric device write path (ops/deflate.py +
     # runtime/device_write.py): Huffman table builds and resident
@@ -129,7 +131,8 @@ CATEGORIES = (
     # HBM-resident fused decode (runtime/columnar.py): ColumnarBatch
     # build (upload-or-in-place parse chain; columnar.batch.stage is
     # the host copy of the decoded blob into its padded upload buffer
-    # inside it), lazy per-column fetches,
+    # inside it, a view where the decode service decoded into that
+    # buffer), lazy per-column fetches,
     # the CIGAR pass for the alignment ends, and release events
     # carrying the batch's d2h-avoided bytes; with them the host side
     # of windowed depth, which is those fetches, that pass and the
@@ -426,8 +429,9 @@ ADVICE = {
     "verify": "the host's check of device-decoded blocks dominates "
               "(codec.inflate.verify: CRCs and the bytes copy after "
               "the device has answered) — it runs per shard on the "
-              "decode worker; smaller splits overlap it with other "
-              "shards' launches",
+              "decode worker; route the read through the decode "
+              "service (DISQ_TPU_DEVICE_SERVICE=1), which checks each "
+              "launch's blocks under the launches that follow",
     "deflate": "CPU-bound compression: raise writer_workers (the "
                "native codec already threads within a shard)",
     "stage": "staging-latency-bound writes: raise writer_workers / "

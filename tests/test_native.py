@@ -86,6 +86,49 @@ class TestDeflateInflate:
             inflate_blocks(bytes(comp), blocks)
 
 
+class TestCrc32CheckNative:
+    def _blob(self):
+        import zlib
+
+        rng = np.random.default_rng(4)
+        sizes = [0, 1, 65280, 7, 4096, 0, 333]
+        offsets = np.zeros(len(sizes) + 1, np.int64)
+        np.cumsum(sizes, out=offsets[1:])
+        blob = rng.integers(0, 256, int(offsets[-1]), np.uint8)
+        expect = np.array(
+            [zlib.crc32(blob[offsets[i]: offsets[i + 1]])
+             for i in range(len(sizes))], np.uint32)
+        return blob, offsets, expect
+
+    def test_sound_blocks_in_any_order_of_indices(self):
+        """Every block against zlib's CRC32 of the same bytes (empty
+        blocks too), whichever blocks are asked for and in whichever
+        order: -1."""
+        from disq_tpu.native import crc32_check_native
+
+        blob, offsets, expect = self._blob()
+        for idx in ([0, 1, 2, 3, 4, 5, 6], [6, 2, 0], [], [5]):
+            assert crc32_check_native(blob, offsets, idx, expect) == -1
+
+    @pytest.mark.parametrize("wrong", [[2], [0, 4], [6]])
+    def test_names_the_position_of_the_first_wrong_block(self, wrong):
+        """The answer is a position in ``idx``, not a block number; a
+        wrong block that is not asked for is not seen."""
+        from disq_tpu.native import crc32_check_native
+
+        blob, offsets, expect = self._blob()
+        expect[wrong] ^= 0x80000000
+        idx = [6, 5, 4, 3, 2, 1, 0]
+        assert crc32_check_native(blob, offsets, idx, expect) == min(
+            idx.index(w) for w in wrong)
+        rest = [i for i in idx if i not in wrong]
+        assert crc32_check_native(blob, offsets, rest, expect) == -1
+        # a view into a larger buffer (the service's padded base)
+        base = np.concatenate([blob, np.zeros(64, np.uint8)])
+        assert crc32_check_native(
+            base[: len(blob)], offsets, rest, expect) == -1
+
+
 class TestSegmentGatherNative:
     def test_matches_numpy_reference(self):
         segment_gather_native = native.segment_gather_native
