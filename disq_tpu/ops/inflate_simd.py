@@ -10,13 +10,15 @@ vector.
 Per superstep (one ``lax.while_loop`` iteration), every lane advances
 its own predicated state machine by pure vector selects; rare events
 (table finalization, dyn-block entry, table-phase stores) are gated
-with ``pl.when``, and the refill/far-history sweeps behind ``lax.cond``
-whole-warp gates. The phases of a superstep run in a fixed order, each
-on the state the one before left: phase A (header / stored / one
-table-build step / one literal-or-length symbol, or a literal pair),
-phase B (the distance of the length phase A has just read), then the
-copy phase (the lanes inside a match, those phase B has just put there
-included). So a token costs one superstep: a literal (two when the
+with ``pl.when``, the far-history sweeps behind a ``lax.cond``
+whole-warp gate, and the sweep of the compressed words behind a
+``lax.cond`` on the loop counter (once in ``COMP_PERIOD`` supersteps:
+no reduction across the lanes). The phases of a superstep run in a
+fixed order, each on the state the one before left: phase A (header /
+stored / one table-build step / one literal-or-length symbol, or a
+literal pair), phase B (the distance of the length phase A has just
+read), then the copy phase (the lanes inside a match, those phase B
+has just put there included). So a token costs one superstep: a literal (two when the
 next token is a literal too), or a match's length + distance + first
 copy chunk together; a long match then takes one superstep more per chunk. An
 emit is placed at the output's byte offset ``off`` and may run past the
@@ -28,7 +30,9 @@ starts. ``meta`` row 2 carries the launch's superstep count (counter
 ``device.inflate.supersteps``), row 3 how many of them read history
 past the ring (``device.inflate.far_supersteps``), row 4 each lane's
 count of copy chunks that ran past the word boundary they started in
-(summed over the lanes: ``device.inflate.crossing_chunks``). A lane
+(summed over the lanes: ``device.inflate.crossing_chunks``), row 5 the
+supersteps in which the compressed buffer was swept
+(``device.inflate.comp_fetches``). A lane
 emits 1-2 bytes per literal superstep, up to 4 per stored superstep,
 and up to 4, 8 or 16 per copy superstep (d < 8 / d >= 8 / d >= 16).
 All data-dependent indexing is a one-hot sweep: the row gather
@@ -42,9 +46,16 @@ are its mirror image, ``_scatter_tile``: a superstep's up-to-four
 output words are placed once, on single registers, in two (8,128) tile
 patches, and a sweep merges the patches into a lane's two aligned
 tiles with two compares a stored register. Big-buffer
-sweeps (comp refill, output RMW, far-history reads) are additionally
-*windowed*: lanes advance in rough lockstep, so each slab's sweep is
-skipped when the live row window [min, max] misses it. Bool (1,128)
+sweeps (the comp window, output RMW, far-history reads) are
+additionally *windowed*: lanes advance in rough lockstep, so each
+slab's sweep is skipped when the live tile window [min, max] misses
+it. The compressed words are not read where they are consumed: a lane
+takes at most one word at each of a superstep's two refill sites, so
+the ``_COMP_TILES`` aligned tiles from the one that holds its next
+word cover everything it can take in ``COMP_PERIOD`` supersteps; the
+loop carries them, one windowed tile sweep of ``comp_ref`` refreshes
+them on that schedule, and a refill picks its word out of the carried
+registers (``_pick_word``). Bool (1,128)
 vectors are carried as i32 across ``lax.cond`` branches, unsigned
 reductions and min run in i32: workarounds written against an earlier
 Mosaic. This installation (jax 0.9.0, libtpu 0.0.34) compiles the
@@ -119,6 +130,13 @@ _SLAB = 2048            # slab rows for big-buffer one-hot ops (VMEM temps)
 RING_W = 1024           # history ring: last 4 KiB per lane, word rows
 RING_SAFE = 4096 - 8    # max distance served by the ring
 MAX_DEVICE_CSIZE = 8192 * 4 - 16  # comp cap; bigger payloads -> host
+# A comp sweep serves COMP_PERIOD supersteps. A lane takes at most two
+# words a superstep (one a refill site), so from word in_w it reads
+# in_w .. in_w + 2 * COMP_PERIOD - 1: all inside the _COMP_TILES aligned
+# 8-word tiles from tile in_w >> 3 on, which the loop carries. A power
+# of two: the schedule is a mask of the loop counter.
+COMP_PERIOD = 4
+_COMP_TILES = (2 * COMP_PERIOD + 14) // 8
 _U32 = jnp.uint32
 _I32 = jnp.int32
 
@@ -263,6 +281,14 @@ def _gather_tile(data, tiles):
     t = jnp.sum(
         jnp.where(ti == tiles[None], data, jnp.zeros_like(data)), axis=0)
     return lax.bitcast_convert_type(t, _U32) if unsigned else t
+
+
+def _pick_word(tiles, first, rows):
+    """Word ``rows[l]`` of lane l out of the consecutive (8,128) tiles
+    ``tiles``, whose first is the lane's tile ``first[l]``: ``rows -
+    8 * first`` lies in [0, 8 * len(tiles)), and the pick is a one-hot
+    over those few registers (no ref access, no gate)."""
+    return _gather(jnp.concatenate(tiles, axis=0), rows - (first << 3))
 
 
 def _tile_hull(tiles, n_tiles: int):
@@ -509,40 +535,48 @@ def _inflate_simd_kernel(
     clen = clen_ref[...].astype(_I32)
 
     # 64-bit bit buffer as a (lo, hi) u32 pair + total valid-bit count.
-    # One *word-aligned* single gather per refill site (the one-hot fast
-    # path). A refill turns any cnt in [0, 32] into cnt + 32, so after
-    # it cnt >= 32 and the low word is whole, whatever was consumed
-    # before; two refill sites per superstep keep every phase's peek
-    # within the low word. Pre-phase-A: 32 valid bits, phase A consumes
-    # <= 32 (a word-aligned 4-byte stored copy; Huffman paths <= 30 —
-    # two literal codes of <= 15 bits each, or a 15-bit length code + 5
+    # One *word-aligned* word per refill site. A refill turns any cnt
+    # in [0, 32] into cnt + 32, so after it cnt >= 32 and the low word
+    # is whole, whatever was consumed before; two refill sites per
+    # superstep keep every phase's peek within the low word.
+    # Pre-phase-A: 32 valid bits, phase A consumes <= 32 (a
+    # word-aligned 4-byte stored copy; Huffman paths <= 30 — two
+    # literal codes of <= 15 bits each, or a 15-bit length code + 5
     # extra bits). Pre-phase-B: 32 valid bits again, so a match's
     # distance can follow its length in the same superstep: the dist
     # code (<= 15) is consumed first, which leaves >= 17 >= its 13
     # extra bits. No unaligned double-gather assembly.
-    def refill64(lo, hi, cnt, in_w):
-        def do_refill(lo, hi, cnt, in_w):
-            w = _gather_ref_win(
-                comp_ref, jnp.minimum(in_w, cw - 1),
-                slab=slab).astype(_U32)
-            do = cnt <= 32
-            cu = jnp.minimum(cnt, 31).astype(_U32)
-            lo = jnp.where(do & (cnt < 32), lo | (w << cu), lo)
-            hi_add = jnp.where(
-                cnt == 32, w,
-                jnp.where(cnt > 0, w >> ((_U32(32) - cu) & _U32(31)),
-                          zrow_u))
-            hi = jnp.where(do, hi | hi_add, hi)
-            cnt = cnt + jnp.where(do, 32, 0)
-            in_w = in_w + jnp.where(do, 1, 0)
-            return lo, hi, cnt, in_w
+    # A site adds at most one word to a lane, so in_w rises by at most
+    # 2 a superstep: the words a lane can want in COMP_PERIOD
+    # supersteps lie in the tiles ``ctiles`` the carry holds for it
+    # (the lane's tiles ``ct`` on: ``comp_sweep`` below), and a site
+    # picks its word out of those registers. comp_ref is read nowhere
+    # else. A malformed lane that runs past its payload reads row
+    # cw - 1 again and again until the overrun guard flags it.
+    def refill64(lo, hi, cnt, in_w, ctiles, ct):
+        w = _pick_word(ctiles, ct, jnp.minimum(in_w, cw - 1))
+        do = cnt <= 32
+        cu = jnp.minimum(cnt, 31).astype(_U32)
+        lo = jnp.where(do & (cnt < 32), lo | (w << cu), lo)
+        hi_add = jnp.where(
+            cnt == 32, w,
+            jnp.where(cnt > 0, w >> ((_U32(32) - cu) & _U32(31)),
+                      zrow_u))
+        hi = jnp.where(do, hi | hi_add, hi)
+        cnt = cnt + jnp.where(do, 32, 0)
+        in_w = in_w + jnp.where(do, 1, 0)
+        return lo, hi, cnt, in_w
 
-        # whole-warp gate: only sweep the comp columns when some lane
-        # actually has room (cnt <= 32)
-        return lax.cond(
-            jnp.any(cnt <= 32), do_refill,
-            lambda lo, hi, cnt, in_w: (lo, hi, cnt, in_w),
-            lo, hi, cnt, in_w)
+    def comp_sweep(ct, live):
+        """The carry's compressed window, read anew: every live lane's
+        ``_COMP_TILES`` tiles from tile ``ct`` on (a tile past the
+        buffer, and every tile of a lane that is done or flagged, is
+        -1: zeros, and no anchor of the hull), in one windowed sweep
+        behind one set of slab gates."""
+        tiles = tuple(
+            jnp.where(live & (ct + j < cw // 8), ct + j, -1)
+            for j in range(_COMP_TILES))
+        return _gather_tiles_ref_win(comp_ref, tiles, slab=slab)
 
     def consume64(lo, hi, cnt, n):
         """Drop n (0..32, per-lane) low bits from the pair. n == 32
@@ -560,10 +594,18 @@ def _inflate_simd_kernel(
         (step, state, lo, hi, cnt, in_w, outpos, bfinal, fixed,
          copy_len, copy_dist, hlit, hdist, hclen, tb_idx, tb_nread,
          rep_val, rep_cnt, prev_len, status, far_steps,
-         crossing) = carry
+         crossing, ctiles, ct, comp_fetches) = carry
 
         live = (state != _DONE) & (state != _ERR)
-        lo, hi, cnt, in_w = refill64(lo, hi, cnt, in_w)
+        # the comp sweep runs on the loop counter's schedule, not on a
+        # condition reduced across the lanes
+        sweep = step & (COMP_PERIOD - 1) == 0
+        ct = jnp.where(sweep, jnp.minimum(in_w, cw - 1) >> 3, ct)
+        ctiles, comp_fetches = lax.cond(
+            sweep,
+            lambda: (comp_sweep(ct, live), comp_fetches + 1),
+            lambda: (ctiles, comp_fetches))
+        lo, hi, cnt, in_w = refill64(lo, hi, cnt, in_w, ctiles, ct)
         bitbuf = lo
 
         new_state = state
@@ -790,7 +832,7 @@ def _inflate_simd_kernel(
 
         # ---- consume phase-A bits, refill for phase B ---------------
         lo, hi, cnt = consume64(lo, hi, cnt, jnp.where(live, used, zrow))
-        lo, hi, cnt, in_w = refill64(lo, hi, cnt, in_w)
+        lo, hi, cnt, in_w = refill64(lo, hi, cnt, in_w, ctiles, ct)
         bitbuf = lo
 
         # ---- DIST (phase B): the distance of the length symbol phase
@@ -999,7 +1041,7 @@ def _inflate_simd_kernel(
         return (step + 1, new_state, lo, hi, cnt, in_w, outpos,
                 bfinal, fixed, copy_len, copy_dist, hlit, hdist, hclen,
                 tb_idx, tb_nread, rep_val, rep_cnt, prev_len, new_status,
-                far_steps, crossing)
+                far_steps, crossing, ctiles, ct, comp_fetches)
 
     def cond(carry):
         step, state = carry[0], carry[1]
@@ -1012,17 +1054,25 @@ def _inflate_simd_kernel(
         zrow, zrow, zrow, zrow,
         zrow, zrow, zrow, zrow, zrow, zrow, zrow, zrow, zrow,
         jnp.int32(0), zrow,
+        # the window: superstep 0 sweeps, so what it starts as is never
+        # read. ct starts as a loaded row and not as zrow because this
+        # Mosaic refuses the loop with it carried from a splat constant
+        # ("Invalid relayout ... replicated in destination but not in
+        # source")
+        (jnp.zeros((8, LANES), _U32),) * _COMP_TILES, clen, jnp.int32(0),
     )
     final = lax.while_loop(cond, superstep, init)
     step, state, _lo, _hi, _cnt, _iw, outpos = final[:7]
     status, far_steps, crossing = final[19:22]
+    comp_fetches = final[24]
     # lanes still live at the step cap ran away
     status = jnp.where(
         (state != _DONE) & (state != _ERR), 6, status)
     meta_ref[...] = jnp.concatenate(
         [outpos, status, jnp.broadcast_to(step[None, None], (1, LANES)),
          jnp.broadcast_to(far_steps[None, None], (1, LANES)),
-         crossing], axis=0)
+         crossing,
+         jnp.broadcast_to(comp_fetches[None, None], (1, LANES))], axis=0)
 
 
 @functools.lru_cache(maxsize=16)
@@ -1044,7 +1094,7 @@ def _compiled(cw: int, ow: int, interpret: bool,
         kernel,
         out_shape=(
             jax.ShapeDtypeStruct((ow, LANES), _U32),
-            jax.ShapeDtypeStruct((5, LANES), _I32),
+            jax.ShapeDtypeStruct((6, LANES), _I32),
         ),
         in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)] * (2 + len(_CONST_TABLES)),
         out_specs=(
@@ -1290,7 +1340,11 @@ def _fetch_chunk(handle, lanes: int,
     chunks that ran past the output word they started in, is summed
     over the lanes into ``device.inflate.crossing_chunks`` and the label
     ``crossing_chunks``: how often the one-chunk match engaged (0 for
-    a launch with no match in it)."""
+    a launch with no match in it).  ``meta`` row 5, the supersteps in
+    which the kernel swept the compressed buffer for the carry's
+    window, is booked as ``device.inflate.comp_fetches`` and the label
+    ``comp_fetches``: over the supersteps it is the schedule's share,
+    1 / ``COMP_PERIOD``."""
     words, meta = handle
     if labels is None:
         labels = {"kind": "inflate", "lanes": lanes}
@@ -1305,9 +1359,11 @@ def _fetch_chunk(handle, lanes: int,
             at_end["supersteps"] = supersteps = int(meta[2, 0])
             at_end["far_supersteps"] = far = int(meta[3, 0])
             at_end["crossing_chunks"] = crossing = int(meta[4].sum())
+            at_end["comp_fetches"] = fetches = int(meta[5, 0])
             _counter("device.inflate.supersteps").inc(supersteps)
             _counter("device.inflate.far_supersteps").inc(far)
             _counter("device.inflate.crossing_chunks").inc(crossing)
+            _counter("device.inflate.comp_fetches").inc(fetches)
     _count_transfer("d2h", nbytes)
     return words.view(np.uint8), meta
 

@@ -704,8 +704,11 @@ def analyze(spans, run, runs, dropped: int = 0) -> str:
     # the share of the supersteps in which some lane read history past
     # the ring (the far_supersteps labels, meta row 3); and the copy
     # chunks that ran past the output word they started in (the
-    # crossing_chunks labels, meta row 4 summed over a launch's lanes)
-    launches = steps = far_steps = crossing = 0
+    # crossing_chunks labels, meta row 4 summed over a launch's lanes);
+    # and the supersteps in which the kernel swept the compressed
+    # buffer for the carry's window (the comp_fetches labels, meta row
+    # 5: a log from before the window was carried has none)
+    launches = steps = far_steps = crossing = fetches = 0
     wait_s = 0.0
     full: List[int] = []   # the full launches' own counts (128 lanes)
     for s in spans:
@@ -721,6 +724,7 @@ def analyze(spans, run, runs, dropped: int = 0) -> str:
                 full.append(int(labels["supersteps"]))
             far_steps += int(labels.get("far_supersteps", 0))
             crossing += int(labels.get("crossing_chunks", 0))
+            fetches += int(labels.get("comp_fetches", 0))
     if steps:
         out.append(
             f"inflate_supersteps: {steps / launches:,.0f} a launch over "
@@ -728,6 +732,8 @@ def analyze(spans, run, runs, dropped: int = 0) -> str:
             f"superstep (device.launch.wait), {far_steps / steps * 100:.1f}% "
             f"of them read history past the ring, {crossing / launches:,.0f} "
             "copy chunks a launch crossed an output word's boundary"
+            + (f", the compressed buffer swept in {fetches / steps:.3f} "
+               "of them" if fetches else "")
             + (f"; {len(full)} full launches of {min(full):,} to "
                f"{max(full):,} supersteps" if full else ""))
         out.append("")

@@ -17,7 +17,9 @@ import numpy as np
 import pytest
 
 from disq_tpu.ops import inflate_simd as tables
-from disq_tpu.ops.inflate_simd import inflate_payloads_simd
+from disq_tpu.ops.inflate_simd import (
+    _COMP_TILES, COMP_PERIOD, MAX_DEVICE_CSIZE, inflate_payloads_simd,
+)
 
 
 def deflate(data: bytes, level: int = 6, strategy: int = zlib.Z_DEFAULT_STRATEGY) -> bytes:
@@ -399,8 +401,8 @@ def flat_lens(symbols, alphabet):
 
 def raw_launch(payloads, cw=128, ow=64, whole=False):
     """One launch of the kernel itself: (each lane's output bytes, the
-    (5, 128) meta rows: outpos, status, supersteps, far supersteps,
-    each lane's crossing chunks). One geometry (512 compressed bytes
+    (6, 128) meta rows: outpos, status, supersteps, far supersteps,
+    each lane's crossing chunks, comp sweeps). One geometry (512 compressed bytes
     in, 256 out) for all the small streams, so the interpreter traces
     the kernel for them once. ``whole``: a lane's output is all of its
     ``ow * 4`` bytes, not cut at its outpos."""
@@ -820,7 +822,7 @@ class TestCrossingChunkCounter:
     def test_meta_row_4_is_a_count_a_lane(self):
         tokens, payloads = _crossing_payloads()
         _outs, meta = raw_launch(payloads, cw=256, ow=256)
-        assert meta.shape == (5, 128)
+        assert meta.shape == (6, 128)
         assert meta[4, :3].tolist() == [fused_schedule(t)[1] for t in tokens]
         assert not meta[4, 3:].any()
 
@@ -1429,3 +1431,208 @@ class TestSuperstepCounter:
         assert steps.total() - base[0] == meta[2, 0] > 0
         assert last_inflate_d2h_labels()["supersteps"] == meta[2, 0]
         assert "device.inflate.supersteps" in telemetry_snapshot()["counters"]
+
+
+# ---- the compressed window in the carry --------------------------------
+# A refill site takes at most one word, so a lane's in_w rises by at
+# most two a superstep; the kernel sweeps comp_ref once in COMP_PERIOD
+# supersteps for the tiles from in_w >> 3 on, and both sites pick
+# their word out of the carried tiles.
+
+def window_picks(bits):
+    """The refill rule over one lane's supersteps, ``bits`` a superstep
+    being (phase A's, phase B's): for every word a site takes,
+    (superstep, site, word, the word's position in the tiles the last
+    sweep carried: word - 8 * (in_w at the sweep >> 3))."""
+    cnt = in_w = tile = 0
+    picks = []
+    for step, used in enumerate(bits):
+        if step % COMP_PERIOD == 0:
+            tile = in_w >> 3
+        for site in (0, 1):
+            if cnt <= 32:
+                picks.append((step, site, in_w, in_w - 8 * tile))
+                cnt, in_w = cnt + 32, in_w + 1
+            cnt -= used[site]
+    return picks
+
+
+def stored_then_literals(n, lits):
+    """A stored block of ``n`` bytes, then a fixed block of the
+    literals ``lits``: (payload, its bytes, the bits each superstep
+    consumes: the headers, LEN, NLEN, a stored chunk to the output
+    word's end, a literal pair, the end-of-block)."""
+    data = bytes((7 * i + 3) & 0xFF for i in range(n))
+    b = Bits()
+    b.put(0, 3)
+    b.n = 8
+    b.put(n, 16)
+    b.put(n ^ 0xFFFF, 16)
+    for byte in data:
+        b.put(byte, 8)
+    fixed_block(b, lits)
+    bits = [8, 16, 16] + [32] * (n // 4) + [8 * (n % 4)] * (n % 4 > 0) + [3]
+    width = [8 if t < 144 else 9 for t in lits]
+    bits += [sum(width[i: i + 2]) for i in range(0, len(lits), 2)] + [7]
+    return b.bytes(), data + bytes(lits), [(a, 0) for a in bits]
+
+
+# the fixed block's first word is word r + 2: every residue of in_w
+# mod 8 under a sweep, with 9-bit pairs drifting across the words after
+_RESIDUE_LITS = [144 + (5 * i) % 100 for i in range(150)]
+_RESIDUE_CASES = [stored_then_literals(4 * r + 3, _RESIDUE_LITS)
+                  for r in range(8)]
+
+# 15-bit codes on a length symbol and a distance symbol with few extra
+# bits: a one-chunk match of 34 bits a superstep, both phases peeking
+_DENSE_LIT = dict(zip(b"abcdefghijklm", range(1, 14)))
+_DENSE_LIT.update({256: 14, 267: 15, ord("z"): 15})
+_DENSE_DIST = {**{s: s + 1 for s in range(9)},
+               **{s: s for s in range(10, 15)}, 9: 15, 15: 15}
+
+
+def _dynamic(tokens, lit_lens, dist_lens):
+    b = Bits()
+    dynamic_block(b, tokens, lit_lens, dist_lens)
+    return b.bytes()
+
+
+_WINDOW_RNG = np.random.default_rng(4242)
+
+
+def _window_bytes(n):
+    return _WINDOW_RNG.integers(0, 256, n, dtype=np.uint8).tobytes()
+
+
+def _truncated_stored():
+    """A stored block that says 300 bytes and holds 150."""
+    return deflate_stored(_window_bytes(300))[:155]
+
+
+_WINDOW_SMALL = (
+    [(f"first-word-{(r + 2) % 8}-mod-8", p) for r, (p, _raw, _bits)
+     in enumerate(_RESIDUE_CASES)]
+    + [("stored-32-bits-a-step", deflate_stored(_window_bytes(900))),
+       ("literal-pairs-of-15-bit-codes",
+        _dynamic([ord("z")] * 380 + list(b"abc"), _LONG_LIT, _LONG_DIST)),
+       ("matches-of-15-bit-codes",
+        _dynamic(list(b"abcdefghijklmabcdefghijklmabcdef")
+                 + [(15 + i % 2, 25 + i % 8) for i in range(120)]
+                 + [ord("z")], _DENSE_LIT, _DENSE_DIST)),
+       ("empty-lane", b""),
+       ("truncated", _truncated_stored())]
+)
+# one launch at the largest geometry: a payload at the comp cap (its
+# last words lie in the buffer's last tile, the tile after it is past
+# the buffer) beside a 51-byte payload that stays live as long (so the
+# sweep's hull spans slabs 0 to 7), empty lanes among them
+_WINDOW_BIG = [
+    ("tiny-payload-long-life", deflate(b"abcd" * 8000, 9)),
+    ("empty-lane", b""),
+    ("payload-at-the-comp-cap",
+     deflate_stored(_window_bytes(MAX_DEVICE_CSIZE - 5))),
+    ("empty-lane-2", b""),
+    ("forty-bytes-and-done", _fixed(list(range(70, 105)))),
+]
+_WINDOW_CASES = ([("small", i) for i in range(len(_WINDOW_SMALL))]
+                 + [("big", i) for i in range(len(_WINDOW_BIG))])
+
+
+@pytest.fixture(scope="module")
+def window_launches():
+    from disq_tpu.ops.inflate_simd import buckets_for
+
+    big = [p for _n, p in _WINDOW_BIG]
+    assert len(big[2]) == MAX_DEVICE_CSIZE and len(big[0]) < 64
+    geometry = buckets_for(big, MAX_DEVICE_CSIZE)
+    assert geometry[0] == 8192
+    return {"small": raw_launch([p for _n, p in _WINDOW_SMALL], 256, 1024),
+            "big": raw_launch(big, *geometry)}
+
+
+class TestCompWindow:
+    def test_the_pick_equals_numpy_at_all_sixteen_positions(self):
+        import jax.numpy as jnp
+
+        from disq_tpu.ops.inflate_simd import _pick_word
+
+        rng = np.random.default_rng(42)
+        tiles = rng.integers(0, 2 ** 32, (2, 8, 128), dtype=np.uint32)
+        first = rng.integers(0, 1024, 128).astype(np.int32)
+        both = np.concatenate(list(tiles), axis=0)
+        for shift in range(16):
+            pos = (np.arange(128) + shift) % 16
+            got = _pick_word(
+                tuple(jnp.asarray(t) for t in tiles),
+                jnp.asarray(first[None]),
+                jnp.asarray((8 * first + pos).astype(np.int32)[None]))
+            assert got.dtype == jnp.uint32 and got.shape == (1, 128)
+            assert (np.asarray(got)[0] == both[pos, np.arange(128)]).all()
+
+    def test_the_cases_cover_the_rule(self):
+        sweeps, picked = set(), set()
+        for payload, _raw, bits in _RESIDUE_CASES:
+            # the model accounts for every bit of the payload
+            assert 0 <= 8 * len(payload) - sum(a for a, _b in bits) < 8
+            picks = window_picks(bits)
+            words = {step: w for step, _site, w, _pos in reversed(picks)}
+            sweeps |= {w & 7 for step, w in words.items()
+                       if step % COMP_PERIOD == 0}
+            picked |= {(site, pos) for _step, site, _w, pos in picks}
+        # a sweep meets in_w at every residue; these lanes take a word
+        # a superstep at most, so their picks reach position 7 + 2
+        assert sweeps == set(range(8))
+        assert {pos for _site, pos in picked} >= set(range(10))
+        assert {site for site, _pos in picked} == {0, 1}
+        # a word at every site (more than any stream can take: phase B
+        # consumes 28 bits at most), from every residue: the last
+        # position of the bound, inside the carried tiles
+        worst = max(pos for lead in range(8) for *_rest, pos in
+                    window_picks([(32, 0)] * lead + [(32, 32)] * 64))
+        assert worst == 7 + 2 * COMP_PERIOD - 1 < 8 * _COMP_TILES
+
+    @pytest.mark.parametrize(
+        "launch,lane", _WINDOW_CASES,
+        ids=[(_WINDOW_SMALL if k == "small" else _WINDOW_BIG)[i][0]
+             for k, i in _WINDOW_CASES])
+    def test_lane_equals_zlib(self, window_launches, launch, lane):
+        name, payload = (_WINDOW_SMALL if launch == "small"
+                         else _WINDOW_BIG)[lane]
+        outs, meta = window_launches[launch]
+        if name == "truncated":
+            # flagged as it was before the window was carried (status
+            # 6 at 160 bytes: the parent kernel's own), so the host
+            # adjudicates as before
+            assert (meta[1, lane], meta[0, lane]) == (6, 160)
+            with pytest.raises(ValueError, match="corrupt DEFLATE"):
+                inflate_payloads_simd([payload], usizes=[300],
+                                      interpret=True)
+            return
+        want = zlib.decompress(payload, -15) if payload else b""
+        assert meta[1, lane] == 0 and meta[0, lane] == len(want)
+        assert outs[lane] == want
+        # lanes past the payloads never started
+        assert not meta[:2, len(outs):].any()
+
+    def test_meta_row_5_counts_the_sweeps(self, window_launches):
+        for _outs, meta in window_launches.values():
+            steps = int(meta[2, 0])
+            assert steps > 8 * COMP_PERIOD
+            assert (meta[5] == -(-steps // COMP_PERIOD)).all()
+
+    @pytest.mark.parametrize("route", ["direct", "service"])
+    def test_comp_fetches_booked_once_a_launch(self, route):
+        from disq_tpu.runtime.tracing import REGISTRY, telemetry_snapshot
+
+        raws = [text_like(150 + 17 * i) for i in range(5)]
+        payloads = [deflate(r) for r in raws]
+        fetches = REGISTRY.counter("device.inflate.comp_fetches")
+        steps = REGISTRY.counter("device.inflate.supersteps")
+        base = fetches.total(), steps.total()
+        assert inflate_by(route, payloads, [len(r) for r in raws]) == raws
+        took = steps.total() - base[1]
+        assert fetches.total() - base[0] == -(-took // COMP_PERIOD)
+        assert (last_inflate_d2h_labels()["comp_fetches"]
+                == fetches.total() - base[0])
+        assert ("device.inflate.comp_fetches"
+                in telemetry_snapshot()["counters"])

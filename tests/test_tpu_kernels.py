@@ -90,9 +90,10 @@ def test_device_kernels_on_chip(tmp_path):
     assert rows["inflate_simd_literal_heavy_kernel_only"][
         "supersteps_per_launch"] == 13191
     assert rows["inflate_simd_kernel_only"]["supersteps_per_launch"] == 5950
-    # the price since the emit merge is a tile scatter (PR 37: 5.4 us
-    # where the four-row merge read 6.2; TPU_KERNELS.json keeps both)
-    assert wgs["us_per_superstep"] < 5.8
+    # the price since the compressed window is carried (PR 42: 4.2 us
+    # where a gated sweep at both refill sites read 5.4;
+    # TPU_KERNELS.json keeps both)
+    assert wgs["us_per_superstep"] < 4.95
     # refresh the repo-root artifact for the judge
     with open(os.path.join(REPO, "TPU_KERNELS.json"), "w") as f:
         json.dump(artifact, f, indent=1)

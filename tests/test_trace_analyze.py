@@ -227,6 +227,20 @@ class TestVerdict:
         assert want in line
         assert line.endswith("an output word's boundary")
 
+    @pytest.mark.parametrize("fetches,want", [
+        ((3750, 3500), "boundary, the compressed buffer swept in 0.250 "
+                       "of them"),
+        ((15000, 14000), "swept in 1.000 of them"),
+        (None, "an output word's boundary"),
+    ], ids=["every-fourth", "every-superstep", "label-absent"])
+    def test_inflate_comp_sweep_share_after_the_crossing_chunks(
+            self, fetches, want):
+        """The share of supersteps in which the kernel swept the
+        compressed buffer, from the d2h spans' ``comp_fetches`` label
+        (meta row 5); a log from before the label says nothing."""
+        line = _supersteps_line((15000, 14000), comp_fetches=fetches)
+        assert line.endswith(want)
+
     @pytest.mark.parametrize("lanes,want", [
         ((13, 128, 128, 40), "; 2 full launches of 13,900 to 14,100 "
                              "supersteps"),
