@@ -121,17 +121,26 @@ def bgzf_compress_with_voffsets(
 
 
 class _LazySlice:
-    """Deferred shard slice for the resident write path: the SBI/BAI
-    fragment builders touch host columns only when an index was
-    requested, so a plain (no-index) resident write never materializes
-    host records at all."""
+    """Deferred shard slice for the two resident write paths (record
+    bytes encoded on the device, or copied from the batch's blob): what
+    a shard's index fragments ask of it comes without a host parse.
+    With the shard's ``encoded`` bytes and record offsets, ``refid``,
+    ``pos`` and ``flag`` are read from those bytes at the offsets (so
+    they are the index of what is written, and cost no d2h), and the
+    alignment ends come from the batch's span cache for this shard's
+    records alone. Without them (the device holds the bytes) the index
+    builders' columns come from a real slice, as does any other
+    attribute either way: a plain (no-index) resident write never
+    materializes host records at all, and a reader of a ragged column
+    only pays."""
 
-    __slots__ = ("_batch", "_lo", "_hi", "_part")
+    __slots__ = ("_batch", "_lo", "_hi", "_part", "_encoded")
 
-    def __init__(self, batch, lo: int, hi: int) -> None:
+    def __init__(self, batch, lo: int, hi: int, encoded=None) -> None:
         self._batch = batch
         self._lo, self._hi = lo, hi
         self._part = None
+        self._encoded = encoded
 
     @property
     def count(self) -> int:
@@ -143,22 +152,45 @@ class _LazySlice:
         return self._part
 
     def alignment_ends(self):
-        return self._mat().alignment_ends()
+        if self._encoded is None:
+            return self._mat().alignment_ends()
+        return self._batch.alignment_ends(self._lo, self._hi)
 
     def __getattr__(self, name: str):
-        return getattr(self._mat(), name)
+        # only what no slot or property answers lands here
+        at = _FIELD_AT.get(name) if self._encoded is not None else None
+        if at is None:
+            return getattr(self._mat(), name)
+        blob, offs = self._encoded
+        dtype = np.dtype(at[1])
+        idx = offs[:-1, None] + np.arange(at[0], at[0] + dtype.itemsize)
+        return blob[idx].view(dtype)[:, 0]
+
+
+# the fixed fields an index fragment reads, as (byte in the record,
+# dtype): what ``bam/codec.py`` packs there
+_FIELD_AT = {"refid": (4, "<i4"), "pos": (8, "<i4"), "flag": (18, "<u2")}
 
 
 class BamSink:
     """Single-file BAM write (``FileCardinalityWriteOption.SINGLE``).
 
+    A batch that holds its records' bytes (a resident read's
+    ``ColumnarBatch``, also filtered, ``permuted()`` or flag-patched:
+    ``encode_source()`` is not None) is written from those bytes: each
+    shard copies its records out of the blob in the pending order
+    (``ColumnarBatch.encoded_slice``), no record is parsed and none is
+    encoded again; the files are the column encoder's, byte for byte.
+    Any other batch (a ``ReadBatch``, a host-built ``ColumnarBatch``)
+    is sliced and encoded from its columns.
+
     With ``DisqOptions.device_deflate`` armed, the per-shard deflate
     routes through the device SIMD encoder (service-coalesced across
-    in-flight write shards), and a sorted device-backed
-    ``ColumnarBatch`` additionally encodes its records ON DEVICE
-    (``runtime/device_write.py``): sort permutation → record-byte
-    gather → entropy coder run HBM-resident, and only compressed
-    blocks (plus csizes for the voffset/BAI arithmetic) cross d2h."""
+    in-flight write shards), and such a device-backed batch encodes
+    its records ON DEVICE instead (``runtime/device_write.py``): sort
+    permutation → record-byte gather → entropy coder run HBM-resident,
+    and only compressed blocks (plus csizes for the voffset/BAI
+    arithmetic) cross d2h."""
 
     def __init__(self, storage=None):
         self._storage = storage
@@ -241,23 +273,32 @@ class BamSink:
     # -- pipeline stage bodies (encode → deflate → stage) -------------------
 
     def _encode_shard(self, batch, bounds, k, resident=None):
-        """Stage 1: slice shard ``k`` and encode its records — on host
-        (CPU record encode), or as a device record-byte gather when the
-        resident write path is armed (the encoded blob then stays in
-        HBM for the deflate stage; host columns materialize only if an
-        index build asks for them)."""
+        """Stage 1: shard ``k``'s records as BAM bytes, by what the
+        batch is. With the resident write path armed, a device
+        record-byte gather (the encoded blob then stays in HBM for the
+        deflate stage; host columns materialize only if an index build
+        asks for them). Else, from a batch that holds its records'
+        bytes, a host copy of them in the batch's order
+        (``encoded_slice``: no parse, no encode, the writers side by
+        side). Else a slice of the columns and the CPU record encode.
+        ``bam.write.slice`` times the cut either way, and
+        ``bam.write.encoded_records{how}`` counts the records by it."""
         lo, hi = int(bounds[k]), int(bounds[k + 1])
         if resident is not None:
             enc = resident.encode_shard(lo, hi)
             return _LazySlice(batch, lo, hi), enc, enc.record_offsets
-        # a device-backed batch materialises in ``slice`` once, under
-        # its own lock: one writer parses and the others wait here
-        from disq_tpu.runtime.tracing import span
+        from disq_tpu.runtime.tracing import counter, span
 
+        encoded_slice = getattr(batch, "encoded_slice", None)
         with span("bam.write.slice", shard=k, records=hi - lo):
-            part = batch.slice(lo, hi)
-        blob, rec_offs = encode_records_with_offsets(part)
-        return part, blob, rec_offs
+            encoded = encoded_slice(lo, hi) if encoded_slice else None
+            part = (batch.slice(lo, hi) if encoded is None
+                    else _LazySlice(batch, lo, hi, encoded))
+        how = "bytes"
+        if encoded is None:
+            encoded, how = encode_records_with_offsets(part), "columns"
+        counter("bam.write.encoded_records").inc(hi - lo, how=how)
+        return (part, *encoded)
 
     def _deflate_shard(self, header, write_bai, write_sbi, payload):
         """Stage 2 (native-threaded CPU, or the device SIMD coder):

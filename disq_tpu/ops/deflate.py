@@ -597,7 +597,7 @@ def deflate_blob_device(blob) -> Tuple[bytes, np.ndarray]:
     # reset first so an exception mid-encode can never leave a previous
     # call's counts attributed to this one
     last_stats.update(blocks=0, stored_fallback=0, host_fallback=0)
-    if not blob:
+    if len(blob) == 0:
         return b"", np.zeros(0, dtype=np.int64)
     from disq_tpu.ops import inflate_simd as IS
 

@@ -446,7 +446,8 @@ class ColumnarBatch:
 
                     blob = self._host_blob()
                     with span("columnar.batch.materialize",
-                              records=self._n, bytes=len(blob)):
+                              records=self._n, bytes=len(blob),
+                              how="parse"):
                         rb = decode_records(
                             blob, self._offsets, n_ref=self._n_ref)
                         if self._order is not None:
@@ -639,34 +640,44 @@ class ColumnarBatch:
     def reference_lengths(self) -> np.ndarray:
         return self._span(1)
 
-    def alignment_ends(self) -> np.ndarray:
+    def alignment_ends(self, lo: int = 0,
+                       hi: Optional[int] = None) -> np.ndarray:
         """0-based exclusive end positions, ``ReadBatch``'s values. A
         batch that holds record bytes derives them from the CIGAR op
         words alone, part by part, and keeps them (``_span``): no blob
-        join, no host record parse."""
-        return self._span(0)
+        join, no host record parse. ``lo``/``hi`` ask for the logical
+        records ``[lo, hi)`` alone (a write shard's): what is kept is
+        then indexed by that stretch of a pending order, not by all
+        of it."""
+        return self._span(0, lo, hi)
 
-    def _span(self, which: int) -> np.ndarray:
-        """The ends i32 (0) or the reference lengths i64 (1) in
-        logical order, from what the batch holds, in this order: an
-        earlier CIGAR pass (``cached``), a host parse some consumer
-        already paid for (``ragged``; a host-built batch's own
-        columns, ``host``), the record bytes (``cigar``). Notes which
-        on ``ends_source``."""
+    def _span(self, which: int, lo: int = 0,
+              hi: Optional[int] = None) -> np.ndarray:
+        """The ends i32 (0) or the reference lengths i64 (1) of the
+        logical records ``[lo, hi)`` (all of them by default), from
+        what the batch holds, in this order: an earlier CIGAR pass
+        (``cached``), a host parse some consumer already paid for
+        (``ragged``; a host-built batch's own columns, ``host``), the
+        record bytes (``cigar``). Notes which on ``ends_source``."""
+        cut = slice(lo, self._n if hi is None else hi)
         spans = self._span_cache.spans
         if spans is not None:
             self.ends_source = "cached"
         else:
             rb = self._ragged_rb
-            if rb is not None:  # already in logical order, and free
+            # already in logical order, and free; a part of a batch
+            # that holds bytes takes the CIGAR pass, which is kept,
+            # over a pass of the parse's columns a part
+            if rb is not None and (self._offsets is None
+                                   or cut == slice(0, self._n)):
                 self.ends_source = (
                     "ragged" if self._offsets is not None else "host")
                 return (rb.reference_lengths() if which
-                        else rb.alignment_ends())
+                        else rb.alignment_ends())[cut]
             spans = self._spans_from_cigar()
             self.ends_source = "cigar"
-        return (spans[which] if self._order is None
-                else spans[which][self._order])
+        col = spans[which]
+        return col[cut] if self._order is None else col[self._order[cut]]
 
     def _spans_from_cigar(self):
         """The CIGAR pass over the record bytes as they are held — one
@@ -900,6 +911,54 @@ class ColumnarBatch:
                     self._blob is None and self._blob_parts is None):
                 return None
         return self._host_blob(), self._offsets, self._order
+
+    def encoded_slice(self, lo: int, hi: int):
+        """The logical records ``[lo, hi)`` as the BAM writer's bytes,
+        ``(uint8 array, (hi - lo + 1,) record offsets)``, or None when
+        the batch holds no record bytes (``encode_source``). The
+        records are copied, not parsed: gathered from the blob by that
+        stretch of a pending order (per-record memcpy, the GIL
+        released: writers' shards gather side by side), or, in source
+        order, a view of the blob's own stretch. The bytes are the
+        column encoder's (``bam/codec.py``) of the same records: it
+        keeps every field as it was read but the pad nibble after an
+        odd number of bases, which it writes as zero; so is it here,
+        in the copy (a view is copied first), never in the blob.
+
+        Runs under ``columnar.batch.materialize{how=bytes}``: the batch
+        brings these records into the form a host consumer takes, as
+        ``how=parse`` does for all of them at once; no
+        ``columnar.batch.materializations`` is booked, no record is
+        parsed."""
+        src = self.encode_source()
+        if src is None:
+            return None
+        from disq_tpu.bam.columnar import segment_gather
+        from disq_tpu.runtime.tracing import span
+
+        blob, offsets, order = src
+        with span("columnar.batch.materialize", records=hi - lo,
+                  how="bytes") as labels:
+            if order is not None:
+                out, offs = segment_gather(blob, offsets, order[lo:hi])
+            else:
+                out = blob[offsets[lo]: offsets[hi]]
+                offs = offsets[lo: hi + 1] - offsets[lo]
+            labels["bytes"] = len(out)
+            at = offs[:-1]
+            at = at[(out[at + 20] & 1) != 0]  # l_seq is odd
+            if len(at):
+                n_cigar = out[at + 16] + (out[at + 17].astype(np.int64) << 8)
+                l_seq = out[at[:, None] + np.arange(20, 24)].view("<i4")
+                # the last packed-sequence byte of each
+                at = (at + 36 + out[at + 12] + 4 * n_cigar
+                      + l_seq[:, 0] // 2)
+                at = at[(out[at] & 0x0F) != 0]
+                if len(at):
+                    if order is None:
+                        out = out.copy()
+                    out[at] &= 0xF0
+        return out, offs
 
     # -- concat -------------------------------------------------------------
 

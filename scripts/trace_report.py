@@ -86,10 +86,12 @@ from typing import Any, Dict, List, Optional
 CATEGORIES = (
     ("fetch", "F", ("executor.fetch",)),
     ("decode", "D", ("executor.decode",)),
-    # The writers' slice of the batch (bam/sink.py), inside
-    # bam.write.encode and listed before it: on a device-backed batch
-    # one writer materialises the records and the others wait for it,
-    # which is not record encoding.
+    # The writers' cut of the batch (bam/sink.py), inside
+    # bam.write.encode and listed before it: a batch that holds its
+    # records' bytes copies a shard's out of them there (the whole
+    # encode), any other is sliced by columns; a batch some consumer
+    # made parse (a host-built wrapper has no bytes) parses once
+    # there, under its lock, which is not record encoding.
     ("write_slice", "m", ("bam.write.slice",)),
     ("encode", "E", ("bam.write.encode", "vcf.write.encode",
                      "bcf.write.encode", "cram.write.encode",
@@ -420,12 +422,13 @@ ADVICE = {
     "decode": "CPU-bound record decode: raise executor_workers or "
               "enable the device codec",
     "encode": "CPU-bound record encode: raise writer_workers",
-    "write_slice": "the writers' slice of the batch dominates: a "
-                   "device-backed batch is parsed on the host once, by "
-                   "one writer, while the others wait (bam.write.slice "
-                   "inside bam.write.encode) — keep the write on the "
-                   "resident encode path, or materialise before the "
-                   "writers start",
+    "write_slice": "the writers' cut of the batch dominates "
+                   "(bam.write.slice inside bam.write.encode): a batch "
+                   "that holds its records' bytes copies each shard's "
+                   "out of them (columnar.batch.materialize{how=bytes}) "
+                   "— raise writer_workers / num_shards; a "
+                   "how=parse span there is one writer parsing the "
+                   "whole batch while the others wait",
     "verify": "the host's check of device-decoded blocks dominates "
               "(codec.inflate.verify: CRCs and the bytes copy after "
               "the device has answered) — it runs per shard on the "

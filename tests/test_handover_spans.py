@@ -223,8 +223,10 @@ class TestWriteSlice:
     def test_nested_in_encode_a_part(self, tmp_path, resident):
         """One ``bam.write.slice`` a written part, inside that part's
         ``bam.write.encode`` (which keeps its extent); the slices'
-        records are the file's.  On a device-backed batch the slice is
-        where the records materialise."""
+        records are the file's.  A device-backed batch holds its
+        records' bytes: each slice copies its own out of them
+        (``columnar.batch.materialize{how=bytes}``, inside the slice),
+        and nothing parses."""
         from disq_tpu import ReadsStorage
         from disq_tpu.runtime.tracing import spans
 
@@ -245,9 +247,14 @@ class TestWriteSlice:
             (sl,) = [s for s in slices if s["labels"]["shard"] == k]
             assert _inside(sl, enc)
         assert sum(s["labels"]["records"] for s in slices) == 90
-        if resident:
-            assert len(_ring_since(
-                since, "columnar.batch.materialize")) == 1
+        mats = _ring_since(since, "columnar.batch.materialize")
+        assert len(mats) == (3 if resident else 0)
+        assert {s["labels"]["how"] for s in mats} <= {"bytes"}
+        assert sum(s["labels"]["records"] for s in mats) == (
+            90 if resident else 0)
+        for sl in slices if resident else ():
+            assert any(_inside(m, sl) and m["labels"]["records"]
+                       == sl["labels"]["records"] for m in mats)
 
 
 # ---------------------------------------------------------------------------

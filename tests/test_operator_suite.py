@@ -271,6 +271,8 @@ class TestResidentChain:
         out_host = str(tmp_path / "host.bam")
         st = ReadsStorage.make_default()
         st.write(res_ds, out_res)
+        # nor did its write: the records' bytes are copied, not parsed
+        assert mat.total() == m0
         st.write(host_ds, out_host)
         res_bytes = open(out_res, "rb").read()
         assert res_bytes == open(out_host, "rb").read()
