@@ -370,7 +370,9 @@ def _key_columns(batch) -> Tuple[Dict[str, np.ndarray], bool]:
             from disq_tpu.runtime.tracing import span
 
             blob, offsets, order = src
-            with span("ops.markdup.keys", records=len(offsets) - 1,
+            # ``bytes`` is what the sweep reads: the whole blob, also
+            # where the batch selects some of its records
+            with span("ops.markdup.keys", records=batch.count,
                       bytes=int(offsets[-1] - offsets[0])) as labels:
                 fields = record_fields_from_blob(blob, offsets, order)
                 reflen, lead, trail, score = batch.clips_and_scores()

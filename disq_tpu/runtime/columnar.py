@@ -205,9 +205,12 @@ class ColumnarBatch:
         self._blob: Optional[np.ndarray] = None
         self._blob_parts: Optional[List[np.ndarray]] = None
         self._offsets: Optional[np.ndarray] = None
-        # record permutation applied by ``permuted()`` (None = source
-        # order): the device columns are already gathered by it; host
-        # ragged/interop apply it lazily
+        # logical record -> record of the held blob (None = the blob's
+        # own order, all of it): a permutation after ``permuted()``, a
+        # selection of ``_n`` of the blob's records after a ``filter``
+        # that kept its source's bytes. The device columns are already
+        # gathered by it; every reader of the bytes indexes
+        # ``_offsets`` through it
         self._order: Optional[np.ndarray] = None
         self._n_ref: Optional[int] = None
         # batch-axis device mesh (runtime/mesh.py) the resident columns
@@ -229,9 +232,11 @@ class ColumnarBatch:
         self.ends_source: Optional[str] = None
         self._hbm = 0
         self._released = False
-        # True when this batch is the sole owner of its record blob
-        # (a compacted filter result): in-place byte patches
-        # (``or_flags``) may skip the copy-on-write
+        # True when no other batch holds this batch's record blob (a
+        # filter's compacted copy, the join of a concat's parts, an
+        # earlier copy-on-write): ``or_flags`` may then patch it in
+        # place. Handing the blob to a ``permuted()`` or filtered
+        # child clears it on both sides
         self._blob_owned = False
         # lazy state is shared across threads (writer pipeline workers
         # slice the same dataset batch concurrently): the lock makes
@@ -430,11 +435,18 @@ class ColumnarBatch:
 
     def _host_blob(self) -> Optional[np.ndarray]:
         """The record bytes as one host array, joining a concat's
-        per-shard parts on first need (under the instance lock)."""
+        per-shard parts on first need (under the instance lock). The
+        join is a fresh array that only this batch holds."""
         with self._lock:
             if self._blob is None and self._blob_parts is not None:
-                self._blob = np.concatenate(self._blob_parts)
+                from disq_tpu.runtime.tracing import span
+
+                with span("columnar.batch.join",
+                          parts=len(self._blob_parts),
+                          bytes=sum(map(len, self._blob_parts))):
+                    self._blob = np.concatenate(self._blob_parts)
                 self._blob_parts = None
+                self._blob_owned = True
             return self._blob
 
     def _ragged_source(self) -> ReadBatch:
@@ -444,14 +456,13 @@ class ColumnarBatch:
                     from disq_tpu.bam.codec import decode_records
                     from disq_tpu.runtime.tracing import counter, span
 
-                    blob = self._host_blob()
                     with span("columnar.batch.materialize",
-                              records=self._n, bytes=len(blob),
-                              how="parse"):
+                              records=self._n, how="parse") as labels:
+                        # this batch's records alone, in its order
+                        blob, offsets = self._logical_bytes()
+                        labels["bytes"] = len(blob)
                         rb = decode_records(
-                            blob, self._offsets, n_ref=self._n_ref)
-                        if self._order is not None:
-                            rb = rb.take(self._order)
+                            blob, offsets, n_ref=self._n_ref)
                     self._ragged_rb = rb
                     # the operator-suite resident-leg witness: a fully
                     # resident chain never host-parses records
@@ -469,15 +480,26 @@ class ColumnarBatch:
         """Spill as HOST data, never as device arrays: pickling the
         resident columns would be an uncounted implicit d2h, and the
         restored copy would re-book their avoidance on release. A
-        device-backed batch spills its host blob + offsets (plus any
-        ``permuted()`` order) and re-runs the fused build on load (a
+        device-backed batch spills its records' bytes in logical order
+        (a pending order folded into them: a selection's source blob
+        is not spilled whole) and re-runs the fused build on load (a
         resumed resident read stays device-backed with fresh, correct
         accounting); a host-backed one spills its plain ``ReadBatch``."""
         if self._blob is not None or self._blob_parts is not None:
             return (_rebuild_from_blob,
-                    (self._host_blob(), self._offsets, self._n_ref,
-                     self._order))
+                    (*self._logical_bytes(), self._n_ref))
         return (_rebuild_from_host, (self.to_read_batch(),))
+
+    def _logical_bytes(self):
+        """``(record bytes, (n + 1,) offsets)`` in logical order: the
+        held blob as it is, or, under a pending order, one gather by
+        it (the copy a compaction would have made)."""
+        blob = self._host_blob()
+        if self._order is None:
+            return blob, self._offsets
+        from disq_tpu.bam.columnar import segment_gather
+
+        return segment_gather(blob, self._offsets, self._order)
 
     # -- ReadBatch interop --------------------------------------------------
 
@@ -510,63 +532,121 @@ class ColumnarBatch:
         """Keep records where ``mask`` is true. Device-backed batches
         compact ON DEVICE (operator-suite tentpole a): the fixed
         columns are gathered by the kept indices in HBM — records the
-        mask drops never cross d2h — and the host record blob is
-        compacted by one vectorized segment gather, so the result is a
-        self-contained device-backed batch (``_order`` folded away:
-        concat / pickle / encode_source all see a plain source-order
-        blob). Host-backed batches materialize as before."""
+        mask drops never cross d2h — and the result is a device-backed
+        batch in one of two forms (``_compact_device``): the kept
+        records' bytes copied into a blob of its own, in source order
+        with no pending order, or the source's blob shared under a
+        pending order that selects the kept records. Either way
+        concat / pickle / ``encode_source`` / ``encoded_slice`` give
+        the kept records in logical order. Host-backed batches
+        materialize as before."""
         mask = np.asarray(mask)
         if self._dev_snapshot() is None or self._offsets is None:
             return self.to_read_batch().filter(mask)
         return self._compact_device(np.nonzero(mask)[0])
 
+    def _gathered(self, dev, idx: np.ndarray) -> "ColumnarBatch":
+        """A new device-backed batch of the logical records ``idx``:
+        the fixed columns gathered on device (one small index upload,
+        bucket-padded by its last entry; zero column round-trips), on
+        this batch's mesh. It holds no record bytes yet."""
+        from disq_tpu.runtime.tracing import count_transfer, track_hbm
+
+        k = len(idx)
+        pad = _bucket_n(k) - k
+        idx_host = np.empty(k + pad, np.int32)
+        idx_host[:k] = idx
+        idx_host[k:] = idx[-1] if k else 0
+        count_transfer("h2d", idx_host.nbytes)
+        idx_dev = _jax_fns()["jnp"].asarray(idx_host)
+        out = ColumnarBatch()
+        out._n = k
+        out._n_ref = self._n_ref
+        out._dev = {name: dev[name][idx_dev] for name in FIXED_COLUMNS}
+        if self._mesh is not None:
+            # the gather may have collapsed placement — restore the
+            # canonical batch sharding so downstream stages keep the
+            # one-sharded-program shape (moved bytes are booked into
+            # device.mesh.reshard_bytes, not h2d/d2h: nothing crosses
+            # the host)
+            from disq_tpu.runtime.mesh import mesh_put
+
+            out._dev = {name: mesh_put(col, self._mesh)
+                        for name, col in out._dev.items()}
+            out._mesh = self._mesh
+        out._hbm = len(out._dev) * (k + pad) * 4
+        track_hbm(out._hbm)
+        _note_build(out._hbm)
+        return out
+
+    def _share_bytes(self, out: "ColumnarBatch", src: np.ndarray) -> None:
+        """``out`` holds this batch's record bytes, offsets and span
+        cache as they are, under the pending order ``src`` (logical
+        record of ``out`` -> record of the blob). Neither side owns
+        the bytes from here on."""
+        with self._lock:
+            out._blob = self._blob
+            out._blob_parts = self._blob_parts
+            self._blob_owned = False
+        out._offsets = self._offsets
+        out._span_cache = self._span_cache
+        out._order = src
+
     def _compact_device(self, keep: np.ndarray) -> "ReadBatch | ColumnarBatch":
         """Device compaction gather behind ``filter``: ``keep`` holds
-        the kept logical indices, ascending."""
-        from disq_tpu.bam.columnar import segment_gather
-        from disq_tpu.runtime.tracing import (
-            count_transfer, span, track_hbm)
+        the kept logical indices, ascending. The 8 fixed columns are
+        gathered on the device either way; the record bytes take one
+        of two forms, said by the span's and the counter's ``how``:
+
+        - ``copied``: the kept records' bytes gathered into a fresh
+          blob that the result owns, source order, no pending order;
+        - ``deferred``: no byte moves. The result shares the blob, the
+          offsets and the span cache, as ``permuted()``'s does, under
+          the pending order ``order[keep]``; whoever wants the bytes
+          in order (the write's ``encoded_slice``, a concat, a spill)
+          gathers by it, once.
+
+        Deferred when the copy would free less than it costs: a
+        deferred result pins the whole blob for as long as it lives,
+        so a copy of ``k`` records frees ``held - k`` records' bytes,
+        and that is no more than it writes when ``2 * k >= held``
+        (``held`` the records the blob holds, which is this batch's
+        count unless it is deferred itself). The waste is then at
+        most as large as the answer. A filter that keeps one record
+        in eight (an interval read's) would pin eight times its answer
+        and keeps copying. What the code can see decides, not an
+        option."""
+        from disq_tpu.runtime.tracing import counter, span
 
         dev = self._dev_snapshot()
         keep = np.asarray(keep, dtype=np.int64)
         k = len(keep)
         if k == 0:
             return ColumnarBatch.from_host(ReadBatch.empty())
-        with span("columnar.batch.compact", records=self._n, kept=k):
-            # host blob compaction: gather the kept records' byte
-            # spans into a fresh contiguous blob (logical -> blob
-            # record index via any pending permutation)
+        with span("columnar.batch.compact", records=self._n,
+                  kept=k) as labels:
+            # logical -> blob record index via any pending order
             src = self._order[keep] if self._order is not None else keep
-            new_blob, new_off = segment_gather(
-                self._host_blob(), self._offsets, src)
-            fns = _jax_fns()
-            jnp = fns["jnp"]
-            pad = _bucket_n(k) - k
-            idx_host = np.empty(k + pad, np.int32)
-            idx_host[:k] = keep
-            idx_host[k:] = keep[-1]
-            count_transfer("h2d", idx_host.nbytes)
-            idx = jnp.asarray(idx_host)
-            out = ColumnarBatch.__new__(ColumnarBatch)
-            ColumnarBatch.__init__(out)
-            out._n = k
-            out._n_ref = self._n_ref
-            out._dev = {name: dev[name][idx] for name in FIXED_COLUMNS}
-            if self._mesh is not None:
-                from disq_tpu.runtime.mesh import mesh_put
+            out = self._gathered(dev, keep)
+            offsets = self._offsets
+            if 2 * k >= len(offsets) - 1:
+                how, moved = "deferred", 0
+                self._share_bytes(out, src)
+                kept_bytes = int((offsets[src + 1] - offsets[src]).sum())
+            else:
+                from disq_tpu.bam.columnar import segment_gather
 
-                out._dev = {name: mesh_put(col, self._mesh)
-                            for name, col in out._dev.items()}
-                out._mesh = self._mesh
-            out._blob = new_blob
-            out._offsets = new_off
-            out._blob_owned = True
-            spans = self._span_cache.spans
-            if spans is not None:
-                out._span_cache.keep(spans[0][src], spans[1][src])
-            out._hbm = len(out._dev) * (k + pad) * 4
-            track_hbm(out._hbm)
-            _note_build(out._hbm)
+                how = "copied"
+                out._blob, out._offsets = segment_gather(
+                    self._host_blob(), offsets, src)
+                out._blob_owned = True
+                spans = self._span_cache.spans
+                if spans is not None:
+                    out._span_cache.keep(spans[0][src], spans[1][src])
+                kept_bytes = moved = len(out._blob)
+            labels["how"], labels["bytes"] = how, moved
+            # the kept records' bytes: gathered | left where they were
+            counter("columnar.batch.compact_bytes").inc(kept_bytes, how=how)
         return out
 
     def or_flags(self, mask: np.ndarray, bits: int = 0x400) -> None:
@@ -574,28 +654,36 @@ class ColumnarBatch:
         true — duplicate marking's write-back. Three synchronized
         views update: the resident flag column (in HBM, one small mask
         upload), the host record blob's flag bytes (copy-on-write
-        unless this batch owns its blob), and any host caches (dropped
-        so the next fetch re-derives). The blob patch is what makes
-        the resident write path's output byte-identical to a
-        host-marked file."""
+        unless this batch owns its blob, which a batch that shares
+        its source's never does: the source's bytes and a sibling's
+        are not patched; span ``columnar.batch.patch``), and any host
+        caches (dropped so the next fetch re-derives). The blob patch
+        is what makes the resident write path's output byte-identical
+        to a host-marked file."""
         idx = np.nonzero(np.asarray(mask))[0]
         if len(idx) == 0:
             return
         lo_b, hi_b = bits & 0xFF, (bits >> 8) & 0xFF
         with self._lock:
             if self._offsets is not None:
-                blob = self._host_blob()
-                if not self._blob_owned:
-                    blob = blob.copy()
-                    self._blob_owned = True
-                src = (self._order[idx]
-                       if self._order is not None else idx)
-                off = self._offsets[src]
-                if lo_b:
-                    blob[off + 18] |= np.uint8(lo_b)
-                if hi_b:
-                    blob[off + 19] |= np.uint8(hi_b)
-                self._blob = blob
+                from disq_tpu.runtime.tracing import span
+
+                with span("columnar.batch.patch",
+                          records=len(idx)) as labels:
+                    blob = self._host_blob()
+                    labels["bytes"] = len(blob)
+                    labels["copied"] = int(not self._blob_owned)
+                    if not self._blob_owned:
+                        blob = blob.copy()
+                        self._blob_owned = True
+                    src = (self._order[idx]
+                           if self._order is not None else idx)
+                    off = self._offsets[src]
+                    if lo_b:
+                        blob[off + 18] |= np.uint8(lo_b)
+                    if hi_b:
+                        blob[off + 19] |= np.uint8(hi_b)
+                    self._blob = blob
             dev = self._dev
             if dev is not None:
                 from disq_tpu.runtime.tracing import count_transfer
@@ -727,6 +815,9 @@ class ColumnarBatch:
         scores)``, i64 each, in logical order: duplicate marking's
         inputs from one sweep over the record bytes
         (``ops/markdup.key_sweep_from_blob``), no host record parse.
+        The sweep reads the whole blob, in its own order, also where a
+        pending order selects some of its records: what it leaves in
+        the span cache serves the source and every view of it.
         The reference lengths are the span cache's where it holds them
         (``ends_source`` then says ``cached``); else the sweep's are
         kept there with their ends (``swept``). Clips and scores are
@@ -864,52 +955,31 @@ class ColumnarBatch:
         dev = self._dev_snapshot()
         if dev is None or self._offsets is None:
             return ColumnarBatch.from_host(self.to_read_batch().take(order))
-        from disq_tpu.runtime.tracing import count_transfer, track_hbm
-
-        fns = _jax_fns()
-        jnp = fns["jnp"]
-        base = self._order[order] if self._order is not None else order
-        pad = _bucket_n(self._n) - self._n
-        idx_host = np.empty(self._n + pad, np.int32)
-        idx_host[: self._n] = order
-        idx_host[self._n:] = order[-1] if self._n else 0
-        count_transfer("h2d", idx_host.nbytes)
-        idx = jnp.asarray(idx_host)
-        out = ColumnarBatch.__new__(ColumnarBatch)
-        ColumnarBatch.__init__(out)
-        out._n = self._n
-        out._n_ref = self._n_ref
-        out._dev = {name: dev[name][idx] for name in FIXED_COLUMNS}
-        if self._mesh is not None:
-            # the gather may have collapsed placement — restore the
-            # canonical batch sharding so downstream stages keep the
-            # one-sharded-program shape (moved bytes are booked into
-            # device.mesh.reshard_bytes, not h2d/d2h: nothing crosses
-            # the host)
-            from disq_tpu.runtime.mesh import mesh_put
-
-            out._dev = {name: mesh_put(col, self._mesh)
-                        for name, col in out._dev.items()}
-            out._mesh = self._mesh
-        out._blob = self._blob
-        out._blob_parts = self._blob_parts
-        out._offsets = self._offsets
-        out._span_cache = self._span_cache
-        out._order = base
-        out._hbm = len(out._dev) * (self._n + pad) * 4
-        track_hbm(out._hbm)
-        _note_build(out._hbm)
+        out = self._gathered(dev, order)
+        self._share_bytes(
+            out, self._order[order] if self._order is not None else order)
         return out
 
+    @property
+    def holds_bytes(self) -> bool:
+        """True when the batch holds its records' bytes
+        (``encode_source()`` is not None), asked without joining a
+        concat's parts: whoever joins them owns the join, so a caller
+        that only wants to know must not be the one."""
+        with self._lock:
+            return self._offsets is not None and (
+                self._blob is not None or self._blob_parts is not None)
+
     def encode_source(self):
-        """The ``(record blob, record offsets, permutation-or-None)``
+        """The ``(record blob, record offsets, pending order or None)``
         triple the resident encode path needs, or None when this batch
         holds no host record blob (host-built batches encode through
-        the classic ``encode_records`` path)."""
-        with self._lock:
-            if self._offsets is None or (
-                    self._blob is None and self._blob_parts is None):
-                return None
+        the classic ``encode_records`` path). The order maps each of
+        this batch's records to a record of the blob: a permutation of
+        them all, or, after a filter that kept its source's bytes, a
+        selection of ``count`` of them. Index ``offsets`` through it."""
+        if not self.holds_bytes:
+            return None
         return self._host_blob(), self._offsets, self._order
 
     def encoded_slice(self, lo: int, hi: int):
@@ -1032,21 +1102,33 @@ class ColumnarBatch:
             # host blobs join LAZILY (first ragged access / pickle):
             # a flagstat-only multi-shard read never pays the
             # O(total-decoded-bytes) memcpy or its transient 2x host
-            # RAM peak
+            # RAM peak. An input under a pending order (a sort's, a
+            # filter's that kept its source's bytes) has its columns
+            # in logical order already; its bytes follow here, by the
+            # one gather a compaction would have made
             parts: List[np.ndarray] = []
-            for b in batches:
-                parts.extend(b._blob_parts if b._blob_parts is not None
-                             else [b._blob])
-            self._blob_parts = parts
             offs = np.zeros(self._n + 1, dtype=np.int64)
+            held = []
             at = 1
             pos = 0
             for b in batches:
-                offs[at: at + b._n] = b._offsets[1:] + pos
+                spans = b._span_cache.spans
+                if b._order is not None:
+                    blob, b_offs = b._logical_bytes()
+                    parts.append(blob)
+                    if spans is not None:
+                        spans = (spans[0][b._order], spans[1][b._order])
+                else:
+                    parts.extend(b._blob_parts if b._blob_parts is not None
+                                 else [b._blob])
+                    b_offs = b._offsets
+                    b._blob_owned = False  # the result holds it too
+                held.append(spans)
+                offs[at: at + b._n] = b_offs[1:] + pos
                 at += b._n
-                pos += int(b._offsets[-1])
+                pos += int(b_offs[-1])
+            self._blob_parts = parts
             self._offsets = offs
-            held = [b._span_cache.spans for b in batches]
             if all(sp is not None for sp in held):
                 self._span_cache.keep(
                     np.concatenate([sp[0] for sp in held]),
@@ -1112,14 +1194,10 @@ class ColumnarBatch:
             pass
 
 
-def _rebuild_from_blob(blob, offsets, n_ref,
-                       order=None) -> "ColumnarBatch":
+def _rebuild_from_blob(blob, offsets, n_ref) -> "ColumnarBatch":
     """Unpickle target for a spilled device-backed batch (module-level
     so pickle resolves it by name)."""
-    batch = ColumnarBatch.from_blob(blob, offsets, n_ref=n_ref)
-    if order is not None and isinstance(batch, ColumnarBatch):
-        batch = batch.permuted(order)
-    return batch
+    return ColumnarBatch.from_blob(blob, offsets, n_ref=n_ref)
 
 
 def _rebuild_from_host(batch: ReadBatch) -> "ColumnarBatch":

@@ -5,9 +5,12 @@ pipeline on the columnar currency).
 An ``OpPipeline`` is an ordered list of operators applied shard-wise
 between decode and sink/reduce. Every transform speaks
 ``ColumnarBatch`` in and out, so a chain over resident shards never
-materializes host records: ``filter`` compacts on device, ``sort``
-returns a ``permuted()`` resident batch, ``markdup`` patches flag
-bits in HBM *and* in the record blob bytes, and the reductions
+materializes host records: ``filter`` gathers the kept records'
+columns on device and, of their bytes, copies the kept ones or keeps
+its source's under a pending order (``ColumnarBatch._compact_device``
+says when), ``sort`` returns a ``permuted()`` resident batch,
+``markdup`` patches flag bits in HBM *and* in the record blob bytes
+(a copy of its own where the blob is shared), and the reductions
 (``pileup`` / ``rgstats``) only move their result rows d2h. Host
 ``ReadBatch`` shards run the same operators through their host paths
 — identical outputs, different residency.

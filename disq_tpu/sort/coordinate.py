@@ -69,7 +69,7 @@ def coordinate_sort_batch(batch: ReadBatch, use_mesh: bool = True,
             # where it crosses d2h) this is what lies between the sort
             # kernel and the write: the records gathered on the host
             with span("sort.gather", stage="gather", records=batch.count):
-                if keep_resident and batch.encode_source() is not None:
+                if keep_resident and batch.holds_bytes:
                     return batch.permuted(order)
                 return batch.take(order)
         resident_src = batch if keep_resident else None
@@ -91,6 +91,6 @@ def coordinate_sort_batch(batch: ReadBatch, use_mesh: bool = True,
             _, order = sharded_coordinate_sort(keys)
     if order is None:
         order = np.argsort(keys, kind="stable")
-    if resident_src is not None and resident_src.encode_source() is not None:
+    if resident_src is not None and resident_src.holds_bytes:
         return resident_src.permuted(order)
     return batch.take(order)
