@@ -9,10 +9,9 @@ The quickest proof that disq-tpu still starts on a TPU. One process:
    that read against the generator's own arrays — the plain reference;
 3. reads it again through the device path (``DISQ_TPU_DEVICE_INFLATE=1`` +
    ``DISQ_TPU_DEVICE_SERVICE=1`` + ``.resident_decode()``), sorts, writes
-   BAM + BAI + SBI (byte-identical to the host path), writes again with
-   ``.device_deflate()`` (byte-valid: ``gzip -t`` + identical records), runs
-   the operator chain, a CRAM round trip with ``DISQ_TPU_DEVICE_RANS=1`` and
-   a few serve requests — every device result held to the host result;
+   BAM + BAI + SBI (byte-identical to the host path), runs the operator
+   chain, a CRAM round trip with ``DISQ_TPU_DEVICE_RANS=1`` and a few serve
+   requests — every device result held to the host result;
 4. with four or more chips, repeats read → sort → write on a 4-device mesh,
    at size/8 splits and at the configured split size.
 
@@ -525,19 +524,6 @@ class Smoke:
         gzip_t(out)
         self.assert_sorted_identical(out, "device sort+write")
 
-    def device_deflate_write(self) -> None:
-        out = self.path("device_deflate.bam")
-        with device_knobs():
-            self.device_storage().device_deflate().write(
-                self.device_ds, out, *sorted_bam_options(), sort=True)
-        gzip_t(out)
-        back = self.host_storage().read(out)
-        check(back.header.sort_order == "coordinate",
-              "device-deflate write: header is not SO:coordinate")
-        assert_columns_equal(back.reads, self.host_sorted.reads, ALL_COLUMNS,
-                             "device-deflate write re-read vs host sort")
-        self.notes["device_deflate_bgzf_bytes"] = os.path.getsize(out)
-
     # -- operator chain -----------------------------------------------------
 
     def operators(self) -> None:
@@ -833,7 +819,6 @@ def main(argv=None) -> int:
                 ("host_sort_write", smoke.host_sort_write),
                 ("device_read", smoke.device_read),
                 ("device_sort_write", smoke.device_sort_write),
-                ("device_deflate_write", smoke.device_deflate_write),
                 ("operators", smoke.operators),
                 ("cram", smoke.cram),
                 ("serve", smoke.serve)]
@@ -858,8 +843,7 @@ def main(argv=None) -> int:
     print("device counters:", flush=True)
     for name, series in counters.items():
         print(f"  {name}: {series}", flush=True)
-    for kernel in ("inflate_simd", "columnar_parse", "rans_simd",
-                   "deflate_simd", "encode_resident"):
+    for kernel in ("inflate_simd", "columnar_parse", "rans_simd"):
         check(launches.get(f"kernel={kernel}", 0) > 0,
               f"no kernel={kernel} launch was booked")
     check(fallbacks.get("reason=flagged", 0) == 0,

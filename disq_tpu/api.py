@@ -206,10 +206,8 @@ class ReadsDataset:
         """Coordinate-sort the dataset.  ``keep_resident`` keeps a
         device-backed ``ColumnarBatch`` device-backed through the sort
         (fixed columns permuted on device, host records never
-        materialized) so the device write path's resident encode →
-        deflate chain can consume it directly — armed automatically by
-        ``ReadsStorage.write(..., sort=True)`` when
-        ``DisqOptions.device_deflate`` is on."""
+        materialized) for the operators and the write from bytes that
+        follow it."""
         from disq_tpu.sort.coordinate import coordinate_sort_batch
 
         header = self.header.with_sort_order("coordinate")
@@ -574,21 +572,6 @@ class ReadsStorage:
         self._options = self._options.with_resident_decode(enable)
         return self
 
-    def device_deflate(self, enable: bool = True) -> "ReadsStorage":
-        """Arm the symmetric device write path (``ops/deflate.py`` +
-        ``runtime/device_write.py``): every BGZF deflate this storage's
-        sinks run routes through the 128-lane SIMD entropy coder
-        (coalesced across in-flight write shards when the device
-        service is up), and a ``write(..., sort=True)`` of a resident
-        ``ColumnarBatch`` keeps the sorted records device-side through
-        encode → deflate — only compressed blocks (plus their sizes,
-        which the voffset/BAI arithmetic needs) cross d2h.  Output is
-        byte-VALID BGZF readable by every reader, but NOT
-        byte-identical to the canonical host zlib pin.  Env
-        equivalent: ``DISQ_TPU_DEVICE_DEFLATE``."""
-        self._options = self._options.with_device_deflate(enable)
-        return self
-
     def mesh(self, devices: int = 0) -> "ReadsStorage":
         """Arm the mesh-native pipeline (``runtime/mesh.py``): resident
         parse batches shard over a ``batch`` device axis with
@@ -663,10 +646,7 @@ class ReadsStorage:
         from disq_tpu.runtime import flightrec
 
         if sort:
-            from disq_tpu.bgzf.codec import device_deflate_enabled
-
-            dataset = dataset.coordinate_sorted(
-                keep_resident=device_deflate_enabled(self))
+            dataset = dataset.coordinate_sorted()
         fmt_opt = _opt(options, ReadsFormatWriteOption, None)
         fmt = sam_format_from_write_options(path, fmt_opt)
         cardinality = _opt(options, FileCardinalityWriteOption, _infer_cardinality(path))
@@ -811,13 +791,6 @@ class VariantsStorage:
         option sets stay interchangeable across storages (the variant
         columnar currency is ROADMAP item 4's port)."""
         self._options = self._options.with_resident_decode(enable)
-        return self
-
-    def device_deflate(self, enable: bool = True) -> "VariantsStorage":
-        """See ``ReadsStorage.device_deflate``: routes every BGZF
-        deflate of this storage's sinks (VCF_BGZ parts and headers,
-        BCF's whole-stream blocks) through the device SIMD encoder."""
-        self._options = self._options.with_device_deflate(enable)
         return self
 
     def mesh(self, devices: int = 0) -> "VariantsStorage":

@@ -28,7 +28,7 @@ protocol are what make that safe.
 from __future__ import annotations
 
 import os
-from typing import List, Optional, Sequence, Tuple
+from typing import List, Sequence, Tuple
 
 import numpy as np
 
@@ -43,11 +43,7 @@ from disq_tpu.bam.codec import encode_records, encode_records_with_offsets
 from disq_tpu.bam.columnar import ReadBatch
 from disq_tpu.bam.header import SamHeader
 from disq_tpu.bgzf.block import BGZF_EOF_MARKER, BGZF_MAX_PAYLOAD
-from disq_tpu.bgzf.codec import (
-    compress_to_bgzf,
-    deflate_blob,
-    device_deflate_enabled,
-)
+from disq_tpu.bgzf.codec import compress_to_bgzf, deflate_blob
 from disq_tpu.fsw.filesystem import FileSystemWrapper, resolve_path
 from disq_tpu.index.bai import BaiIndex, build_bai, merge_bai_fragments
 from disq_tpu.index.sbi import SbiIndex
@@ -91,52 +87,36 @@ def _opt_enabled(options: Sequence[WriteOption], cls, default: bool) -> bool:
     return default
 
 
-def voffsets_from_csizes(
-    csizes: np.ndarray, record_offsets: np.ndarray
-) -> Tuple[np.ndarray, np.ndarray]:
-    """(start voffsets, end voffsets) for records at uncompressed
-    offsets ``record_offsets`` ((N+1,)) inside a BGZF stream whose
-    per-block compressed sizes are ``csizes`` — pure array arithmetic,
-    shared by the host deflate and the device write path (whose csizes
-    are the only thing that crosses d2h)."""
+def bgzf_compress_with_voffsets(
+    blob: bytes, record_offsets: np.ndarray
+) -> Tuple[bytes, np.ndarray, np.ndarray]:
+    """Deflate ``blob`` into canonical BGZF (no terminator) and return
+    (compressed bytes, start voffsets, end voffsets) for the records whose
+    uncompressed offsets are ``record_offsets`` ((N+1,): starts + end):
+    array arithmetic over the per-block compressed sizes."""
+    comp, csizes = deflate_blob(blob)
     block_comp_start = np.zeros(len(csizes) + 1, dtype=np.int64)
     np.cumsum(csizes, out=block_comp_start[1:])
     offs = record_offsets.astype(np.int64)
     block_idx = offs // BGZF_MAX_PAYLOAD
     within = offs % BGZF_MAX_PAYLOAD
     voffs = (block_comp_start[block_idx].astype(np.uint64) << np.uint64(16)) | within.astype(np.uint64)
-    return voffs[:-1], voffs[1:]
-
-
-def bgzf_compress_with_voffsets(
-    blob: bytes, record_offsets: np.ndarray, device: Optional[bool] = None
-) -> Tuple[bytes, np.ndarray, np.ndarray]:
-    """Deflate ``blob`` into canonical BGZF (no terminator) and return
-    (compressed bytes, start voffsets, end voffsets) for the records whose
-    uncompressed offsets are ``record_offsets`` ((N+1,): starts + end).
-    ``device`` routes the deflate like ``bgzf.codec.deflate_blob``."""
-    comp, csizes = deflate_blob(blob, device=device)
-    voffs, end_voffs = voffsets_from_csizes(csizes, record_offsets)
-    return comp, voffs, end_voffs
+    return comp, voffs[:-1], voffs[1:]
 
 
 class _LazySlice:
-    """Deferred shard slice for the two resident write paths (record
-    bytes encoded on the device, or copied from the batch's blob): what
-    a shard's index fragments ask of it comes without a host parse.
-    With the shard's ``encoded`` bytes and record offsets, ``refid``,
-    ``pos`` and ``flag`` are read from those bytes at the offsets (so
-    they are the index of what is written, and cost no d2h), and the
-    alignment ends come from the batch's span cache for this shard's
-    records alone. Without them (the device holds the bytes) the index
-    builders' columns come from a real slice, as does any other
-    attribute either way: a plain (no-index) resident write never
-    materializes host records at all, and a reader of a ragged column
+    """Deferred shard slice for the write from a batch's record bytes:
+    what a shard's index fragments ask of it comes without a host
+    parse. ``refid``, ``pos`` and ``flag`` are read from the shard's
+    ``encoded`` bytes at its record offsets (so they are the index of
+    what is written, and cost no d2h), and the alignment ends come from
+    the batch's span cache for this shard's records alone. Any other
+    attribute comes from a real slice: a reader of a ragged column
     only pays."""
 
     __slots__ = ("_batch", "_lo", "_hi", "_part", "_encoded")
 
-    def __init__(self, batch, lo: int, hi: int, encoded=None) -> None:
+    def __init__(self, batch, lo: int, hi: int, encoded) -> None:
         self._batch = batch
         self._lo, self._hi = lo, hi
         self._part = None
@@ -152,13 +132,11 @@ class _LazySlice:
         return self._part
 
     def alignment_ends(self):
-        if self._encoded is None:
-            return self._mat().alignment_ends()
         return self._batch.alignment_ends(self._lo, self._hi)
 
     def __getattr__(self, name: str):
         # only what no slot or property answers lands here
-        at = _FIELD_AT.get(name) if self._encoded is not None else None
+        at = _FIELD_AT.get(name)
         if at is None:
             return getattr(self._mat(), name)
         blob, offs = self._encoded
@@ -182,19 +160,10 @@ class BamSink:
     (``ColumnarBatch.encoded_slice``), no record is parsed and none is
     encoded again; the files are the column encoder's, byte for byte.
     Any other batch (a ``ReadBatch``, a host-built ``ColumnarBatch``)
-    is sliced and encoded from its columns.
-
-    With ``DisqOptions.device_deflate`` armed, the per-shard deflate
-    routes through the device SIMD encoder (service-coalesced across
-    in-flight write shards), and such a device-backed batch encodes
-    its records ON DEVICE instead (``runtime/device_write.py``): sort
-    permutation → record-byte gather → entropy coder run HBM-resident,
-    and only compressed blocks (plus csizes for the voffset/BAI
-    arithmetic) cross d2h."""
+    is sliced and encoded from its columns."""
 
     def __init__(self, storage=None):
         self._storage = storage
-        self._device = False
 
     def _num_shards(self) -> int:
         return resolve_num_shards(self._storage)
@@ -222,12 +191,6 @@ class BamSink:
             (o for o in options if isinstance(o, StageManifestWriteOption)), None
         )
         n_shards, bounds = shard_bounds(self._storage, batch.count)
-        self._device = device_deflate_enabled(self._storage)
-        resident = None
-        if self._device:
-            from disq_tpu.runtime.device_write import resident_encoder_for
-
-            resident = resident_encoder_for(self._storage, batch)
         if manifest_opt is not None:
             from disq_tpu.runtime import StageManifest
 
@@ -240,18 +203,13 @@ class BamSink:
                     "n_shards": int(n_shards),
                     "bai": write_bai,
                     "sbi": write_sbi,
-                    # the device coder's bytes are valid but not
-                    # byte-identical to the zlib pin: flipping the knob
-                    # between a crash and a resume must reset staging,
-                    # not concatenate mixed-provenance parts
-                    "device_deflate": bool(self._device),
                 },
             )
         fs.mkdirs(temp_dir)
         try:
             self._write_parts_and_merge(
                 fs, header, batch, path, temp_dir, n_shards, bounds,
-                write_bai, write_sbi, manifest, resident,
+                write_bai, write_sbi, manifest,
             )
         except BaseException:
             # Idempotent write protocol (SURVEY.md §5): the merge is the
@@ -272,21 +230,15 @@ class BamSink:
 
     # -- pipeline stage bodies (encode → deflate → stage) -------------------
 
-    def _encode_shard(self, batch, bounds, k, resident=None):
+    def _encode_shard(self, batch, bounds, k):
         """Stage 1: shard ``k``'s records as BAM bytes, by what the
-        batch is. With the resident write path armed, a device
-        record-byte gather (the encoded blob then stays in HBM for the
-        deflate stage; host columns materialize only if an index build
-        asks for them). Else, from a batch that holds its records'
-        bytes, a host copy of them in the batch's order
-        (``encoded_slice``: no parse, no encode, the writers side by
-        side). Else a slice of the columns and the CPU record encode.
-        ``bam.write.slice`` times the cut either way, and
-        ``bam.write.encoded_records{how}`` counts the records by it."""
+        batch is. From a batch that holds its records' bytes, a host
+        copy of them in the batch's order (``encoded_slice``: no parse,
+        no encode, the writers side by side). Else a slice of the
+        columns and the CPU record encode. ``bam.write.slice`` times
+        the cut either way, and ``bam.write.encoded_records{how}``
+        counts the records by it."""
         lo, hi = int(bounds[k]), int(bounds[k + 1])
-        if resident is not None:
-            enc = resident.encode_shard(lo, hi)
-            return _LazySlice(batch, lo, hi), enc, enc.record_offsets
         from disq_tpu.runtime.tracing import counter, span
 
         encoded_slice = getattr(batch, "encoded_slice", None)
@@ -301,19 +253,12 @@ class BamSink:
         return (part, *encoded)
 
     def _deflate_shard(self, header, write_bai, write_sbi, payload):
-        """Stage 2 (native-threaded CPU, or the device SIMD coder):
-        BGZF deflate, vectorized voffset arithmetic, and index-fragment
-        build.  A resident-encoded shard deflates straight from its
-        device blob — only compressed blocks and csizes come back."""
+        """Stage 2 (native-threaded CPU): BGZF deflate, vectorized
+        voffset arithmetic, and index-fragment build."""
         from disq_tpu.runtime import check_voffsets, debug_enabled
 
         part, blob, rec_offs = payload
-        if hasattr(blob, "deflate"):  # runtime/device_write.EncodedShard
-            comp, csizes = blob.deflate()
-            voffs, end_voffs = voffsets_from_csizes(csizes, rec_offs)
-        else:
-            comp, voffs, end_voffs = bgzf_compress_with_voffsets(
-                blob, rec_offs, device=self._device)
+        comp, voffs, end_voffs = bgzf_compress_with_voffsets(blob, rec_offs)
         if debug_enabled():
             check_voffsets(voffs)
         sbi_frag = bai_frag = None
@@ -354,7 +299,7 @@ class BamSink:
 
     def _write_one_part(
         self, fs, header, batch, temp_dir, bounds, write_bai, write_sbi, k,
-        frag_cache=None, resident=None,
+        frag_cache=None,
     ) -> dict:
         """Whole-shard unit (encode + deflate + stage in one call) —
         the sequential manifest path's work function, and the
@@ -362,7 +307,7 @@ class BamSink:
         from disq_tpu.runtime.tracing import span
 
         with span("bam.write.encode", shard=k):
-            payload = self._encode_shard(batch, bounds, k, resident)
+            payload = self._encode_shard(batch, bounds, k)
         with span("bam.write.deflate", shard=k):
             payload = self._deflate_shard(header, write_bai, write_sbi,
                                           payload)
@@ -392,7 +337,7 @@ class BamSink:
 
     def _make_write_task(self, fs, header, batch, temp_dir, bounds,
                          write_bai, write_sbi, k, frag_cache,
-                         resident=None, byte_range=None):
+                         byte_range=None):
         from disq_tpu.runtime.executor import (
             WriteShardTask,
             write_retrier_for_storage,
@@ -404,7 +349,7 @@ class BamSink:
             shard_id=k,
             encode=wrap_span(
                 "bam.write.encode",
-                lambda: self._encode_shard(batch, bounds, k, resident),
+                lambda: self._encode_shard(batch, bounds, k),
                 shard=k),
             deflate=wrap_span(
                 "bam.write.deflate",
@@ -422,7 +367,7 @@ class BamSink:
 
     def _write_parts_and_merge(
         self, fs, header, batch, path, temp_dir, n_shards, bounds,
-        write_bai, write_sbi, manifest=None, resident=None,
+        write_bai, write_sbi, manifest=None,
     ) -> None:
         from disq_tpu.runtime import trace_phase
         from disq_tpu.runtime.executor import (
@@ -437,51 +382,36 @@ class BamSink:
         # resumed shards reload from disk below.
         frag_cache = None if manifest is not None else {}
 
-        # the historical 9-arg call survives when the resident path is
-        # off (tests wrap _write_one_part with that exact signature);
-        # the device write path extends it only when armed
-        if resident is None:
-            def one_part(k):
-                return self._write_one_part(
-                    fs, header, batch, temp_dir, bounds,
-                    write_bai, write_sbi, k)
-        else:
-            def one_part(k):
-                return self._write_one_part(
-                    fs, header, batch, temp_dir, bounds,
-                    write_bai, write_sbi, k, resident=resident)
-        try:
-            with trace_phase("bam.write.parts"):
-                from disq_tpu.runtime.scheduler import write_leasing_armed
+        def one_part(k):
+            return self._write_one_part(
+                fs, header, batch, temp_dir, bounds,
+                write_bai, write_sbi, k)
 
-                leasing = write_leasing_armed(self._storage)
-                if (manifest is not None and pipeline.workers == 1
-                        and not leasing):
-                    # Historical sequential-checkpoint path: run_stage
-                    # owns skip/retry/RuntimeError semantics per shard.
-                    infos = manifest.run_stage(
-                        "bam.parts", n_shards, one_part)
-                else:
-                    # byte ranges feed write-lease locality scoring;
-                    # off-path saves skip the O(n) size walk entirely
-                    ranges = (self._part_byte_ranges(batch, bounds)
-                              if leasing and manifest is not None
-                              else None)
-                    infos = run_write_stage(
-                        pipeline, n_shards,
-                        lambda k: self._make_write_task(
-                            fs, header, batch, temp_dir, bounds,
-                            write_bai, write_sbi, k, frag_cache,
-                            resident,
-                            byte_range=(ranges[k] if ranges else None)),
-                        manifest=manifest, stage_name="bam.parts",
-                        storage=self._storage, path=path, fs=fs,
-                    )
-        finally:
-            if resident is not None:
-                # the shared record-blob upload dies with the parts
-                # stage; the merge below is host-side concat only
-                resident.release()
+        with trace_phase("bam.write.parts"):
+            from disq_tpu.runtime.scheduler import write_leasing_armed
+
+            leasing = write_leasing_armed(self._storage)
+            if (manifest is not None and pipeline.workers == 1
+                    and not leasing):
+                # Historical sequential-checkpoint path: run_stage
+                # owns skip/retry/RuntimeError semantics per shard.
+                infos = manifest.run_stage(
+                    "bam.parts", n_shards, one_part)
+            else:
+                # byte ranges feed write-lease locality scoring;
+                # off-path saves skip the O(n) size walk entirely
+                ranges = (self._part_byte_ranges(batch, bounds)
+                          if leasing and manifest is not None
+                          else None)
+                infos = run_write_stage(
+                    pipeline, n_shards,
+                    lambda k: self._make_write_task(
+                        fs, header, batch, temp_dir, bounds,
+                        write_bai, write_sbi, k, frag_cache,
+                        byte_range=(ranges[k] if ranges else None)),
+                    manifest=manifest, stage_name="bam.parts",
+                    storage=self._storage, path=path, fs=fs,
+                )
         part_paths = [i["part"] for i in infos]
         part_lens = [i["len"] for i in infos]
 
@@ -504,8 +434,7 @@ class BamSink:
         driver = write_retrier_for_storage(self._storage, path)
         with trace_phase("bam.write.merge"):
             header_comp = compress_to_bgzf(
-                header.to_bam_bytes(), with_terminator=False,
-                device=self._device)
+                header.to_bam_bytes(), with_terminator=False)
             header_path = os.path.join(temp_dir, "_header")
             driver.call(fs.write_all, header_path, header_comp,
                         what="bam.merge")
@@ -551,7 +480,6 @@ class BamSinkMultiple:
         n_shards, bounds = shard_bounds(self._storage, batch.count)
         fs.mkdirs(path)
         header_bytes = header.to_bam_bytes()
-        device = device_deflate_enabled(self._storage)
 
         def make_task(k):
             def encode():
@@ -567,9 +495,7 @@ class BamSinkMultiple:
                 shard_id=k,
                 encode=wrap_span("bam.write.encode", encode, shard=k),
                 deflate=wrap_span(
-                    "bam.write.deflate",
-                    lambda data: compress_to_bgzf(data, device=device),
-                    shard=k),
+                    "bam.write.deflate", compress_to_bgzf, shard=k),
                 stage=wrap_span("bam.write.stage", stage, shard=k),
                 retrier=write_retrier_for_storage(self._storage, path),
                 what="bam.part",

@@ -31,7 +31,7 @@ from __future__ import annotations
 import io
 import struct
 import zlib
-from typing import BinaryIO, List, Optional, Sequence
+from typing import BinaryIO, List, Sequence
 
 from disq_tpu.bgzf.block import (
     BGZF_EOF_MARKER,
@@ -264,65 +264,15 @@ def _verify_block_crcs(data, blocks, base, blob, offsets) -> None:
             check(i)
 
 
-def device_deflate_enabled(storage=None) -> bool:
-    """True when the device write path is armed for this storage:
-    ``DisqOptions.device_deflate`` or the ``DISQ_TPU_DEVICE_DEFLATE``
-    env knob.  The storage-aware mirror of the read side's
-    ``runtime/columnar.resident_decode_enabled``."""
-    opts = getattr(storage, "_options", None)
-    if opts is not None and getattr(opts, "device_deflate", False):
-        return True
-    from disq_tpu.runtime.debug import env_flag
-
-    return env_flag("DISQ_TPU_DEVICE_DEFLATE")
-
-
-def deflate_blob_for(storage, blob) -> tuple[bytes, "np.ndarray"]:
-    """THE routed deflate entry point every sink uses: canonical host
-    zlib by default, the device SIMD encoder (service-coalesced when
-    the decode service is up) behind ``DisqOptions.device_deflate`` /
-    ``DISQ_TPU_DEVICE_DEFLATE`` — so the knob covers every BGZF write
-    (BAM parts, VCF_BGZ parts and headers, BCF's whole-stream blocks)."""
-    return deflate_blob(blob, device=device_deflate_enabled(storage))
-
-
-def deflate_blob(blob: bytes,
-                 device: Optional[bool] = None) -> tuple[bytes, "np.ndarray"]:
+def deflate_blob(blob: bytes) -> tuple[bytes, "np.ndarray"]:
     """Deflate a payload into canonical BGZF blocks (no terminator);
     returns (compressed bytes, per-block compressed sizes). The sizes
     vector is what makes write-side virtual offsets computable by array
-    arithmetic (BamSink). Native-threaded when built.
-
-    ``device`` (None ⇒ the ``DISQ_TPU_DEVICE_DEFLATE`` env knob)
-    selects the device dynamic-Huffman encoder instead — valid BGZF
-    but NOT byte-identical to the canonical zlib pin.  With the device
-    service up (``DISQ_TPU_DEVICE_SERVICE=1``) the block payloads are
-    submitted to its deflate queue, where blocks from concurrently
-    writing shards coalesce into full 128-lane encode launches."""
+    arithmetic (BamSink). Native-threaded when built."""
     import numpy as np
 
     if len(blob) == 0:
         return b"", np.zeros(0, dtype=np.int64)
-    if device is None:
-        from disq_tpu.runtime.debug import env_flag
-
-        device = env_flag("DISQ_TPU_DEVICE_DEFLATE")
-    if device:
-        from disq_tpu.runtime import device_service
-
-        if device_service.enabled():
-            mv = memoryview(blob)
-            payloads = [
-                mv[o: o + BGZF_MAX_PAYLOAD]
-                for o in range(0, len(blob), BGZF_MAX_PAYLOAD)
-            ]
-            parts = device_service.get_service().submit_deflate(
-                payloads).result()
-            sizes = np.array([len(p) for p in parts], dtype=np.int64)
-            return b"".join(parts), sizes
-        from disq_tpu.ops.deflate import deflate_blob_device
-
-        return deflate_blob_device(blob)
     pay_off = np.arange(0, len(blob) + BGZF_MAX_PAYLOAD, BGZF_MAX_PAYLOAD, dtype=np.int64)
     pay_off[-1] = len(blob)
     try:
@@ -371,11 +321,9 @@ def deflate_block(payload: bytes) -> bytes:
     )
 
 
-def compress_to_bgzf(data: bytes, with_terminator: bool = True,
-                     device: Optional[bool] = None) -> bytes:
-    """Whole buffer → BGZF bytes (blocks of ≤65280 payload).
-    ``device`` routes like ``deflate_blob``."""
-    comp, _ = deflate_blob(data, device=device)
+def compress_to_bgzf(data: bytes, with_terminator: bool = True) -> bytes:
+    """Whole buffer → BGZF bytes (blocks of ≤65280 payload)."""
+    comp, _ = deflate_blob(data)
     return comp + BGZF_EOF_MARKER if with_terminator else comp
 
 

@@ -336,62 +336,6 @@ def run_kernel_fuzz(results: list) -> None:
     assert inf_big + rns_big == 0, "fuzz payloads escaped the device caps"
 
 
-def run_deflate(results: list) -> None:
-    """Device DEFLATE encoder: committed ratio + throughput vs the
-    canonical zlib-6 pin on realistic payloads, with the stored-block
-    fallback count."""
-    from disq_tpu.ops import deflate as dev_deflate
-
-    rng = np.random.default_rng(3)
-    # two payload classes: entropy-dominated (no LZ77 matches exist, so
-    # the entropy-only device coder can be compared head-on with zlib)
-    # and match-heavy (where the missing LZ77 stage shows — reported,
-    # not hidden)
-    entropy_blob = rng.integers(28, 42, 2_000_000,
-                                dtype=np.uint8).tobytes()
-    blob = _bam_like(2_000_000, rng)
-    ze = _deflate(entropy_blob)
-    ce, _ = dev_deflate.deflate_blob_device(entropy_blob)
-    entropy_row = {
-        "ratio_device": round(len(entropy_blob) / len(ce), 3),
-        "ratio_zlib6": round(len(entropy_blob) / len(ze), 3),
-        "stored_fallback_blocks": dev_deflate.last_stats["stored_fallback"],
-    }
-    comp, sizes = dev_deflate.deflate_blob_device(blob)
-    stats = dict(dev_deflate.last_stats)
-    # round-trip through an independent decoder
-    from disq_tpu.bgzf.block import parse_block_header
-
-    import struct
-
-    pos, back = 0, bytearray()
-    while pos < len(comp):
-        total = parse_block_header(comp, pos)
-        xlen = struct.unpack_from("<H", comp, pos + 10)[0]
-        back += zlib.decompress(comp[pos + 12 + xlen: pos + total - 8],
-                                wbits=-15)
-        pos += total
-    ok = bytes(back) == blob
-    best = 1e9
-    for _ in range(2):
-        t0 = time.perf_counter()
-        dev_deflate.deflate_blob_device(blob)
-        best = min(best, time.perf_counter() - t0)
-    zbytes = _deflate(blob)
-    results.append({
-        "kernel": "deflate_device_encode",
-        "shape": f"{len(blob)} B",
-        "mb_per_sec": round(len(blob) / best / 1e6, 2),
-        "ratio_device": round(len(blob) / len(comp), 3),
-        "ratio_zlib6": round(len(blob) / len(zbytes), 3),
-        "stored_fallback_blocks": stats["stored_fallback"],
-        "blocks": stats["blocks"],
-        "entropy_payload": entropy_row,
-        "correct": ok,
-    })
-    assert ok, "device deflate round-trip mismatch"
-
-
 def run_device_pipeline_row(results: list) -> None:
     """Device-resident read pipeline under jax.transfer_guard:
     decoded bytes -> prefix gather -> Pallas parse -> keys -> sort ->
@@ -614,44 +558,6 @@ def run_resident_operators(results: list) -> None:
     assert all(checks.values()), checks
 
 
-def run_resident_write(results: list) -> None:
-    """Device write path: the per-byte record gather of the sorted
-    batch (``encode_resident``) and the fused deflate of the
-    still-resident blob, against the host encode and zlib's inflate."""
-    from disq_tpu.bam.codec import encode_records
-    from disq_tpu.runtime.columnar import ColumnarBatch
-    from disq_tpu.runtime.device_write import ResidentShardEncoder
-    from disq_tpu.sort.coordinate import coordinate_sort_batch
-
-    blob, offsets, host = _record_shard(200_000, 14)
-    cb = ColumnarBatch.from_blob(blob, offsets, n_ref=len(_REFS))
-    sorted_cb = coordinate_sort_batch(cb, keep_resident=True)
-    want = np.frombuffer(encode_records(
-        coordinate_sort_batch(host, use_mesh=False)), np.uint8)
-    enc = ResidentShardEncoder(sorted_cb)
-    t0 = time.perf_counter()
-    shard = enc.encode_shard(0, enc.count)
-    ok_gather = np.array_equal(
-        np.asarray(shard._words).view(np.uint8)[: len(want)], want)
-    comp, sizes = shard.deflate()
-    wall = time.perf_counter() - t0
-    back, pos = bytearray(), 0
-    for size in sizes:
-        back += zlib.decompress(comp[pos + 18: pos + int(size) - 8], -15)
-        pos += int(size)
-    ok_deflate = bytes(back) == want.tobytes()
-    enc.release()
-    results.append({
-        "kernel": "resident_write_encode_deflate",
-        "shape": f"{host.count} records, {len(sizes)} blocks",
-        "first_call_sec": round(wall, 2),
-        "ratio_device": round(len(want) / len(comp), 3),
-        "gather_equal": bool(ok_gather), "deflate_roundtrip": bool(ok_deflate),
-        "correct": bool(ok_gather and ok_deflate),
-    })
-    assert ok_gather and ok_deflate
-
-
 def run_mesh_parse(results: list) -> None:
     """The Pallas parse inside a ``shard_map`` program on real devices
     (needs > 1; one chip records the skip)."""
@@ -697,10 +603,10 @@ def main(out_path: str = "TPU_KERNELS.json",
             rows.append(functools.partial(
                 run_inflate_simd_wgs30x, record_bytes=f.read()))
     for fn in (*rows,
-               run_rans_simd, run_kernel_fuzz, run_deflate,
+               run_rans_simd, run_kernel_fuzz,
                run_device_pipeline_row, run_resident_decode,
                run_decode_service, run_resident_operators,
-               run_resident_write, run_mesh_parse):
+               run_mesh_parse):
         try:
             fn(results)
         except Exception as e:  # record the failure, keep going

@@ -935,7 +935,7 @@ class ColumnarBatch:
         self._consume_on_device("sort_keys", 8 * self._n)
         return out
 
-    # -- resident permutation (the device write path's sort output) ---------
+    # -- resident permutation (the resident sort's output) -------------------
 
     def permuted(self, order: np.ndarray) -> "ColumnarBatch":
         """A reordered batch that STAYS device-backed: the fixed
@@ -943,11 +943,9 @@ class ColumnarBatch:
         upload, zero column round-trips), and the host record blob is
         kept with the permutation so ragged access materializes
         lazily — exactly like the unpermuted batch.  This is the sort
-        output the symmetric write path consumes: its
-        ``encode_source()`` triple feeds ``runtime/device_write``'s
-        resident encode → deflate chain with no host record
-        materialization.  Falls back to a host-backed batch when the
-        device columns are gone (released / host-built)."""
+        output the write copies from (``encoded_slice``), with no host
+        record materialization.  Falls back to a host-backed batch when
+        the device columns are gone (released / host-built)."""
         order = np.asarray(order, dtype=np.int64)
         if len(order) != self._n:
             raise ValueError(
@@ -972,7 +970,7 @@ class ColumnarBatch:
 
     def encode_source(self):
         """The ``(record blob, record offsets, pending order or None)``
-        triple the resident encode path needs, or None when this batch
+        triple a reader of the record bytes needs, or None when this batch
         holds no host record blob (host-built batches encode through
         the classic ``encode_records`` path). The order maps each of
         this batch's records to a record of the blob: a permutation of

@@ -47,7 +47,7 @@ import jax.numpy as jnp
 
 
 from disq_tpu.util import bucket_pow2 as _bucket
-from disq_tpu.util import pad_quantum as _pad_quantum
+from disq_tpu.util import pad_quantum
 
 
 def gather_record_words(blob_words: jax.Array,
@@ -229,7 +229,7 @@ def assemble_device_words(chunks, lane_of: np.ndarray,
     # quantum-padded like the upload path: a plain power-of-two bucket
     # would run the 4 per-word gathers (and hold HBM) over up to 2x the
     # real data on large shards
-    total_words = _pad_quantum(max(1, (total + 3) // 4))
+    total_words = pad_quantum(max(1, (total + 3) // 4))
     nb = len(offsets) - 1
     nb_pad = _bucket(max(1, nb))
     off_pad = np.empty(nb_pad + 1, np.int32)
@@ -283,7 +283,7 @@ def parse_columns_resident(
     that blob. Returns (cols, resident word bytes, record count).
 
     ``staged`` is a host buffer that is an upload buffer already (the
-    decode service's ``Submission.base``: uint8, ``_pad_quantum`` words
+    decode service's ``Submission.base``: uint8, ``pad_quantum`` words
     long, zero past the decoded bytes) and holds ``blob`` from byte
     ``origin`` on: it goes up whole, as it is, where any other host
     blob is first copied into such a buffer.  Either way the span
@@ -297,7 +297,7 @@ def parse_columns_resident(
     the bucket-padded starts shard over ``batch`` (power-of-two bucket
     sizes always divide the power-of-two axis), and the returned
     columns are batch-sharded device arrays.  ``coarse`` pads the
-    uploaded blob in ``_pad_quantum``'s coarse steps."""
+    uploaded blob in ``pad_quantum``'s coarse steps."""
     from disq_tpu.runtime.tracing import (
         count_transfer, counter, device_span, span)
 
@@ -319,7 +319,7 @@ def parse_columns_resident(
         # per split, and an exact-shape upload would retrace the parse
         # jit once per shard — quantized shapes keep compiles to a
         # handful per run at <=~6% pad overhead on big shards
-        nbytes = (staged.nbytes if staged is not None else 4 * _pad_quantum(
+        nbytes = (staged.nbytes if staged is not None else 4 * pad_quantum(
             max(1, (len(blob) + 3) // 4), coarse))
         with span("columnar.batch.stage", bytes=nbytes):
             padded = staged

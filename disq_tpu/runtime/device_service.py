@@ -13,10 +13,7 @@ per-chunk allocation, transfers and partial lanes).
 This module inverts the ownership, the way "Extending TensorFlow's
 Semantics with Pipelined Execution" overlaps producer/consumer stages:
 executor decode stages submit their shard's block batch
-(``submit_inflate`` / ``submit_rans``) — and, since the symmetric
-write path, write-pipeline deflate stages submit their shard's
-uncompressed BGZF block payloads (``submit_deflate``) — and get a
-future back; ONE
+(``submit_inflate`` / ``submit_rans``) and get a future back; ONE
 dispatcher thread coalesces blocks *across* in-flight shards into full
 128-lane chunks (flushing on full, on an oldest-lane timeout, or at
 drain), keeps an adaptive window of launches in flight
@@ -409,68 +406,6 @@ class _RansEngine:
                     lambda lane: RS._host_decode0(lane.payload[0]))
 
 
-class _DeflateEngine:
-    """Launch/finalize hooks for BGZF DEFLATE *encode* lanes — the
-    write-side mirror of ``_InflateEngine`` (ops/deflate's 128-lane
-    batched entropy coder on the shared arena/packer layout).
-
-    A lane's payload is ``(block payload bytes <= 65280, its 256-bin
-    histogram)`` — the histogram was computed on the SUBMITTING thread
-    so this dispatcher only sums small vectors; its delivery is the
-    complete framed BGZF block.  Each flushed chunk builds ONE shared
-    Huffman table from its lanes' combined histogram (blocks
-    co-batched from different shards share the table — bit-valid for
-    every lane; the table is part of each block's own dynamic header
-    so shards stay independent).  Lanes the entropy coder expanded
-    reroute to host zlib over the service's host pool, off the
-    dispatcher thread."""
-
-    kind = "deflate"
-
-    def __init__(self, interpret: bool, host_map) -> None:
-        # the encoder is plain jitted XLA (no Pallas): interpret is
-        # accepted for engine-construction symmetry but unused
-        self._host_map = host_map
-
-    def launch(self, lanes: Sequence[_Lane], labels: Dict[str, Any]):
-        from disq_tpu.ops import deflate as DF
-
-        payloads = [l.payload[0] for l in lanes]
-        with _span("device.launch.pack", **labels):
-            freq = np.zeros(256, np.int64)
-            for l in lanes:
-                freq += l.payload[1]
-            table = DF.DeflateTable(freq, len(lanes))
-            packed = DF.pack_chunk(payloads)
-        nbytes = packed[0].nbytes + packed[1].nbytes
-        with _span("device.launch.submit", bytes=nbytes, **labels):
-            handle = DF.submit_chunk(packed, table)
-        return handle, table
-
-    def finalize(self, handle, lanes: Sequence[_Lane],
-                 labels: Dict[str, Any]) -> None:
-        from disq_tpu.ops import deflate as DF
-
-        chunk_handle, table = handle
-        try:
-            bodies, end = DF.fetch_chunk(
-                chunk_handle, table, len(lanes), labels)
-        except BaseException:
-            DF.release_chunk_arena(chunk_handle)
-            raise
-        # shared per-lane finalize: identical framing + accounting on
-        # every route; expanded lanes fan out over the service's host
-        # pool, off this dispatcher thread
-        with _span("device.launch.deliver", **labels):
-            DF.release_chunk_arena(chunk_handle)
-            DF.finalize_chunk(
-                bodies, end, table, [l.payload[0] for l in lanes],
-                lambda j, blk: lanes[j].sub.deliver(lanes[j].index, blk),
-                lambda flagged: self._host_map(
-                    [lanes[j] for j in flagged],
-                    lambda lane: DF.host_block(lane.payload[0])))
-
-
 class DeviceDecodeService:
     """The dispatcher that owns the device queue (module docstring)."""
 
@@ -495,7 +430,6 @@ class DeviceDecodeService:
             "inflate": _InflateEngine(
                 interpret, self._host_map, self._host_check),
             "rans": _RansEngine(interpret, self._host_map),
-            "deflate": _DeflateEngine(interpret, self._host_map),
         }
         self._cond = threading.Condition()
         # dispatch targets, snapshotted once: [None] (default-device
@@ -507,7 +441,7 @@ class DeviceDecodeService:
         n_dev = len(self._devices)
         self._queues: Dict[str, List[Deque[_Lane]]] = {
             k: [deque() for _ in range(n_dev)]
-            for k in ("inflate", "rans", "deflate")}
+            for k in self._engines}
         self._next_queue = 0  # tie-break rotation (see _enqueue)
         # by device, under ``_cond``: lanes queued or in flight (what
         # ``_enqueue`` balances)
@@ -617,38 +551,6 @@ class DeviceDecodeService:
                 continue
             lanes.append(_Lane(sub, k, (s, meta), meta[0], 0.0, ctx))
         self._enqueue("rans", lanes, sub)
-        return sub
-
-    def submit_deflate(self, payloads: Sequence) -> Submission:
-        """Submit one write shard's uncompressed BGZF block payloads
-        (each <= 65280 bytes, the canonical blocking ``deflate_blob``
-        applies); the result is the per-block framed BGZF block bytes
-        list, in submission order.  The dispatcher coalesces blocks
-        ACROSS in-flight write shards into full 128-lane encode
-        launches — the write-side mirror of ``submit_inflate``.
-        A payload over the BGZF bound raises HERE (no encode can frame
-        it as one block — ``deflate_block``'s contract); each lane's
-        byte histogram is computed on THIS thread so the dispatcher
-        only sums them per chunk instead of rescanning up to ~8 MB of
-        payload while every other queue waits."""
-        from disq_tpu.bgzf.block import BGZF_MAX_PAYLOAD
-
-        n = len(payloads)
-        sub = Submission(parts_n=n)
-        ctx = _current_trace()
-        lanes: List[_Lane] = []
-        for i, p in enumerate(payloads):
-            if len(p) > BGZF_MAX_PAYLOAD:
-                raise ValueError(
-                    f"payload too large for one BGZF block: {len(p)}")
-            if len(p) == 0:
-                sub.deliver_local(i, b"")
-            else:
-                hist = np.bincount(
-                    np.frombuffer(p, np.uint8),
-                    minlength=256).astype(np.int64)
-                lanes.append(_Lane(sub, i, (p, hist), len(p), 0.0, ctx))
-        self._enqueue("deflate", lanes, sub)
         return sub
 
     def _enqueue(self, kind: str, lanes: List[_Lane],

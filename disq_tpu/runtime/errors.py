@@ -188,17 +188,6 @@ class DisqOptions:
     # DISQ_TPU_RESIDENT_DECODE. Off (default) ⇒ plain host ReadBatch
     # and zero device allocations (check_overhead-guarded).
     resident_decode: bool = False
-    # Symmetric device write path (ops/deflate + runtime/device_write):
-    # every sink's BGZF deflate routes through the 128-lane SIMD
-    # encoder (service-coalesced across write shards when the device
-    # service is up), and a sorted device-backed ColumnarBatch encodes
-    # its records on device so sort → encode → deflate never
-    # materializes host records — only compressed blocks cross d2h.
-    # Output is byte-VALID BGZF but not byte-identical to the host
-    # zlib pin. Env equivalent: DISQ_TPU_DEVICE_DEFLATE. Off (default)
-    # ⇒ canonical host zlib and zero device allocations
-    # (check_overhead-guarded).
-    device_deflate: bool = False
     # Mesh-native device pipeline (runtime/mesh.py): None (default)
     # keeps every device stage on the single-device dispatch and
     # builds no Mesh object (check_overhead-guarded); 0 shards the
@@ -367,9 +356,6 @@ class DisqOptions:
 
     def with_resident_decode(self, enable: bool = True) -> "DisqOptions":
         return replace(self, resident_decode=bool(enable))
-
-    def with_device_deflate(self, enable: bool = True) -> "DisqOptions":
-        return replace(self, device_deflate=bool(enable))
 
     def with_read_filter(self, spec: str) -> "DisqOptions":
         """Push a ``samtools view``-grammar read filter into the

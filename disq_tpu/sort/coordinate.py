@@ -44,13 +44,13 @@ def coordinate_sort_batch(batch: ReadBatch, use_mesh: bool = True,
     ``disq_tpu.sort.sharded``); ragged columns are reordered host-side
     by one vectorized segment gather either way.
 
-    ``keep_resident`` (the symmetric write path) returns
-    ``batch.permuted(order)`` instead of materializing host records:
-    the sorted batch stays a device-backed ``ColumnarBatch`` whose
-    fixed columns were permuted on device and whose record bytes feed
-    the resident encode → deflate chain (``runtime/device_write.py``)
-    — whether the permutation came from the single-chip lexsort or the
-    multi-chip psum/all_to_all exchange.
+    ``keep_resident`` returns ``batch.permuted(order)`` instead of
+    materializing host records: the sorted batch stays a device-backed
+    ``ColumnarBatch`` whose fixed columns were permuted on device and
+    whose record bytes the write copies in that order
+    (``ColumnarBatch.encoded_slice``) — whether the permutation came
+    from the single-chip lexsort or the multi-chip psum/all_to_all
+    exchange.
     """
     from disq_tpu.runtime.columnar import ColumnarBatch
     from disq_tpu.runtime.tracing import span

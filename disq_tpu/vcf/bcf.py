@@ -804,7 +804,7 @@ class BcfSink:
 
     def save(self, dataset, path: str, options: Sequence = ()) -> None:
         from disq_tpu.bgzf.block import BGZF_EOF_MARKER
-        from disq_tpu.bgzf.codec import deflate_blob_for
+        from disq_tpu.bgzf.codec import deflate_blob
         from disq_tpu.fsw.filesystem import resolve_path
         from disq_tpu.runtime.executor import (
             WriteShardTask,
@@ -827,11 +827,7 @@ class BcfSink:
                 return encode_bcf_records(part, header)
 
             def deflate(body):
-                # the ONE routed deflate entry point (bgzf/codec):
-                # DisqOptions.device_deflate / DISQ_TPU_DEVICE_DEFLATE
-                # covers BCF's whole-stream blocks like every other sink
-                return (deflate_blob_for(self._storage, body)[0]
-                        if body else b"")
+                return deflate_blob(body)[0]
 
             return WriteShardTask(
                 shard_id=k,
@@ -846,8 +842,7 @@ class BcfSink:
         # (stream writes land in the atomic staging file directly).
         with write_retrier_for_storage(self._storage, path).call(
                 fs.create, path, what="bcf.create") as out:
-            out.write(deflate_blob_for(
-                self._storage, build_bcf_header_block(header))[0])
+            out.write(deflate_blob(build_bcf_header_block(header))[0])
             for res in pipeline.map_ordered(tasks):
                 if res.value:
                     with span("bcf.write.stage", shard=res.shard_id):

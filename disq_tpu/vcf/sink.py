@@ -27,7 +27,7 @@ from disq_tpu.api import (
     WriteOption,
 )
 from disq_tpu.bgzf.block import BGZF_EOF_MARKER, BGZF_MAX_PAYLOAD
-from disq_tpu.bgzf.codec import deflate_blob_for
+from disq_tpu.bgzf.codec import deflate_blob
 from disq_tpu.fsw.filesystem import resolve_path
 from disq_tpu.index.tbi import TbiIndex, build_tbi, merge_tbi_fragments
 from disq_tpu.vcf.columnar import VariantBatch
@@ -89,14 +89,12 @@ class VcfSink:
         return part, _lines_blob(part)
 
     def _deflate_shard(self, fmt, write_tbi, payload):
-        """Stage 2 (CPU, or the device SIMD coder behind
-        ``DisqOptions.device_deflate``): compress per the format and,
-        for BGZF parts, build the part-local tabix fragment from
-        vectorized voffsets."""
+        """Stage 2 (CPU): compress per the format and, for BGZF parts,
+        build the part-local tabix fragment from vectorized voffsets."""
         part, body = payload
         tbi_frag = None
         if fmt is VariantsFormatWriteOption.VCF_BGZ:
-            comp, csizes = deflate_blob_for(self._storage, body)
+            comp, csizes = deflate_blob(body)
             if write_tbi:
                 lens = np.diff(part.line_offsets)
                 line_starts = np.zeros(part.count + 1, dtype=np.int64)
@@ -179,7 +177,7 @@ class VcfSink:
         driver = write_retrier_for_storage(self._storage, path)
         header_path = os.path.join(temp_dir, "_header")
         if bgz:
-            hdr, _ = deflate_blob_for(self._storage, header_bytes)
+            hdr, _ = deflate_blob(header_bytes)
         elif plain_gz:
             buf = io.BytesIO()
             with gzip.GzipFile(fileobj=buf, mode="wb", mtime=0) as z:
@@ -230,7 +228,7 @@ class VcfSinkMultiple:
 
         def deflate(payload):
             if fmt is VariantsFormatWriteOption.VCF_BGZ:
-                comp, _ = deflate_blob_for(self._storage, payload)
+                comp, _ = deflate_blob(payload)
                 return comp + BGZF_EOF_MARKER
             if fmt is VariantsFormatWriteOption.VCF_GZ:
                 buf = io.BytesIO()

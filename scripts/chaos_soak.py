@@ -350,82 +350,6 @@ def ops_leg(path, baseline) -> str:
     return ""
 
 
-def device_write_leg(path, baseline) -> str:
-    """--device-write leg: the symmetric device write path
-    (service-routed SIMD deflate + resident encode) under injected
-    write-side faults must produce a file the repo's OWN reader decodes
-    to records identical to a fault-free host-path write of the same
-    dataset.  Byte-VALIDITY, not byte-identity, is the contract — the
-    device coder's streams legitimately differ from the zlib pin — so
-    the comparison is record-level after a full re-read."""
-    from dataclasses import fields as dc_fields
-
-    import numpy as np
-
-    from disq_tpu import DisqOptions, ReadsStorage
-    from disq_tpu.fsw import (
-        FaultInjectingFileSystemWrapper,
-        FaultSpec,
-        PosixFileSystemWrapper,
-        register_filesystem,
-    )
-    from disq_tpu.runtime import device_service
-
-    faults = [
-        FaultSpec(kind="transient", probability=0.10, op="write"),
-        FaultSpec(kind="stall", probability=0.05, stall_s=0.0,
-                  op="write"),
-    ]
-    register_filesystem("fault", FaultInjectingFileSystemWrapper(
-        PosixFileSystemWrapper(), faults, seed=777))
-    out_dev = path + ".device-write.bam"
-    out_host = path + ".host-write.bam"
-    prev = os.environ.get("DISQ_TPU_DEVICE_SERVICE")
-    os.environ["DISQ_TPU_DEVICE_SERVICE"] = "1"
-    try:
-        # device path: resident-decoded read, sorted device write with
-        # BAI, through the fault fs with the parallel writer
-        opts = DisqOptions(max_retries=8, retry_backoff_s=0.0,
-                           resident_decode=True, device_deflate=True,
-                           writer_workers=2)
-        from disq_tpu.api import BaiWriteOption
-
-        st = (ReadsStorage.make_default().split_size(SPLIT)
-              .num_shards(5).options(opts))
-        ds = st.read(path)
-        st.write(ds, "fault://" + out_dev, BaiWriteOption.ENABLE,
-                 sort=True)
-        # fault-free host-path baseline of the same dataset
-        ReadsStorage.make_default().num_shards(5).write(
-            baseline, out_host, BaiWriteOption.ENABLE, sort=True)
-        got = ReadsStorage.make_default().read(out_dev)
-        want = ReadsStorage.make_default().read(out_host)
-        if got.count() != want.count():
-            return (f"device-write: {got.count()} records re-read, "
-                    f"host path wrote {want.count()}")
-        got_rb, want_rb = got.reads, want.reads
-        for f in dc_fields(want_rb):
-            if not np.array_equal(getattr(got_rb, f.name),
-                                  getattr(want_rb, f.name)):
-                return (f"device-write: column {f.name} differs from "
-                        "the fault-free host-path baseline")
-        if not os.path.exists(out_dev + ".bai"):
-            return "device-write: BAI sidecar missing"
-        return ""
-    except Exception as e:  # noqa: BLE001 — any escape is a failure
-        return f"device-write: {type(e).__name__}: {e}"
-    finally:
-        if prev is None:
-            os.environ.pop("DISQ_TPU_DEVICE_SERVICE", None)
-        else:
-            os.environ["DISQ_TPU_DEVICE_SERVICE"] = prev
-        device_service.shutdown_service()
-        for p in (out_dev, out_host, out_dev + ".bai",
-                  out_host + ".bai"):
-            if os.path.exists(p):
-                os.unlink(p)
-
-
 def breaker_leg(path, baseline) -> str:
     """Deterministic circuit-breaker scenario: a total fault storm must
     trip the breaker within its window, rejected calls must fail fast
@@ -1510,13 +1434,6 @@ def main(argv=None) -> int:
                          "pipeline through a transient-fault schedule "
                          "must produce stats and marked flag columns "
                          "identical to the fault-free chain")
-    ap.add_argument("--device-write", action="store_true",
-                    help="run the symmetric device write leg: a "
-                         "resident-encoded, service-routed SIMD-deflate "
-                         "write under injected write faults must "
-                         "re-read to records identical to the "
-                         "fault-free host-path output (byte-validity, "
-                         "not byte-identity)")
     ap.add_argument("--steal", action="store_true",
                     help="run the work-stealing leg: a 2-subprocess "
                          "scheduled read with one worker slowed by a "
@@ -1605,12 +1522,6 @@ def main(argv=None) -> int:
         if args.ops:
             err = ops_leg(path, baseline)
             print(f"[ops] {'ok' if not err else 'FAIL: ' + err}")
-            if err:
-                failures.append((args.seed, err))
-        if args.device_write:
-            err = device_write_leg(path, baseline)
-            print(f"[device-write] "
-                  f"{'ok' if not err else 'FAIL: ' + err}")
             if err:
                 failures.append((args.seed, err))
         if args.steal:

@@ -44,11 +44,8 @@ ALLOWED_PREFIXES = {
     # stall events and the /progress feed.
     "watchdog", "progress",
     # Device observability (runtime/device_pipeline.py + ops/): synced
-    # kernel spans, transfer counters, HBM gauge; the symmetric write
-    # path's device.deflate.* family (ops/deflate.py +
-    # runtime/device_write.py: table-build spans, encode chunks, block
-    # and byte counters); and the cluster aggregator's scrape
-    # telemetry (runtime/cluster.py).
+    # kernel spans, transfer counters, HBM gauge; and the cluster
+    # aggregator's scrape telemetry (runtime/cluster.py).
     "device", "cluster",
     # Adaptive resilience (runtime/resilience.py): hedged-fetch
     # bookkeeping, circuit-breaker state machine, per-shard deadline

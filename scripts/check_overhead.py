@@ -114,25 +114,6 @@ def main() -> int:
             "device decode service instantiated with no flag set — the "
             "disabled path must spawn zero dispatcher threads")
 
-    # -- 1b2. device write path: disabled ⇒ no kernels, LUTs, arenas ---------
-    from disq_tpu.bgzf import codec as bgzf_codec
-    from disq_tpu.ops import deflate as dev_deflate
-
-    if bgzf_codec.device_deflate_enabled(_Storage()):
-        errors.append(
-            "DISQ_TPU_DEVICE_DEFLATE leaked into the guard's env — the "
-            "default path must deflate with canonical host zlib")
-    bgzf_codec.deflate_blob(b"overhead-guard-payload" * 4096)
-    if any(dev_deflate.device_stats.values()):
-        errors.append(
-            f"device deflate did work on the disabled path "
-            f"({dev_deflate.device_stats}) — host-zlib writes must "
-            "launch no kernels, upload no LUTs and touch no arenas")
-    if device_service.service_if_running() is not None:
-        errors.append(
-            "a host-path deflate spun up the device service — "
-            "submit_deflate must only run behind both knobs")
-
     # -- 1b3. shard scheduler: disabled ⇒ no coordinator, inline loop --------
     from disq_tpu.runtime import scheduler
     from disq_tpu.runtime.executor import map_ordered_resumable  # noqa: F401

@@ -125,11 +125,6 @@ CATEGORIES = (
     # bytes copy (the decode service checks each launch's blocks as it
     # delivers them, so its route leaves the copy alone here).
     ("verify", "V", ("codec.inflate.verify",)),
-    # Symmetric device write path (ops/deflate.py +
-    # runtime/device_write.py): Huffman table builds and resident
-    # encode→deflate chunks — the write-side device work, separable
-    # from read-side kernels in the verdict.
-    ("device_write", "W", ("device.deflate.",)),
     # HBM-resident fused decode (runtime/columnar.py): ColumnarBatch
     # build (upload-or-in-place parse chain; columnar.batch.stage is
     # the host copy of the decoded blob into its padded upload buffer
@@ -396,7 +391,7 @@ STALL_CATEGORIES = {"emit_stall", "retry", "quarantine", "watchdog"}
 # it only wins instants where nothing else is making progress — and
 # hedge-wasted time ranks last among work: it is burned concurrency,
 # attributed to its own bucket so the --analyze verdict can name it.
-WORK_PRIORITY = ("device", "transfer", "dispatch", "device_write",
+WORK_PRIORITY = ("device", "transfer", "dispatch",
                  "columnar", "verify",
                  "decode", "write_slice", "encode", "deflate",
                  "stage", "fetch", "hedge", "hedge_wasted",
@@ -465,13 +460,6 @@ ADVICE = {
                     "host upstream of it (fetch, parse hand-over, "
                     "ordered emit) starves the device; raise "
                     "executor_workers / prefetch_shards",
-    "device_write": "device encode/deflate dominates the write: raise "
-                    "writer_workers so shards overlap launches, route "
-                    "through the service (DISQ_TPU_DEVICE_SERVICE=1) "
-                    "to coalesce partial chunks, or check "
-                    "device.host_fallback_blocks{reason=expanded} — "
-                    "incompressible lanes rerouting to host zlib eat "
-                    "the win",
     "columnar": "resident-decode build/fetch dominates: columns are "
                 "being materialized host-side after all — check which "
                 "consumer forces the fetches (ops.depth.prepare is "

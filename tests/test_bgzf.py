@@ -25,8 +25,10 @@ from disq_tpu.bgzf import (
     make_virtual_offset,
     split_virtual_offset,
 )
+from disq_tpu.bgzf.block import BGZF_MAX_PAYLOAD as BLOCK
 from disq_tpu.bgzf.block import parse_block_header
 from disq_tpu.fsw import MemoryFileSystemWrapper, compute_path_splits
+from disq_tpu.ops import tpu_ci
 
 
 def _payload(n: int, seed: int = 0) -> bytes:
@@ -65,6 +67,60 @@ class TestRoundTrip:
         p = rng.integers(0, 256, 65280, dtype=np.uint8).tobytes()
         comp = compress_to_bgzf(p)
         assert decompress_bgzf(comp) == p
+
+
+# by id: the payload ``deflate_blob`` is handed
+DEFLATE_PAYLOADS = {
+    "1": lambda: _payload(1),
+    "2": lambda: _payload(2),
+    "255": lambda: _payload(255),
+    "one_block": lambda: _payload(BLOCK),
+    "one_block_and_a_byte": lambda: _payload(BLOCK + 1),
+    "three_blocks": lambda: _payload(3 * BLOCK),
+    # past the compaction's 256-row chunk
+    "257_blocks_and_a_byte": lambda: (
+        _payload(100_003) * 168)[: 257 * BLOCK] + b"x",
+    "incompressible": lambda: np.random.default_rng(7).integers(
+        0, 256, 2 * BLOCK + 9, dtype=np.uint8).tobytes(),
+    "repetitive": lambda: b"\0" * (2 * BLOCK - 1),
+    "bam_like": lambda: tpu_ci._bam_like(200_000, np.random.default_rng(5)),
+    "ndarray": lambda: np.frombuffer(_payload(BLOCK + 300), np.uint8),
+}
+
+
+class TestDeflateBlob:
+    """The one BGZF deflate every sink writes through, held to the
+    block coder (``deflate_block``, the canonical pin) cut by cut, on
+    the native route and on the Python one."""
+
+    @pytest.mark.parametrize("route", ["native", "python"])
+    @pytest.mark.parametrize("name", DEFLATE_PAYLOADS)
+    def test_blocks_are_the_canonical_ones(self, name, route, monkeypatch):
+        import sys
+
+        from disq_tpu.bgzf.codec import deflate_block, deflate_blob
+
+        if route == "native":
+            pytest.importorskip("disq_tpu.native")
+        else:
+            monkeypatch.setitem(sys.modules, "disq_tpu.native", None)
+        payload = DEFLATE_PAYLOADS[name]()
+        raw = bytes(payload)
+        n = len(raw)
+        comp, sizes = deflate_blob(payload)
+        assert isinstance(comp, bytes) and sizes.dtype == np.int64
+        assert int(sizes.sum()) == len(comp)
+        assert len(sizes) == -(-n // BLOCK)
+        pos = 0
+        for i, size in enumerate(sizes):
+            assert parse_block_header(comp, pos) == size <= 0x10000
+            crc, isize = struct.unpack_from("<II", comp, pos + size - 8)
+            cut = raw[i * BLOCK: (i + 1) * BLOCK]
+            assert isize == len(cut) and crc == zlib.crc32(cut)
+            pos += int(size)
+        assert decompress_bgzf(comp) == raw
+        assert comp == b"".join(
+            deflate_block(raw[o: o + BLOCK]) for o in range(0, n, BLOCK))
 
 
 class TestWriterReader:

@@ -22,7 +22,7 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SMOKE = os.path.join(REPO, "chip_smoke.py")
 LEGS = ("host_read", "host_sort_write", "device_read", "device_sort_write",
-        "device_deflate_write", "operators", "cram", "serve", "mesh")
+        "operators", "cram", "serve", "mesh")
 
 
 TINY = ["--allow-cpu", "--records", "60", "--block-payload", "300",
@@ -55,8 +55,7 @@ def _check_summary(proc, cache, legs):
         assert doc["legs"][leg]["cold_s"] > 0, leg
         assert doc["legs"][leg]["second_s"] > 0, leg
     launches = doc["counters"]["kernel_launches"]
-    for kernel in ("inflate_simd", "columnar_parse", "rans_simd",
-                   "deflate_simd", "encode_resident"):
+    for kernel in ("inflate_simd", "columnar_parse", "rans_simd"):
         assert launches[f"kernel={kernel}"] > 0, kernel
     assert doc["counters"]["host_fallback_blocks"].get(
         "reason=flagged", 0) == 0

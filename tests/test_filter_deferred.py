@@ -427,30 +427,11 @@ def read_groups(out, want):
     np.testing.assert_array_equal(ids, ref_ids)
 
 
-def device_encoder(out, want):
-    from disq_tpu.runtime.device_write import ResidentShardEncoder
-
-    want_blob, want_offs = _record_bytes(want)
-    enc = ResidentShardEncoder(out)
-    try:
-        assert enc.count == out.count
-        for lo, hi in ((0, out.count), (2, out.count // 2)):
-            shard = enc.encode_shard(lo, hi)
-            cut = want_blob[want_offs[lo]: want_offs[hi]]
-            assert shard.host_payload().tobytes() == cut
-            assert np.asarray(shard._words).view(np.uint8)[
-                : shard.nbytes].tobytes() == cut
-            np.testing.assert_array_equal(
-                shard.record_offsets, want_offs[lo: hi + 1] - want_offs[lo])
-    finally:
-        enc.release()
-
-
 @pytest.mark.parametrize("state", STATES)
 @pytest.mark.parametrize("share", SHARES)
 @pytest.mark.parametrize("reader", [
     markdup_keys, markdup_marks, subsample_filter, pileup_bounds,
-    read_groups, device_encoder], ids=lambda f: f.__name__)
+    read_groups, _assert_writer_bytes], ids=lambda f: f.__name__)
 def test_a_reader_of_the_bytes_indexes_through_the_order(
         reader, share, state):
     records = synth_paired_records(N // 2, seed=13)

@@ -150,7 +150,7 @@ class TestStageSpan:
 
 class TestTransferSpans:
     def test_every_site_in_the_source_names_itself(self):
-        """Five sites share the name ``device.transfer``: each says
+        """Four sites share the name ``device.transfer``: each says
         which it is, one word a site, no two alike."""
         sites = []
         for root, _dirs, files in os.walk(os.path.join(REPO, "disq_tpu")):
@@ -165,7 +165,7 @@ class TestTransferSpans:
                     assert site, (f, m.group(0))
                     assert "direction=" in m.group(1)
                     sites.append(site.group(1))
-        assert len(sites) == len(set(sites)) == 5, sites
+        assert len(sites) == len(set(sites)) == 4, sites
 
     def test_bytes_are_what_count_transfer_books(
             self, tmp_path, monkeypatch):

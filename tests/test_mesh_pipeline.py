@@ -382,7 +382,7 @@ class TestEveryChipKeepsItsOwnPipeline:
         for i in range(4):                 # load the other chips
             if i != a:
                 svc._outstanding[i] += 10_000
-        assert self._split(svc, 128, "deflate") == a
-        svc._queues["deflate"][a][0].ts -= 1.0     # the older lane
-        assert self._launch_one(svc)[0] == "deflate"
+        assert self._split(svc, 128, "rans") == a
+        svc._queues["rans"][a][0].ts -= 1.0        # the older lane
+        assert self._launch_one(svc)[0] == "rans"
         assert self._launch_one(svc)[0] == "inflate"

@@ -246,8 +246,8 @@ def test_ends_of_a_stretch_are_the_stretch_of_the_ends(source):
     np.testing.assert_array_equal(batch.alignment_ends(0, 74), want)
 
 
-def test_the_device_coder_takes_an_array_of_no_bytes():
-    from disq_tpu.ops.deflate import deflate_blob_device
+def test_the_deflate_takes_an_array_of_no_bytes():
+    from disq_tpu.bgzf.codec import deflate_blob
 
-    comp, sizes = deflate_blob_device(np.zeros(0, np.uint8))
+    comp, sizes = deflate_blob(np.zeros(0, np.uint8))
     assert comp == b"" and len(sizes) == 0

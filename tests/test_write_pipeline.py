@@ -8,6 +8,7 @@ import os
 import threading
 import time
 
+import numpy as np
 import pytest
 
 from bam_oracle import DEFAULT_REFS, make_bam_bytes, synth_records
@@ -489,6 +490,107 @@ class TestManifestResumeParallel:
             open(clean + ".bai", "rb").read()
         assert open(out + ".sbi", "rb").read() == \
             open(clean + ".sbi", "rb").read()
+
+
+# ---------------------------------------------------------------------------
+# the shard deflate: record voffsets from the blocks' compressed sizes
+
+from disq_tpu.bgzf.block import BGZF_MAX_PAYLOAD as BLOCK  # noqa: E402
+
+# by id: the lengths of the records in the blob
+VOFFSET_RECORDS = {
+    "many_records": lambda: np.random.default_rng(7).integers(40, 200, 800),
+    "one_straddles_a_block": lambda: [BLOCK - 10, 50, 100],
+    "one_ends_at_a_block": lambda: [1000, BLOCK - 1000, 100, 7],
+    "one_is_longer_than_a_block": lambda: [30, 2 * BLOCK + 17, 5],
+    "no_records": lambda: [],
+}
+
+
+@pytest.mark.parametrize("name", VOFFSET_RECORDS)
+def test_voffsets_seek_to_each_records_bytes(name):
+    """Every voffset ``bgzf_compress_with_voffsets`` computes from the
+    blocks' compressed sizes must seek (via the framework's BgzfReader)
+    to that record's exact bytes, and every end must be the next
+    record's start."""
+    import io
+
+    from disq_tpu.bam.sink import bgzf_compress_with_voffsets
+    from disq_tpu.bgzf.block import BGZF_EOF_MARKER
+    from disq_tpu.bgzf.codec import BgzfReader
+
+    rec_lens = np.asarray(VOFFSET_RECORDS[name](), np.int64)
+    offs = np.zeros(len(rec_lens) + 1, np.int64)
+    np.cumsum(rec_lens, out=offs[1:])
+    blob = np.random.default_rng(8).integers(
+        0, 24, int(offs[-1]), np.uint8).tobytes()
+    comp, voffs, end_voffs = bgzf_compress_with_voffsets(blob, offs)
+    assert len(voffs) == len(end_voffs) == len(rec_lens)
+    assert voffs.dtype == end_voffs.dtype == np.uint64
+    np.testing.assert_array_equal(voffs[1:], end_voffs[:-1])
+    reader = BgzfReader(io.BytesIO(comp + BGZF_EOF_MARKER))
+    for i in range(0, len(rec_lens), 97 if len(rec_lens) > 97 else 1):
+        reader.seek_virtual(int(voffs[i]))
+        want = blob[int(offs[i]): int(offs[i + 1])]
+        assert reader.read_exact(len(want)) == want
+    if len(rec_lens):
+        # the last end is where the blob ends: nothing is left to read
+        reader.seek_virtual(int(end_voffs[-1]))
+        assert reader.read(1) == b""
+
+
+def test_a_default_write_does_no_device_work(reads_ds, tmp_path,
+                                             monkeypatch):
+    """No device service is started and no kernel is launched."""
+    from disq_tpu.api import BaiWriteOption
+    from disq_tpu.runtime import device_service
+    from disq_tpu.runtime.tracing import REGISTRY
+
+    monkeypatch.delenv("DISQ_TPU_DEVICE_SERVICE", raising=False)
+    device_service.shutdown_service()
+    launches = REGISTRY.counter("device.kernel_launches")
+    before = launches.total()
+    (ReadsStorage.make_default().num_shards(4)
+     .write(reads_ds, str(tmp_path / "host.bam"), BaiWriteOption.ENABLE,
+            sort=True))
+    assert launches.total() == before
+    assert device_service.service_if_running() is None
+
+
+def test_quarantined_read_then_write(tmp_path):
+    """A corrupt block quarantined on read loses exactly its own
+    records; the write of the surviving dataset re-reads to exactly
+    those records: the owner shard's loss never spreads."""
+    from disq_tpu import DisqOptions
+    from disq_tpu.bgzf.block import parse_block_header
+
+    n_rec = 153
+    data = make_bam_bytes(
+        DEFAULT_REFS, synth_records(150, seed=11, unmapped_tail=3),
+        blocksize=900)
+    # corrupt the DEFLATE payload of the 3rd block
+    layout, pos = [], 0
+    while pos < len(data):
+        layout.append(pos)
+        pos += parse_block_header(data, pos)
+    bad = bytearray(data)
+    bad[layout[3] + 20] ^= 0xFF
+    bad_path = str(tmp_path / "bad.bam")
+    with open(bad_path, "wb") as f:
+        f.write(bytes(bad))
+    opts = DisqOptions(
+        error_policy="quarantine", quarantine_dir=str(tmp_path / "quar"))
+    ds = ReadsStorage.make_default().options(opts).read(bad_path)
+    assert ds.counters.quarantined_blocks == 1
+    assert 0 < n_rec - ds.count() <= 40
+    out = str(tmp_path / "salvaged.bam")
+    ReadsStorage.make_default().num_shards(3).write(ds, out)
+    got = ReadsStorage.make_default().read(out)
+    assert got.count() == ds.count()
+    for col in ("pos", "flag", "names", "seqs", "quals", "tags"):
+        np.testing.assert_array_equal(
+            np.asarray(getattr(got.reads, col)),
+            np.asarray(getattr(ds.reads, col)), err_msg=col)
 
 
 # ---------------------------------------------------------------------------
