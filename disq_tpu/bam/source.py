@@ -291,14 +291,19 @@ class BamSource:
                 return fn(*args)
             return ctx.retrier.call(fn, *args, what=what)
 
+        from disq_tpu.runtime.tracing import span
+
         end_vo = _call(self._data_end_voffset, fs, path, what="data_end")
         bounds = [first_voffset]
-        for s in splits[1:]:
+        for shard, s in enumerate(splits[1:], 1):
             if sbi is not None:
                 vo = sbi.first_offset_at_or_after(s.start)
             else:
-                vo = _call(self._guess_record_voffset, fs, path, header,
-                           s.start, ctx, what="boundary")
+                # ``window_bytes``: how far the search window grew (a
+                # record longer than the first window makes it grow)
+                with span("bam.split.guess", shard=shard) as grew:
+                    vo = _call(self._guess_record_voffset, fs, path,
+                               header, s.start, ctx, grew, what="boundary")
                 if vo is None:
                     vo = end_vo
             bounds.append(max(min(vo, end_vo), bounds[-1]))
@@ -312,9 +317,12 @@ class BamSource:
         header: SamHeader,
         file_offset: int,
         ctx=None,
+        grew: Optional[dict] = None,
     ) -> Optional[int]:
         """First record boundary at-or-after ``file_offset`` (SURVEY §3.1:
         BgzfBlockGuesser → BamRecordGuesser over a decompressed window).
+        ``grew["window_bytes"]`` is left at the compressed window the
+        search ended with.
 
         Under a skip/quarantine ``ctx``, a corrupt block inside the
         search window is stepped over *silently* (per good-block run) —
@@ -335,6 +343,8 @@ class BamSource:
         # boundary is found or the window reaches EOF.
         window_csize = 4 * 0x10000
         while True:
+            if grew is not None:
+                grew["window_bytes"] = window_csize
             try:
                 window_blocks, data = _walk_blocks_collect(
                     fs, path, block_start, block_start + window_csize,

@@ -132,7 +132,7 @@ def _bind(lib: ctypes.CDLL) -> None:
     lib.disq_bam_reference_lengths.restype = ctypes.c_int64
     lib.disq_bam_reference_lengths.argtypes = [
         u8p, ctypes.c_int64, i64p, ctypes.c_int64, ctypes.c_int64, i32p,
-        i64p,
+        i64p, i64p,
     ]
     lib.disq_bam_markdup_keys.restype = ctypes.c_int64
     lib.disq_bam_markdup_keys.argtypes = [
@@ -380,24 +380,26 @@ def decode_records_native(buf, offsets: np.ndarray):
 
 
 def reference_lengths_native(buf, offsets: np.ndarray, base: int = 0):
-    """``(pos i32, reference length i64)`` of the records of ``buf``
-    from its fixed fields and CIGAR op words alone, one sequential C
-    pass.  ``offsets`` are the (N+1,) record offsets of these records
-    in a larger blob of which ``buf`` is the part starting at byte
-    ``base``."""
+    """``(pos i32, reference length i64, op words walked)`` of the
+    records of ``buf`` from its fixed fields and CIGAR op words alone,
+    one sequential C pass.  ``offsets`` are the (N+1,) record offsets
+    of these records in a larger blob of which ``buf`` is the part
+    starting at byte ``base``."""
     lib = _load()
     arr = _as_u8(buf)
     offsets = np.ascontiguousarray(offsets, dtype=np.int64)
     n = max(0, len(offsets) - 1)
     pos = np.empty(n, np.int32)
     reflen = np.empty(n, np.int64)
+    ops = np.zeros(1, np.int64)
     rc = lib.disq_bam_reference_lengths(
         _ptr(arr, ctypes.c_uint8), len(arr),
         _ptr(offsets, ctypes.c_int64), base, n,
-        _ptr(pos, ctypes.c_int32), _ptr(reflen, ctypes.c_int64))
+        _ptr(pos, ctypes.c_int32), _ptr(reflen, ctypes.c_int64),
+        _ptr(ops, ctypes.c_int64))
     if rc != 0:
         raise ValueError(f"record {-(rc + 1)}: malformed sections")
-    return pos, reflen
+    return pos, reflen, int(ops[0])
 
 
 def markdup_keys_native(buf, offsets: np.ndarray):

@@ -59,9 +59,11 @@ registers (``_pick_word``). Bool (1,128)
 vectors are carried as i32 across ``lax.cond`` branches, unsigned
 reductions and min run in i32: workarounds written against an earlier
 Mosaic. This installation (jax 0.9.0, libtpu 0.0.34) compiles the
-kernel as it stands, the largest geometry included (comp 4 MB + out
-8 MB + tables whole in VMEM, no ``vmem_limit_bytes``); whether it
-would also take the constructs those workarounds avoid is untested.
+kernel as it stands: the narrow geometries (comp up to 4 MB + out 8 MB
++ tables whole in VMEM) with no ``vmem_limit_bytes``, the wide one
+(comp 8 MB, for BGZF payloads over 32,752 bytes) with the limit raised
+to 32 MiB for that geometry alone; whether it would also take the
+constructs those workarounds avoid is untested.
 
 Huffman decoding is bit-serial canonical (puff-style count/first/offset
 walk) rather than root-table driven: the per-length arrays are (16,128)
@@ -72,7 +74,14 @@ construction sweep entirely — dynamic table build reduces to counting
 sorts over the code-length arrays.
 
 Memory (v1): compressed words, output words, all tables and a ring of
-each lane's last 4 KiB of output live whole in VMEM. A copy step needs
+each lane's last 4 KiB of output live whole in VMEM, at every geometry:
+a payload up to ``NARROW_CSIZE`` (32,752 bytes) launches at cw <= 8192
+(12.5 MB with the full output), a wider one, up to BGZF's largest
+(``MAX_DEVICE_CSIZE``, 65,510 bytes), at cw 16384 (16.5 MB: past the
+16 MiB scoped default, so ``_compiled`` raises ``vmem_limit_bytes`` for
+that geometry and leaves the others' programs as they were; a v5e core
+has 128 MiB). The sweeps of ``comp_ref`` are windowed by slab, so a
+superstep pays for the live window and not for cw. A copy step needs
 up to five consecutive history words a lane; they lie inside two
 aligned 8-word tiles, so it reads them with two tile sweeps of the
 1,024-row ring and, in the supersteps where some lane's distance
@@ -129,13 +138,22 @@ _MAXLENS = 320          # 288 lit/len + 32 dist code lengths
 _SLAB = 2048            # slab rows for big-buffer one-hot ops (VMEM temps)
 RING_W = 1024           # history ring: last 4 KiB per lane, word rows
 RING_SAFE = 4096 - 8    # max distance served by the ring
-MAX_DEVICE_CSIZE = 8192 * 4 - 16  # comp cap; bigger payloads -> host
+# The comp cap is BGZF's largest payload: a block is at most 65,536
+# bytes with its 18-byte header and 8-byte footer.  A raw stream over
+# the cap, or one whose output is over MAX_DEVICE_USIZE, goes to the host.
+MAX_DEVICE_CSIZE = 65536 - 26
+MAX_DEVICE_USIZE = 65536
+# Payloads up to here launch at cw <= 8192, the standing geometry; wider
+# ones at cw 16384, whose buffers are past the scoped-VMEM default
+# (``_compiled``).  The decode service queues the two apart.
+NARROW_CSIZE = 8192 * 4 - 16
 # A comp sweep serves COMP_PERIOD supersteps. A lane takes at most two
 # words a superstep (one a refill site), so from word in_w it reads
 # in_w .. in_w + 2 * COMP_PERIOD - 1: all inside the _COMP_TILES aligned
 # 8-word tiles from tile in_w >> 3 on, which the loop carries. A power
 # of two: the schedule is a mask of the loop counter.
 COMP_PERIOD = 4
+_WIDE_VMEM_LIMIT = 32 << 20
 _COMP_TILES = (2 * COMP_PERIOD + 14) // 8
 _U32 = jnp.uint32
 _I32 = jnp.int32
@@ -1085,6 +1103,20 @@ def _compiled(cw: int, ow: int, interpret: bool,
     # big geometries (comp 4 MB + out 8 MB persistent) leave < 4 MB of
     # scoped-vmem stack: halve the slab temps there
     slab = 1024 if cw + ow >= 20480 else _SLAB
+    # the wide geometry (comp 8 MB + out 8 MB + ring and tables) is past
+    # the 16 MiB scoped-VMEM default (the chip refuses it: "ran out of
+    # memory in memory space vmem"): it alone raises the limit (a v5e
+    # core has 128 MiB), so every narrower geometry compiles to the
+    # program it always did.  Its lanes are long-read blocks of 25-42 KB
+    # of payload that drift apart, so a sweep's window spans more slabs:
+    # 512-row slabs read 4.86 us a superstep there against 5.12 at 1,024
+    # and 5.28 at 256 (PERF.md, PR 48)
+    params = {}
+    if cw > 8192:
+        slab = 512
+        if not interpret:
+            params["compiler_params"] = pltpu.CompilerParams(
+                vmem_limit_bytes=_WIDE_VMEM_LIMIT)
     kernel = functools.partial(
         _inflate_simd_kernel, cw=cw, ow=ow, max_steps=max_steps,
         slab=slab)
@@ -1113,6 +1145,7 @@ def _compiled(cw: int, ow: int, interpret: bool,
             pltpu.VMEM((RING_W, LANES), _U32),     # history ring
         ],
         interpret=interpret,
+        **params,
     )
     if transpose:
         inner = call
@@ -1321,7 +1354,7 @@ def host_inflate(p, expect: Optional[int] = None) -> bytes:
 
 def _fetch_chunk(handle, lanes: int,
                  labels: Optional[Dict[str, Any]] = None,
-                 kernel: str = "inflate_simd"):
+                 kernel: str = "inflate_simd", cw: int = 0):
     """Materialize one launched chunk and book the D2H bytes; returns
     the lanes-major uint8 view + the meta rows.  Two spans, so a
     launch's time splits into kernel and transfer: ``device.launch.wait``
@@ -1344,7 +1377,10 @@ def _fetch_chunk(handle, lanes: int,
     which the kernel swept the compressed buffer for the carry's
     window, is booked as ``device.inflate.comp_fetches`` and the label
     ``comp_fetches``: over the supersteps it is the schedule's share,
-    1 / ``COMP_PERIOD``."""
+    1 / ``COMP_PERIOD``.  ``cw``, the launch's compressed-buffer rows,
+    books its lanes under ``device.inflate.lanes{cw}``: the lanes
+    decoded, by launch geometry (16384 is the wide one, payloads over
+    ``NARROW_CSIZE``)."""
     words, meta = handle
     if labels is None:
         labels = {"kind": "inflate", "lanes": lanes}
@@ -1364,6 +1400,7 @@ def _fetch_chunk(handle, lanes: int,
             _counter("device.inflate.far_supersteps").inc(far)
             _counter("device.inflate.crossing_chunks").inc(crossing)
             _counter("device.inflate.comp_fetches").inc(fetches)
+            _counter("device.inflate.lanes").inc(lanes, cw=cw)
     _count_transfer("d2h", nbytes)
     return words.view(np.uint8), meta
 
@@ -1498,10 +1535,13 @@ def inflate_payloads_simd(
             empty = np.empty(0, np.uint8), np.zeros(1, np.int64)
             return (*empty, None) if keep_device else empty
         return []
-    # VMEM budget (~16 MB/core): comp (8192,128) u32 = 4 MB + out
-    # (16384,128) u32 = 8 MB + tables/ring ~1.2 MB fits because the
-    # out-sized ops run slab-wise (2048-row temps). Payloads over the
-    # 32 KiB comp cap go to host zlib.
+    # VMEM budget: comp (8192,128) u32 = 4 MB + out (16384,128) u32 =
+    # 8 MB + tables/ring ~1.2 MB fits the scoped default because the
+    # out-sized ops run slab-wise; a payload over NARROW_CSIZE makes the
+    # call's geometry the wide one (comp (16384,128) = 8 MB), which
+    # raises its limit (``_compiled``). Only what is no BGZF block (a
+    # payload over MAX_DEVICE_CSIZE, an output over MAX_DEVICE_USIZE)
+    # goes to host zlib.
     results: List[Any] = [None] * n
     # With known usizes the output layout is known up front: decoded
     # lanes are written straight into the final blob as each chunk
@@ -1532,7 +1572,8 @@ def inflate_payloads_simd(
 
     small: List[int] = []
     for i, p in enumerate(payloads):
-        if len(p) > MAX_DEVICE_CSIZE:
+        if len(p) > MAX_DEVICE_CSIZE or (
+                usizes is not None and int(usizes[i]) > MAX_DEVICE_USIZE):
             last_stats["host_big"] += 1
             _counter("device.host_fallback_blocks").inc(reason="oversize")
             val = host_inflate(
@@ -1574,7 +1615,7 @@ def inflate_payloads_simd(
                 launched.append(launch(ids))
             for ci, ids in enumerate(chunks):
                 handle, arena = launched[ci]
-                lanes_u8, meta = _fetch_chunk(handle, len(ids))
+                lanes_u8, meta = _fetch_chunk(handle, len(ids), cw=cw)
                 launched[ci] = None
                 # materialized => the upload was consumed; the arena is
                 # safe to repack for a later chunk

@@ -162,8 +162,9 @@ def clip_and_span(cigars: np.ndarray, cigar_offsets: np.ndarray
 
 def reference_spans_from_blob(blob: np.ndarray, offsets: np.ndarray,
                               base: int = 0
-                              ) -> Tuple[np.ndarray, np.ndarray]:
-    """``(pos i32, reference length i64)`` of the records of ``blob``
+                              ) -> Tuple[np.ndarray, np.ndarray, int]:
+    """``(pos i32, reference length i64, CIGAR op words walked)`` of the
+    records of ``blob``
     from their fixed fields and CIGAR op words alone — the one
     blob -> CIGAR-span routine of the tree
     (``ColumnarBatch.reference_lengths`` / ``alignment_ends``, and
@@ -192,7 +193,8 @@ def reference_spans_from_blob(blob: np.ndarray, offsets: np.ndarray,
             f"record {int(np.nonzero(bad)[0][0])}: malformed sections")
     span, _lead, _trail = clip_and_span(
         *cigar_arrays_from_blob(blob, fields))
-    return fields["pos"].astype(np.int32), span
+    return (fields["pos"].astype(np.int32), span,
+            int(fields["n_cigar"].sum()))
 
 
 def qual_scores_from_blob(blob: np.ndarray,

@@ -40,16 +40,19 @@ def _bam_like(n: int, rng) -> bytes:
     return (seq.tobytes() + qual.tobytes())[:n]
 
 
-def _inflate_kernel_only(raws: list, payloads: list):
+def _inflate_kernel_only(raws: list, payloads: list, cw: int = 0):
     """One hand-packed launch of the SIMD inflate kernel, timed alone:
     inputs pre-uploaded, sync on the 2 KiB meta pull (isolates the
     kernel from packing and transfers). Returns (every lane's status 0
-    and its output equal to ``raws``, best seconds of 3, meta rows)."""
+    and its output equal to ``raws``, best seconds of 3, meta rows).
+    ``cw``: a wider compressed buffer than the payloads ask for (what a
+    geometry costs lanes that do not need it)."""
     import jax.numpy as jnp
     from disq_tpu.ops import inflate_simd as S
 
     assert all(len(p) <= S.MAX_DEVICE_CSIZE for p in payloads)
-    cw, ow = S.buckets_for(payloads, max(len(r) for r in raws))
+    need, ow = S.buckets_for(payloads, max(len(r) for r in raws))
+    cw = max(cw, need)
     fn = S._compiled(cw, ow, False)
     comp, clen = S._pack_chunk(payloads, cw)
     carg, cl = jnp.asarray(comp), jnp.asarray(clen)
@@ -166,6 +169,49 @@ def run_inflate_simd_wgs30x(results: list, record_bytes: bytes) -> None:
         "correct": ok,
     })
     assert ok, "wgs30x SIMD inflate output != its input"
+
+
+def run_inflate_simd_ont30x(results: list, record_bytes: bytes) -> None:
+    """The kernel at its wide geometry on the long-read benchmark's own
+    bytes: 128 lanes of 65,280 ``ont30x`` record bytes each, zlib 6
+    (``benchmark/gen_longread.py`` + ``reference_longread.encode_records``,
+    handed in by the caller). Most payloads are over the narrow
+    geometry's 32,752 bytes, so the launch is (16384, 16384). Beside
+    the row's own factors: what the same launch costs the lanes under
+    the narrow cap when they launch alone at the narrow geometry and
+    alone at the wide one (the geometry's own price a superstep)."""
+    from disq_tpu.ops.inflate_simd import NARROW_CSIZE
+
+    block = 65280
+    assert len(record_bytes) >= 128 * block, (
+        f"{len(record_bytes)} record bytes do not fill 128 lanes")
+    raws = [record_bytes[i * block: (i + 1) * block] for i in range(128)]
+    payloads = [_deflate(r) for r in raws]
+    wide = sum(len(p) > NARROW_CSIZE for p in payloads)
+    ok, best, meta = _inflate_kernel_only(raws, payloads)
+    row = {
+        "kernel": "inflate_simd_ont30x_kernel_only",
+        "shape": "128 lanes x 65280 B of ont30x records, zlib 6",
+        "mb_per_sec": round(128 * block / best / 1e6, 2),
+        "ratio_zlib6": round(128 * block / sum(map(len, payloads)), 3),
+        "lanes_over_32752_B": wide,
+        **_superstep_factors(best, meta),
+        "far_superstep_share": round(
+            int(meta[3, 0]) / int(meta[2, 0]), 4),
+        "crossing_chunks": int(meta[4].sum()),
+    }
+    narrow = [i for i, p in enumerate(payloads) if len(p) <= NARROW_CSIZE]
+    if narrow:
+        sub = [raws[i] for i in narrow], [payloads[i] for i in narrow]
+        for key, cw in (("narrow_lanes_at_cw8192", 0),
+                        ("narrow_lanes_at_cw16384", 16384)):
+            ok_n, best_n, meta_n = _inflate_kernel_only(*sub, cw=cw)
+            ok = ok and ok_n
+            row[key] = {"lanes": len(narrow),
+                        **_superstep_factors(best_n, meta_n)}
+    row["correct"] = ok
+    results.append(row)
+    assert ok, "ont30x SIMD inflate output != its input"
 
 
 def run_rans_simd(results: list) -> None:
@@ -583,9 +629,10 @@ def run_mesh_parse(results: list) -> None:
 
 
 def main(out_path: str = "TPU_KERNELS.json",
-         wgs30x_records: str = "") -> int:
-    """``wgs30x_records``: a file of the benchmark's record bytes for
-    the ``inflate_simd_wgs30x_kernel_only`` row (left out without)."""
+         wgs30x_records: str = "", ont30x_records: str = "") -> int:
+    """``wgs30x_records``, ``ont30x_records``: files of the benchmark's
+    record bytes for the ``inflate_simd_wgs30x_kernel_only`` and
+    ``inflate_simd_ont30x_kernel_only`` rows (each left out without)."""
     import jax
 
     from disq_tpu.util import enable_compile_cache
@@ -602,6 +649,10 @@ def main(out_path: str = "TPU_KERNELS.json",
         with open(wgs30x_records, "rb") as f:
             rows.append(functools.partial(
                 run_inflate_simd_wgs30x, record_bytes=f.read()))
+    if ont30x_records:
+        with open(ont30x_records, "rb") as f:
+            rows.append(functools.partial(
+                run_inflate_simd_ont30x, record_bytes=f.read()))
     for fn in (*rows,
                run_rans_simd, run_kernel_fuzz,
                run_device_pipeline_row, run_resident_decode,

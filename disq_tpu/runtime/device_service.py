@@ -51,9 +51,13 @@ exact single-queue behavior it had before.
 Error isolation is strict per submission: a lane the kernel flags is
 re-inflated on host; if the host also fails (truly corrupt input) only
 the OWNER shard's future raises — lanes co-batched from other shards
-are delivered regardless.  Oversize payloads never enter the queue:
-they decode on the submitting shard's own thread, exactly like the
-per-shard dispatch did.
+are delivered regardless.  Oversize payloads (a raw stream over BGZF's
+largest payload, or one that decodes to over 64 KiB: no BGZF block)
+never enter the queue: they decode on the submitting shard's own
+thread, exactly like the per-shard dispatch did.  Every BGZF block goes
+to the device; the blocks whose payload is over the narrow launch
+geometry's 32,752 bytes queue apart (``inflate.wide``) and launch at
+the wide one, so each launch's geometry is its queue's.
 
 Telemetry: ``device.lane_fill`` (lanes per launch / 128),
 ``device.queue_depth``, ``device.batch.flush{reason=full|timeout|drain}``,
@@ -110,6 +114,10 @@ LANES = 128  # mirrors ops/inflate_simd.LANES (not imported: keep this
 # tasks of ~2 MB, not one a block (a task's hand-over costs what the
 # CRC of a block does) and not one a launch (the pool has four threads)
 CHECK_LANES = 32
+# the queue of the inflate lanes that launch at the wide geometry, and
+# the engine that serves it
+WIDE_QUEUE = "inflate.wide"
+QUEUE_ENGINE = {WIDE_QUEUE: "inflate"}
 
 
 class _Lane:
@@ -312,7 +320,8 @@ class _InflateEngine:
 
         out, arena, cw = handle
         try:
-            lanes_u8, meta = IS._fetch_chunk(out, len(lanes), labels)
+            lanes_u8, meta = IS._fetch_chunk(
+                out, len(lanes), labels, cw=cw)
         except BaseException:
             IS.ARENAS.release(("inflate", cw), arena)
             raise
@@ -439,9 +448,16 @@ class DeviceDecodeService:
 
         self._devices = service_devices()
         n_dev = len(self._devices)
+        # a queue an engine, and one more for the inflate lanes whose
+        # payload is over the narrow geometry's (``inflate.wide``): a
+        # launch's geometry follows its widest payload
+        # (``inflate_simd.buckets_for``), so lanes queued apart launch
+        # at the one geometry of their kind whichever pass, shard or
+        # flush brings them together, and a warm-up pass has compiled
+        # both
         self._queues: Dict[str, List[Deque[_Lane]]] = {
             k: [deque() for _ in range(n_dev)]
-            for k in self._engines}
+            for k in (*self._engines, WIDE_QUEUE)}
         self._next_queue = 0  # tie-break rotation (see _enqueue)
         # by device, under ``_cond``: lanes queued or in flight (what
         # ``_enqueue`` balances)
@@ -462,13 +478,14 @@ class DeviceDecodeService:
         self._sleep_t0: Optional[float] = None
         self._book_sleep_locked(0.0)
         _on_snapshot(self._settle_idle)
-        # window sized for the standard full-BGZF geometry; the env
-        # knobs in dispatch_window apply here too.  Scaled by the
-        # device count: the window bounds launches IN FLIGHT, and with
-        # n chips each wants its own pipeline of them
+        # window sized for the widest full-BGZF geometry (8 MiB of
+        # compressed words and 8 MiB of output a launch); the env knobs
+        # in dispatch_window apply here too.  Scaled by the device
+        # count: the window bounds launches IN FLIGHT, and with n chips
+        # each wants its own pipeline of them
         from disq_tpu.ops.inflate_simd import dispatch_window
 
-        self._window = dispatch_window(4, 16 << 20) * n_dev
+        self._window = dispatch_window(4, 2 * (16384 + 8) * LANES * 4) * n_dev
         self._thread = threading.Thread(
             target=self._run, name="disq-device-dispatch", daemon=True)
         self._thread.start()
@@ -513,9 +530,11 @@ class DeviceDecodeService:
             crcs = np.asarray(crcs, np.uint32)
         sub = Submission(blob=blob, offsets=offsets, crcs=crcs, base=base)
         ctx = _current_trace()
-        lanes: List[_Lane] = []
+        lanes: Dict[str, List[_Lane]] = {"inflate": [], WIDE_QUEUE: []}
         for i, p in enumerate(payloads):
-            if len(p) > IS.MAX_DEVICE_CSIZE:
+            if (len(p) > IS.MAX_DEVICE_CSIZE
+                    or int(usizes[i]) > IS.MAX_DEVICE_USIZE):
+                # no BGZF block: a raw stream past the kernel's buffers
                 IS.last_stats["host_big"] += 1
                 _counter("device.host_fallback_blocks").inc(
                     reason="oversize")
@@ -523,8 +542,10 @@ class DeviceDecodeService:
                 sub.check((i,))
             else:
                 # ts stamped at enqueue (see _enqueue)
-                lanes.append(_Lane(sub, i, p, int(usizes[i]), 0.0, ctx))
-        self._enqueue("inflate", lanes, sub)
+                lanes[WIDE_QUEUE if len(p) > IS.NARROW_CSIZE
+                      else "inflate"].append(
+                    _Lane(sub, i, p, int(usizes[i]), 0.0, ctx))
+        self._enqueue(lanes, sub)
         return sub
 
     def submit_rans(self, streams: Sequence[bytes]) -> Submission:
@@ -550,10 +571,10 @@ class DeviceDecodeService:
                 sub.deliver_local(k, RS._host_decode0(s))
                 continue
             lanes.append(_Lane(sub, k, (s, meta), meta[0], 0.0, ctx))
-        self._enqueue("rans", lanes, sub)
+        self._enqueue({"rans": lanes}, sub)
         return sub
 
-    def _enqueue(self, kind: str, lanes: List[_Lane],
+    def _enqueue(self, lanes_by_queue: Dict[str, List[_Lane]],
                  sub: Submission) -> None:
         # stamp the flush clock HERE, not at submission start: oversize
         # host decode / rANS table parsing on the submitting thread can
@@ -561,8 +582,11 @@ class DeviceDecodeService:
         # flush immediately at partial fill — defeating the coalescing
         # this queue exists for
         now = time.perf_counter()
-        for lane in lanes:
-            lane.ts = now
+        n_lanes = 0
+        for lanes in lanes_by_queue.values():
+            n_lanes += len(lanes)
+            for lane in lanes:
+                lane.ts = now
         with self._cond:
             if self._closed:
                 raise RuntimeError("device decode service is closed")
@@ -576,13 +600,13 @@ class DeviceDecodeService:
             # next arrives (serve queries, tiny splits) would all land
             # on device 0.  With one device this is the old
             # single-queue append
-            subqs = self._queues[kind]
-            n_q = len(subqs)
+            n_q = len(self._devices)
             pick = min(range(n_q), key=lambda i: (
                 self._outstanding[i], (i - self._next_queue) % n_q))
             self._next_queue = (pick + 1) % n_q
-            self._outstanding[pick] += len(lanes)
-            subqs[pick].extend(lanes)
+            self._outstanding[pick] += n_lanes
+            for queue, lanes in lanes_by_queue.items():
+                self._queues[queue][pick].extend(lanes)
             depth = sum(
                 len(q) for qs in self._queues.values() for q in qs)
             if sub._pending <= 0:
@@ -763,7 +787,7 @@ class DeviceDecodeService:
                 reason = "drain" if self._closed else "timeout"
             else:
                 continue
-            return kind, i, lanes, reason
+            return QUEUE_ENGINE.get(kind, kind), i, lanes, reason
         return None
 
     def _wait_s_locked(self) -> Optional[float]:

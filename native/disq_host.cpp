@@ -384,11 +384,13 @@ static inline int64_t cigar_reference_length(const uint8_t* c, uint16_t nc) {
 // CIGAR-only pass: each record's pos and the reference length its
 // CIGAR consumes (ops M, D, N, =, X), reading 36 fixed bytes and the
 // op words of a record and nothing else of it.  ``offsets`` index a
-// larger blob of which ``buf`` is the part that starts at ``base``.
+// larger blob of which ``buf`` is the part that starts at ``base``;
+// ``ops`` takes the op words walked.
 int64_t disq_bam_reference_lengths(const uint8_t* buf, int64_t buf_len,
                                    const int64_t* offsets, int64_t base,
                                    int64_t n, int32_t* pos,
-                                   int64_t* reflen) {
+                                   int64_t* reflen, int64_t* ops) {
+  *ops = 0;
   for (int64_t i = 0; i < n; i++) {
     int64_t at = offsets[i] - base, end = offsets[i + 1] - base;
     if (at < 0 || end < at + 36 || end > buf_len) return -1 - i;
@@ -398,6 +400,7 @@ int64_t disq_bam_reference_lengths(const uint8_t* buf, int64_t buf_len,
     if (36 + (int64_t)r[12] + 4LL * nc > end - at) return -1 - i;
     std::memcpy(pos + i, r + 8, 4);
     reflen[i] = cigar_reference_length(r + 36 + r[12], nc);
+    *ops += nc;
   }
   return 0;
 }

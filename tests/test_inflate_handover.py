@@ -214,8 +214,8 @@ def _flag_lanes(monkeypatch, which):
 
     real = IS._fetch_chunk
 
-    def flagging(handle, lanes, labels=None, kernel="inflate_simd"):
-        words, meta = real(handle, lanes, labels, kernel)
+    def flagging(handle, lanes, labels=None, kernel="inflate_simd", **kw):
+        words, meta = real(handle, lanes, labels, kernel, **kw)
         meta = np.array(meta)
         meta[1, list(which)] = 7
         return words, meta

@@ -9,6 +9,7 @@ import pytest
 
 from disq_tpu.ops.inflate_simd import (
     MAX_DEVICE_CSIZE,
+    MAX_DEVICE_USIZE,
     inflate_payloads_simd,
     last_stats,
 )
@@ -72,17 +73,21 @@ def test_compressible_structured():
     roundtrip([data] * 3, [raw_deflate(data, 6, s) for s in strategies])
 
 
-def test_full_64k_block_over_the_comp_cap_goes_to_host():
-    # a payload past the kernel's comp cap goes to the host alone
+def test_a_stream_over_the_comp_cap_or_the_output_goes_to_host():
+    # what is no BGZF block goes to the host alone: a payload past the
+    # kernel's comp cap (BGZF's largest), a raw size past its output
     rng = np.random.default_rng(3)
-    big = rng.integers(0, 200, 65536, dtype=np.uint8).tobytes()
+    big = rng.integers(0, 256, MAX_DEVICE_CSIZE + 4096,
+                       dtype=np.uint8).tobytes()
+    long = b"a raw size over the output buffer " * 2000
     small = b"the lane that stays on the device " * 40
-    payloads = [raw_deflate(big, 9), raw_deflate(small)]
-    assert len(payloads[0]) > MAX_DEVICE_CSIZE >= len(payloads[1])
+    payloads = [raw_deflate(big, 9), raw_deflate(long), raw_deflate(small)]
+    assert len(payloads[0]) > MAX_DEVICE_CSIZE >= len(payloads[2])
+    assert len(long) > MAX_DEVICE_USIZE and len(payloads[1]) < 1024
     before = dict(last_stats)
-    roundtrip([big, small], payloads)
+    roundtrip([big, long, small], payloads)
     delta = {k: last_stats[k] - before[k] for k in before}
-    assert delta == {"device_lanes": 1, "host_big": 1, "host_fallback": 0}
+    assert delta == {"device_lanes": 1, "host_big": 2, "host_fallback": 0}
 
 
 def test_batch_of_mixed_blocks():

@@ -18,7 +18,8 @@ import pytest
 
 from disq_tpu.ops import inflate_simd as tables
 from disq_tpu.ops.inflate_simd import (
-    _COMP_TILES, COMP_PERIOD, MAX_DEVICE_CSIZE, inflate_payloads_simd,
+    _COMP_TILES, COMP_PERIOD, MAX_DEVICE_CSIZE, NARROW_CSIZE,
+    inflate_payloads_simd,
 )
 
 
@@ -1522,15 +1523,17 @@ _WINDOW_SMALL = (
        ("empty-lane", b""),
        ("truncated", _truncated_stored())]
 )
-# one launch at the largest geometry: a payload at the comp cap (its
-# last words lie in the buffer's last tile, the tile after it is past
-# the buffer) beside a 51-byte payload that stays live as long (so the
-# sweep's hull spans slabs 0 to 7), empty lanes among them
+# one launch at the narrow geometry's largest: a payload at its comp cap
+# (its last words lie in the buffer's last tile, the tile after it is
+# past the buffer; the wide geometry's twin, a payload at
+# MAX_DEVICE_CSIZE, is tests/test_inflate_wide.py's) beside a 51-byte
+# payload that stays live as long (so the sweep's hull spans slabs 0 to
+# 7), empty lanes among them
 _WINDOW_BIG = [
     ("tiny-payload-long-life", deflate(b"abcd" * 8000, 9)),
     ("empty-lane", b""),
     ("payload-at-the-comp-cap",
-     deflate_stored(_window_bytes(MAX_DEVICE_CSIZE - 5))),
+     deflate_stored(_window_bytes(NARROW_CSIZE - 5))),
     ("empty-lane-2", b""),
     ("forty-bytes-and-done", _fixed(list(range(70, 105)))),
 ]
@@ -1543,8 +1546,9 @@ def window_launches():
     from disq_tpu.ops.inflate_simd import buckets_for
 
     big = [p for _n, p in _WINDOW_BIG]
-    assert len(big[2]) == MAX_DEVICE_CSIZE and len(big[0]) < 64
-    geometry = buckets_for(big, MAX_DEVICE_CSIZE)
+    assert len(big[2]) == NARROW_CSIZE < MAX_DEVICE_CSIZE
+    assert len(big[0]) < 64
+    geometry = buckets_for(big, NARROW_CSIZE)
     assert geometry[0] == 8192
     return {"small": raw_launch([p for _n, p in _WINDOW_SMALL], 256, 1024),
             "big": raw_launch(big, *geometry)}

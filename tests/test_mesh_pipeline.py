@@ -322,7 +322,7 @@ class TestEveryChipKeepsItsOwnPipeline:
 
         sub = Submission(parts_n=n_lanes)
         lanes = [_Lane(sub, i, b"", 0, 0.0, None) for i in range(n_lanes)]
-        svc._enqueue(kind, lanes, sub)
+        svc._enqueue({kind: lanes}, sub)
         return next(i for i, q in enumerate(svc._queues[kind])
                     if q and q[-1] is lanes[-1])
 

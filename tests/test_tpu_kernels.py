@@ -47,10 +47,27 @@ def wgs30x_record_bytes(n: int = 24000, seed: int = 27) -> bytes:
     return reference.encode_records(gen.generate(n, seed, wgs30x_config()))
 
 
+def ont30x_record_bytes(n: int = 800, seed: int = 27) -> bytes:
+    """Record bytes of the long-read benchmark's shape for the
+    ``inflate_simd_ont30x_kernel_only`` row: 800 ``ont30x`` records
+    (~10 MB: 128 lanes of 65,280 bytes and to spare)."""
+    sys.path.insert(0, REPO)
+    try:
+        from benchmark import gen_longread, reference_longread
+    finally:
+        sys.path.pop(0)
+    with open(os.path.join(REPO, "benchmark", "configs", "ont30x.json")) as f:
+        cfg = json.load(f)
+    return reference_longread.encode_records(
+        gen_longread.generate(n, seed, cfg))
+
+
 def test_device_kernels_on_chip(tmp_path):
     out = tmp_path / "TPU_KERNELS.json"
     records = tmp_path / "wgs30x_records.bin"
     records.write_bytes(wgs30x_record_bytes())
+    long_records = tmp_path / "ont30x_records.bin"
+    long_records.write_bytes(ont30x_record_bytes())
     # CPU parent, chip child: this process is pinned to the CPU by the
     # conftest and never touches the chip, so the child may take it.
     # Drop the conftest's overrides; JAX_PLATFORMS is unset
@@ -60,7 +77,7 @@ def test_device_kernels_on_chip(tmp_path):
            if k not in ("JAX_PLATFORMS", "XLA_FLAGS")}
     proc = subprocess.run(
         [sys.executable, "-m", "disq_tpu.ops.tpu_ci", str(out),
-         str(records)],
+         str(records), str(long_records)],
         env=env, cwd=REPO, capture_output=True, text=True, timeout=900,
     )
     # non-zero on any failed kernel AND when the child found no TPU
@@ -76,7 +93,8 @@ def test_device_kernels_on_chip(tmp_path):
     # the copy chunks that crossed an output word's boundary
     for kernel in ("inflate_simd_kernel_only",
                    "inflate_simd_literal_heavy_kernel_only",
-                   "inflate_simd_wgs30x_kernel_only"):
+                   "inflate_simd_wgs30x_kernel_only",
+                   "inflate_simd_ont30x_kernel_only"):
         assert rows[kernel]["supersteps_per_launch"] > 0
         assert rows[kernel]["us_per_superstep"] > 0
     wgs = rows["inflate_simd_wgs30x_kernel_only"]
@@ -94,6 +112,9 @@ def test_device_kernels_on_chip(tmp_path):
     # where a gated sweep at both refill sites read 5.4;
     # TPU_KERNELS.json keeps both)
     assert wgs["us_per_superstep"] < 4.95
+    # the long-read row ran at the wide geometry: most of its lanes are
+    # over the narrow one's payload
+    assert rows["inflate_simd_ont30x_kernel_only"]["lanes_over_32752_B"] > 64
     # refresh the repo-root artifact for the judge
     with open(os.path.join(REPO, "TPU_KERNELS.json"), "w") as f:
         json.dump(artifact, f, indent=1)
